@@ -1,9 +1,8 @@
 """Repo-root shim: the canonical implementation lives in the package
 (``lightgbm_tpu/utils/hermetic.py``) so library code — e.g. the
 multi-process launcher — can use it when installed.  Loaded here by FILE
-PATH, not package import: bench.py's outer watchdog process must be able
-to build child environments without importing lightgbm_tpu (whose
-package __init__ pulls in jax)."""
+PATH, not package import: ``force_cpu`` must run before jax's backend
+initializes, and importing the package imports jax."""
 
 import importlib.util as _ilu
 import os as _os

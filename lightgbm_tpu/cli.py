@@ -187,6 +187,10 @@ def run(argv: List[str]) -> int:
 
 
 def main() -> None:
+    # the process entry point, not run(): callers of run() in a live
+    # process keep whatever cache configuration that process has
+    from .utils.jax_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(run(sys.argv[1:]))
 
 

@@ -306,17 +306,6 @@ def _store_best(state: _GrowState, leaf: jnp.ndarray, bs: BestSplit,
     )
 
 
-def _shard_map():
-    """shard_map + version-dependent replication-check kwarg (jax >= 0.8
-    moved it out of experimental and renamed check_rep)."""
-    try:
-        from jax import shard_map
-        return shard_map, {"check_vma": False}
-    except ImportError:                        # pragma: no cover
-        from jax.experimental.shard_map import shard_map
-        return shard_map, {"check_rep": False}
-
-
 def fp_capable_for(cfg: GrowerConfig, mesh, data_axis: str) -> bool:
     """Static predicate: does this config route a feature-only mesh to the
     feature-sharded perm layout (vs the GSPMD mask fallback)?  Shared by
@@ -753,10 +742,8 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
     pool_capable = pool_active_for(cfg, mesh, data_axis)
     # ---- fused wave kernel (ops/pallas_wave.py, tpu_wave_kernel): the
     # composition-level gate; the shape-level wave_layout_fits check runs
-    # at trace time inside _grow_wave.  Interpret mode on non-TPU backends
-    # is how tier-1 exercises the kernel body on CPU.
+    # at trace time inside _grow_wave.
     wave_fused_req = wave_fused_for(cfg, mesh, data_axis)
-    wave_interpret = jax.default_backend() != "tpu"
     _W_FRONTIER = min(cfg.leaf_batch, max(L - 1, 1))
 
     def _pool_slots(hist_cols: int) -> int:
@@ -2168,7 +2155,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         # trace-time statics, so degrade costs nothing.
         use_fused = wave_fused_req and axis is None and not voting
         if use_fused:
-            from ..ops.pallas_common import C_PAD
+            from ..ops.pallas_common import C_PAD, interpret_mode
             from ..ops.pallas_wave import (fused_wave_call, hist_from_flat,
                                            hist_to_flat, payload_to_best,
                                            plane_order, wave_dtype_for,
@@ -2223,7 +2210,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                             wave_scale, num_bins=HB, features=f,
                             rows_block=min(cfg.rows_block, S),
                             dtype=wave_dtype, packed4=cfg.packed4,
-                            scfg=cfg.split, interpret=wave_interpret)
+                            scfg=cfg.split, interpret=interpret_mode())
                     return br
 
                 bi = jnp.max(jnp.where(active, _bucket_of(small_cnt), 0))
@@ -2728,7 +2715,6 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         column store).  Cost per split is O(leaf rows + N), not the mask
         layout's O(N * num_leaves) full rescan."""
         from jax.sharding import PartitionSpec as P
-        shard_map, smap_kw = _shard_map()
 
         S = fp_shards
         fl = -(-bins.shape[1] // S)
@@ -2768,13 +2754,14 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                               (nb_l, na_l, ic_l, mo_l), None, sk,
                               axis=None, faxis=fp_axis_name, fp_shards=S)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(None, fp_axis_name), P(), P(fp_axis_name),
                       P(fp_axis_name), P(fp_axis_name), P(fp_axis_name),
                       P(fp_axis_name)) + tuple(especs),
             out_specs=(P(), P()),
-            **smap_kw)(bins, vals, fmask, nbpf, nanb, iscat, mono, *extras)
+            check_vma=False)(bins, vals, fmask, nbpf, nanb, iscat, mono,
+                             *extras)
 
     # -------------------------------------------------------------- sharded path
     def _grow_sharded(bins, vals, scale3, feature_mask, meta, cegb,
@@ -2789,7 +2776,6 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         replicated on all shards, so the tree state is replicated and the
         while_loop stays in lockstep."""
         from jax.sharding import PartitionSpec as P
-        shard_map, smap_kw = _shard_map()
 
         grow_fn = (_grow_wave if (cfg.leaf_batch > 1 or cfg.voting)
                    else _grow_perm)
@@ -2824,12 +2810,12 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 sk = extra[i]
             return grow_fn(bins, vals, s3, fmask, m, cg, sk, axis=data_axis)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(data_axis), P(data_axis), P())
             + (P(),) * n_meta + tuple(especs),
             out_specs=(P(), P(data_axis)),
-            **smap_kw,
+            check_vma=False,
         )(bins, vals, feature_mask, *meta, *extras)
 
     def _grow_impl(

@@ -54,7 +54,7 @@ class RandomForest(GBDT):
             hk = h_dev[:, k] if self._shape_k else h_dev
             qk = None if qkey is None else jax.random.fold_in(qkey, k)
             zero = jnp.zeros(self.train_data.num_data, jnp.float32)
-            contrib, arrays, row_leaf = self._hist_fallback_call(
+            contrib, arrays, row_leaf = self._dispatch(
                 "_grow_apply", self.bins_dev, zero, gk, hk, mask_dev, fmask,
                 1.0, quant_key=qk)
             self.dev_models[k].append(arrays)

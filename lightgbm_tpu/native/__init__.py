@@ -3,7 +3,8 @@
 The C++ side (``csrc/native.cpp``) provides the host components that are C++
 in the reference — text data loading (``src/io/parser.cpp``), binning
 (``src/io/bin.cpp``), and batch tree traversal (``src/io/tree.cpp``).  The
-library is compiled on first use with ``g++`` and cached next to the sources;
+library is compiled on first use with ``g++`` and cached next to the sources
+under a name that carries the source's hash;
 every entry point has a pure-NumPy fallback so the package works without a
 toolchain (``available()`` reports which path is active).
 """
@@ -12,7 +13,6 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 from typing import Optional, Tuple
 
@@ -20,7 +20,6 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "csrc", "native.cpp")
-_LIB_PATH = os.path.join(_HERE, "_native.so")
 
 _lock = threading.Lock()
 _lib = None
@@ -32,18 +31,12 @@ _f64 = ctypes.c_double
 
 
 def _build() -> Optional[str]:
-    """Compile csrc/native.cpp -> _native.so (cached by mtime)."""
-    if (os.path.exists(_LIB_PATH)
-            and os.path.getmtime(_LIB_PATH) >= os.path.getmtime(_SRC)):
-        return _LIB_PATH
-    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o",
-           _LIB_PATH + ".tmp", _SRC]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=240)
-    except Exception:
-        return None
-    os.replace(_LIB_PATH + ".tmp", _LIB_PATH)
-    return _LIB_PATH
+    """Compile csrc/native.cpp -> _native-<source hash>.so (utils/sobuild)."""
+    from ..utils.sobuild import cached_library
+    return cached_library(
+        _SRC, "_native",
+        lambda out: ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o",
+                     out, _SRC])
 
 
 def _load():

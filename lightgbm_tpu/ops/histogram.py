@@ -168,19 +168,18 @@ def histogram_from_vals(
     quantized histograms stay exact either way)."""
     impl = resolve_impl(impl)
     if impl in ("pallas", "flat", "flat_bf16"):
+        from .pallas_common import interpret_mode
         from .pallas_histogram import histogram_flat
         if jnp.issubdtype(vals.dtype, jnp.integer):
             # Quantized histograms: s8 x s8 -> s32 on the MXU's double-rate
             # int8 path (reference Int32HistogramSumReducer, bin.h:48-81).
-            out = histogram_flat(bins, vals, num_bins=num_bins,
-                                 rows_block=rows_block, dtype="int8",
-                                 packed4=packed4, features=features)
+            dtype = "int8"
         else:
-            out = histogram_flat(bins, vals, num_bins=num_bins,
-                                 rows_block=rows_block,
-                                 dtype="bf16" if impl == "flat_bf16"
-                                 else "f32",
-                                 packed4=packed4, features=features)
+            dtype = "bf16" if impl == "flat_bf16" else "f32"
+        out = histogram_flat(bins, vals, num_bins=num_bins,
+                             rows_block=rows_block, dtype=dtype,
+                             packed4=packed4, features=features,
+                             interpret=interpret_mode())
         return out if init is None else init + out
     if impl == "onehot":
         return histogram_onehot(bins, vals, num_bins=num_bins,

@@ -1,9 +1,9 @@
-"""Shared scaffolding for the Pallas TPU kernels (histogram + fused wave).
+"""Shared scaffolding for the Pallas TPU kernels (histogram, fused wave,
+traversal).
 
-One copy of the jax-version shims and layout constants both kernels need,
-so the fused wave kernel (``ops/pallas_wave.py``) reuses the histogram
-kernel's exact compile parameters and dtype table instead of duplicating
-the rename shim (the ISSUE-7 cleanup satellite).
+One copy of the compile parameters, the interpret-mode decision, the
+dtype table and the one-hot contraction, so the three kernels cannot
+drift apart.
 """
 
 from __future__ import annotations
@@ -17,7 +17,12 @@ from jax.experimental.pallas import tpu as pltpu
 # vs a full 8-sublane tile.
 C_PAD = 4
 
-# Mosaic scoped-vmem ceiling (v5e has 128MB).
+# Mosaic scoped-vmem ceiling.  The v5e compiler reports 128 MiB of VMEM
+# ("would exceed memory (size=134217728)"); half of it leaves XLA room for
+# the fusions around the custom call.  Each kernel's own layout model
+# (``_pick_tiles`` 16 MiB, ``WAVE_VMEM_BUDGET`` 48 MiB,
+# ``TRAVERSE_VMEM_BUDGET`` 32 MiB) compiles under it at the largest shape
+# the model admits (tests/test_tpu_lowering.py compiles the Higgs shape).
 VMEM_LIMIT = 64 * 1024 * 1024
 
 # one-hot/compute dtype -> (operand dtype, accumulator dtype, itemsize)
@@ -28,17 +33,20 @@ DTYPES = {
 }
 
 
-def compiler_params_cls():
-    """pltpu compiler-params class across the jax rename
-    (TPUCompilerParams -> CompilerParams); fails with the attribute names
-    rather than an opaque NoneType call on a third rename."""
-    cls = getattr(pltpu, "CompilerParams",
-                  getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:
-        raise AttributeError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams "
-            "nor TPUCompilerParams; unsupported jax version")
-    return cls
+def interpret_mode() -> bool:
+    """The ONE interpret-mode decision for every Pallas kernel in the
+    package: Mosaic compiles the kernel on a TPU backend, and anywhere
+    else the body runs in the Pallas interpreter (how the CPU tests
+    exercise kernel bodies).  The grower, the histogram dispatch, the
+    serve plan and the tools all read it here, so what ``chip_smoke.py``
+    prints is what every kernel call used."""
+    return jax.default_backend() != "tpu"
+
+
+def compiler_params(*dimension_semantics: str) -> pltpu.CompilerParams:
+    """Mosaic compile parameters shared by the three kernels."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=VMEM_LIMIT)
 
 
 def onehot_contract(bins_blk, valsT, *, num_bins, oh_dtype, acc_dtype,

@@ -40,12 +40,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 # Shared kernel scaffolding (ops/pallas_common.py): the fused wave kernel
-# reuses the SAME compile-params shim / dtype table / one-hot contraction,
-# so the two kernels cannot drift apart.  The old private names stay as
-# aliases for back-compat with external callers/tests.
-from .pallas_common import (C_PAD, DTYPES as _DTYPES,
-                            VMEM_LIMIT as _VMEM_LIMIT,
-                            compiler_params_cls as _compiler_params_cls,
+# reuses the SAME compile parameters / dtype table / one-hot contraction,
+# so the two kernels cannot drift apart.
+from .pallas_common import (C_PAD, DTYPES as _DTYPES, compiler_params,
                             onehot_contract)
 
 
@@ -199,10 +196,7 @@ def histogram_flat(
         out_specs=pl.BlockSpec((C_PAD, ftile * b_pad),
                                lambda i: (0, 0), memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((C_PAD, ftile * b_pad), acc_dtype),
-        # jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5
-        compiler_params=_compiler_params_cls()(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
     )
     chunks = [call(jax.lax.slice_in_dim(bins, c * cols_tile,

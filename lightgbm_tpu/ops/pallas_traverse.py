@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_common import VMEM_LIMIT, compiler_params_cls
+from .pallas_common import compiler_params
 
 #: VMEM working-set budget for one traversal call: resident pack (widened
 #: to i32 operands) + one streamed row block + the per-step one-hot
@@ -101,36 +101,34 @@ def _traverse_kernel(bins_ref, nanb_ref, sf_ref, sb_ref, dl_ref, ic_ref,
     bins = bins_ref[...].astype(jnp.int32)               # (blk, f_pad)
     blk, f_pad = bins.shape
     nanb = nanb_ref[...].astype(jnp.int32)               # (1, f_pad)
-    sf_all = sf_ref[...]                                 # (T, m_pad) i32
-    sb_all = sb_ref[...]
-    dl_all = dl_ref[...]
-    ic_all = ic_ref[...]
-    lc_all = lc_ref[...]
-    rc_all = rc_ref[...]
-    catb_all = catb_ref[...]                             # (T, m_pad*bb_pad)
-    leaf_all = leaf_ref[...]                             # (T, l_pad) i32
-    l_pad = leaf_all.shape[1]
+    l_pad = leaf_ref.shape[1]
     iota_m = jax.lax.broadcasted_iota(jnp.int32, (blk, m_pad), 1)
     iota_f = jax.lax.broadcasted_iota(jnp.int32, (blk, f_pad), 1)
     iota_bb = jax.lax.broadcasted_iota(jnp.int32, (blk, bb_pad), 1)
     iota_l = jax.lax.broadcasted_iota(jnp.int32, (blk, l_pad), 1)
 
-    def row_of(arr2d, t):
-        return jax.lax.dynamic_index_in_dim(arr2d, t, 0, keepdims=True)
+    def row_of(ref, t):
+        # Tree ``t``'s row, loaded from the resident block: Pallas TPU
+        # lowers a dynamic row index on a ref, not on a loaded value.
+        return ref[pl.ds(t, 1), :]
 
     def tree_body(t, acc):
-        sf_t = row_of(sf_all, t)
-        sb_t = row_of(sb_all, t)
-        dl_t = row_of(dl_all, t)
-        ic_t = row_of(ic_all, t)
-        lc_t = row_of(lc_all, t)
-        rc_t = row_of(rc_all, t)
-        catb_t = row_of(catb_all, t).reshape(m_pad, bb_pad) \
+        sf_t = row_of(sf_ref, t)                         # (1, m_pad) i32
+        sb_t = row_of(sb_ref, t)
+        dl_t = row_of(dl_ref, t)
+        ic_t = row_of(ic_ref, t)
+        lc_t = row_of(lc_ref, t)
+        rc_t = row_of(rc_ref, t)
+        catb_t = row_of(catb_ref, t).reshape(m_pad, bb_pad) \
             .astype(jnp.float32)
-        leaf_t = row_of(leaf_all, t)
+        leaf_t = row_of(leaf_ref, t)                     # (1, l_pad) i32
 
-        def step(_, st):
-            node, done = st
+        def step(_, node):
+            # A row is done once its node id went negative (~leaf): the
+            # one-hot below is then all-zero and the row keeps its leaf.
+            # (Mosaic cannot carry an i1 vector through the loop, and the
+            # sign already says it.)
+            done = node < 0
             ohn = (node == iota_m).astype(jnp.int32)     # (blk, m_pad)
 
             def sel(row):                                # row (1, m_pad)
@@ -155,17 +153,16 @@ def _traverse_kernel(bins_ref, nanb_ref, sf_ref, sb_ref, dl_ref, ic_ref,
             byte = jnp.sum(ohb * rowb, axis=1,
                            keepdims=True).astype(jnp.int32)
             catbit = ((byte >> (col & 7)) & 1) > 0
-            gl = jnp.where(ic > 0, catbit, col <= sb)
-            gl = jnp.where(isnan & (ic == 0), dl > 0, gl)
-            nxt = jnp.where(gl, lc, rc)
-            is_leaf = nxt < 0
-            node = jnp.where(is_leaf | done, node, nxt)
-            node = jnp.where(is_leaf & ~done, nxt, node)
-            return node, done | is_leaf
+            # _tree_walk_q's two selects between bool vectors, written
+            # as mask algebra (Mosaic has no i1-valued vector select)
+            cat = ic > 0
+            gl = (cat & catbit) | (~cat & (col <= sb))
+            nan_num = isnan & ~cat
+            gl = (nan_num & (dl > 0)) | (~nan_num & gl)
+            return jnp.where(done, node, jnp.where(gl, lc, rc))
 
-        node0 = jnp.zeros((blk, 1), jnp.int32)
-        done0 = jnp.zeros((blk, 1), jnp.bool_)
-        node, _ = jax.lax.fori_loop(0, depth, step, (node0, done0))
+        node = jax.lax.fori_loop(0, depth, step,
+                                 jnp.zeros((blk, 1), jnp.int32))
         leaf_idx = jnp.where(node < 0, ~node, 0)
         ohl = (leaf_idx == iota_l).astype(jnp.int32)
         return acc + jnp.sum(ohl * leaf_t, axis=1, keepdims=True)
@@ -225,9 +222,7 @@ def fused_traverse_call(
         out_specs=pl.BlockSpec((blk, 1), lambda r: (r, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n + pad, 1), jnp.int32),
-        compiler_params=compiler_params_cls()(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=VMEM_LIMIT),
+        compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
     )(bins, nan_bins, sf, sb, dl, ic, catb, lc, rc, leaf)
     return out[:n, 0]

@@ -53,7 +53,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_common import (C_PAD, DTYPES, VMEM_LIMIT, compiler_params_cls,
+from .pallas_common import (C_PAD, DTYPES, compiler_params,
                             onehot_contract)
 from .pallas_histogram import kernel_layout
 from .split import BestSplit, SplitConfig, scan_tables, select_payload
@@ -403,8 +403,6 @@ def fused_wave_call(
             jax.ShapeDtypeStruct((w, 2, C_PAD, fb), acc_dtype),
             jax.ShapeDtypeStruct((w, 2, pay_w), jnp.float32),
         ],
-        compiler_params=compiler_params_cls()(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=VMEM_LIMIT),
+        compiler_params=compiler_params("arbitrary", "arbitrary"),
         interpret=interpret,
     )(*inputs)

@@ -331,6 +331,24 @@ def _col(a):
     return a if a.ndim == 2 else a[:, None]
 
 
+def _prefix_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sum along the bin axis in log2(B) shift-and-add
+    steps (Hillis-Steele).  Pallas TPU has no lowering for ``cumsum``;
+    zero-filled shifts and elementwise adds lower everywhere, and they fix
+    the ORDER of the f32 additions: the fused kernel (Mosaic or
+    interpreted) and the XLA scan produce the same bits from the same
+    histogram on any backend, which no matmul or backend-chosen scan
+    promises."""
+    b = x.shape[-1]
+    k = 1
+    while k < b:
+        shifted = jnp.concatenate(
+            [jnp.zeros(x.shape[:-1] + (k,), x.dtype), x[..., :-k]], axis=-1)
+        x = x + shifted
+        k *= 2
+    return x
+
+
 def scan_tables(
     G: jnp.ndarray,               # (F, B) grad sums (f32, scaled)
     H: jnp.ndarray,               # (F, B) hess sums
@@ -377,9 +395,9 @@ def scan_tables(
     Hn = jnp.sum(jnp.where(nan_pos, H, 0.0), axis=1, keepdims=True)
     Cn = jnp.sum(jnp.where(nan_pos, C, 0.0), axis=1, keepdims=True)
 
-    cumG = jnp.cumsum(Gv, axis=1)
-    cumH = jnp.cumsum(Hv, axis=1)
-    cumC = jnp.cumsum(Cv, axis=1)
+    cumG = _prefix_sum(Gv)
+    cumH = _prefix_sum(Hv)
+    cumC = _prefix_sum(Cv)
 
     # Parent gain shift: closed form without smoothing, output-based with
     # (reference BeforeNumerical / FindBestThresholdCategoricalInner).

@@ -27,7 +27,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .mesh import DATA_AXIS
@@ -112,7 +111,7 @@ def histogram_reduce_scatter(local_hist: jnp.ndarray, mesh: Mesh,
         # h: this shard's full-F local histogram -> (F/K, B, C) owned block.
         return histogram_reduce_scatter_local(h, axis, 0)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=P(axis),      # stacked per-shard partials
         out_specs=P(axis),
@@ -129,8 +128,8 @@ def allgather_histogram(owned: jnp.ndarray, mesh: Mesh,
     def body(h):
         return jax.lax.all_gather(h, axis, axis=0, tiled=True)
 
-    return shard_map(body, mesh=mesh, in_specs=P(axis), out_specs=P(),
-                     check_rep=False)(owned)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(axis), out_specs=P(),
+                         check_vma=False)(owned)
 
 
 def sync_global_best_split(gains: jnp.ndarray, payload: jnp.ndarray,
@@ -149,11 +148,11 @@ def sync_global_best_split(gains: jnp.ndarray, payload: jnp.ndarray,
         win = jnp.argmax(all_g)
         return all_g[win], all_p[win]
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(axis, None)),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(gains, payload)
 
 
@@ -162,8 +161,8 @@ def _scalar_sync(reduce_fn, value: jnp.ndarray, mesh: Mesh,
     def body(v):
         return reduce_fn(v, axis)
 
-    return shard_map(body, mesh=mesh, in_specs=P(axis), out_specs=P(),
-                     check_rep=False)(value)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(axis), out_specs=P(),
+                         check_vma=False)(value)
 
 
 def global_sum(value: jnp.ndarray, mesh: Mesh,
@@ -204,8 +203,8 @@ def global_mean(value: jnp.ndarray, weight: jnp.ndarray, mesh: Mesh,
         return jax.lax.psum(v * w, axis) / jnp.maximum(
             jax.lax.psum(w, axis), 1e-35)
 
-    return shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
-                     out_specs=P(), check_rep=False)(value, weight)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
+                         out_specs=P(), check_vma=False)(value, weight)
 
 
 # ----------------------------------------------------------------- voting mode
@@ -237,7 +236,7 @@ def global_feature_vote(local_gains: jnp.ndarray, top_k: int, mesh: Mesh,
         _, win = jax.lax.top_k(score, min(2 * k, f))
         return jnp.zeros(f, bool).at[win].set(True)[None]
 
-    mask = shard_map(body, mesh=mesh, in_specs=P(axis),
-                     out_specs=P(axis))(local_gains)
+    mask = jax.shard_map(body, mesh=mesh, in_specs=P(axis),
+                         out_specs=P(axis))(local_gains)
     # All shards compute identical masks; take the first replica.
     return mask[0]

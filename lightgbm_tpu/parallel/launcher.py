@@ -69,6 +69,13 @@ def launch(worker: Callable, num_workers: int, *,
     process (the hermetic test topology); 0 uses each process's default
     backend.  ``machines`` overrides the auto-generated localhost list for
     multi-host launches (reference dask.py builds it from worker IPs).
+
+    One process per chip: with ``devices_per_worker=0`` on a TPU host,
+    every worker's default backend claims EVERY local chip, so the second
+    worker fails or hangs at backend init.  Several processes on one TPU
+    host are out of scope (ROADMAP retired it) — one process drives all
+    of a host's chips through ``tree_learner=data``; ``chip_smoke.py``
+    never goes through this launcher.
     """
     if num_workers < 1:
         raise ValueError("num_workers must be >= 1")

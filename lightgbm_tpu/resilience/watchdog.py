@@ -11,13 +11,13 @@ and prints one JSON line; the parent's verdict is
 - ``wedged`` — the child exceeded the budget (killed; backend unusable),
 - ``error``  — the child exited nonzero (backend broken but not hung).
 
-This module is deliberately importable WITHOUT the lightgbm_tpu package
-(stdlib-only at module level): bench.py's outer process loads it by file
-path precisely because importing the package pulls in jax, and a wedged
-plugin can hang even at import.  The fault seam (wedge_dispatch) is
-re-implemented inline in the child source for the same reason.
+This module is deliberately runnable WITHOUT importing the lightgbm_tpu
+package (stdlib-only at module level): importing the package pulls in
+jax, and a wedged backend can hang even at import.  The fault seam
+(wedge_dispatch) is re-implemented inline in the child source for the
+same reason.
 
-CLI (used by tools/tpu_bench_playbook.sh)::
+CLI::
 
     python lightgbm_tpu/resilience/watchdog.py [--timeout S] [--platform P]
 
@@ -66,7 +66,7 @@ print(json.dumps({
 
 @dataclasses.dataclass
 class ProbeResult:
-    """One backend probe verdict (the block bench.py lands in its JSON)."""
+    """One backend probe verdict."""
 
     verdict: str                    # "live" | "wedged" | "error"
     backend: Optional[str] = None
@@ -144,16 +144,32 @@ class BackendWedgedError(RuntimeError):
     of letting training hang inside backend init."""
 
 
+def _backend_initialised() -> bool:
+    """Has THIS process already initialised a jax backend?  (Never imports
+    jax itself: a process that has not imported it has not.)"""
+    xb = sys.modules.get("jax._src.xla_bridge")
+    return bool(xb is not None and xb.backends_are_initialized())
+
+
 def preflight(params: Optional[Dict] = None) -> Optional[ProbeResult]:
     """Opt-in training preflight (``LIGHTGBM_TPU_WATCHDOG=1``): probe the
     backend under the ``tpu_probe_timeout`` budget BEFORE the trainer's
     first device touch.  Wedged -> :class:`BackendWedgedError` (a clear
     crash beats an indefinite hang); error -> warn and continue (the
-    in-process init will surface the real exception).  The
-    accelerator-resolved-to-cpu degrade warning is the trainer's
-    (models/gbdt.py emits it once, watchdog armed or not).
-    Returns the probe result, or None when the watchdog is not armed."""
+    in-process init will surface the real exception).
+
+    A chip belongs to one process: once this process has initialised its
+    backend it holds the chip, the probe child could not take it, and its
+    failure would read as "wedged".  So the probe is refused then — the
+    live in-process backend is the better evidence anyway.
+    Returns the probe result, or None when the watchdog is not armed or
+    the probe was refused."""
     if os.environ.get(WATCHDOG_ENV, "0") in ("", "0"):
+        return None
+    if _backend_initialised():
+        _say("backend watchdog: this process already initialised its jax "
+             "backend and holds the device; not probing from a second "
+             "process (arm the watchdog before the first jax call)")
         return None
     params = params or {}
     budget = float(params.get("tpu_probe_timeout", default_timeout()) or
@@ -177,13 +193,16 @@ def preflight(params: Optional[Dict] = None) -> Optional[ProbeResult]:
             "python -m lightgbm_tpu.resilience.watchdog to re-check, or "
             "set JAX_PLATFORMS=cpu for the CPU fallback)")
     if res.verdict == "error":
-        _warn = f"backend watchdog probe errored: {res.error}"
-        try:
-            from ..utils.log import Log
-            Log.warning(_warn)
-        except ImportError:      # loaded standalone (no package parent)
-            sys.stderr.write(f"[watchdog] {_warn}\n")
+        _say(f"backend watchdog probe errored: {res.error}")
     return res
+
+
+def _say(msg: str) -> None:
+    try:
+        from ..utils.log import Log
+        Log.warning(msg)
+    except ImportError:      # loaded standalone (no package parent)
+        sys.stderr.write(f"[watchdog] {msg}\n")
 
 
 # --------------------------------------------- multiprocess capability probe

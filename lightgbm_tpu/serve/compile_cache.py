@@ -99,10 +99,14 @@ class CompileCache:
         return os.path.join(self.root, key + ENTRY_SUFFIX)
 
     # ------------------------------------------------------------ load/store
-    def load(self, key: str):
+    def load(self, key: str, devices=None):
         """Deserialized compiled executable for ``key``, or None (miss /
         corrupt / version-stale — the latter two unlinked so the caller's
-        fresh compile rebuilds the entry)."""
+        fresh compile rebuilds the entry).  ``devices`` are the devices
+        the executable was compiled for and will run on; jax's default is
+        every local device, which on a multi-device host loads a
+        one-device program that then demands one argument shard per
+        device."""
         path = self._path(key)
         if not os.path.exists(path):
             self.misses += 1
@@ -116,7 +120,8 @@ class CompileCache:
                     f"{meta.get('versions')}, running {_versions()})")
             from jax.experimental.serialize_executable import \
                 deserialize_and_load
-            compiled = deserialize_and_load(payload, in_tree, out_tree)
+            compiled = deserialize_and_load(payload, in_tree, out_tree,
+                                            execution_devices=devices)
         except Exception as e:  # noqa: BLE001 — any bad entry: warn+rebuild
             self.errors += 1
             self.misses += 1

@@ -109,7 +109,8 @@ class PredictPlan:
         self.traverse_mode, self.traverse_degrade = _resolve_traverse(
             model, traverse, self.quantize_mode, self._packs,
             self.num_features)
-        self._interpret = jax.default_backend() != "tpu"
+        from ..ops.pallas_common import interpret_mode
+        self._interpret = interpret_mode()
         self.stack_count = 1          # re-stacks would increment (never do)
         # Resident bytes for this plan (tree pack — quantized or fp32 —
         # + bin tables + NaN routing) — the per-plan half of the serve
@@ -243,7 +244,9 @@ class PredictPlan:
     def _aot_compile(self, kind: str, key: tuple, args):
         from .compile_cache import entry_key
         ck = entry_key(self.identity, kind, key[1])
-        compiled = self._ccache.load(ck)
+        home = sorted(jax.tree.leaves(self._arrays)[0].devices(),
+                      key=lambda d: d.id)
+        compiled = self._ccache.load(ck, devices=home)
         fresh = compiled is None
         if fresh:
             jit_fn = self._jit_bits if kind == "bits" else self._jit_binned

@@ -241,7 +241,7 @@ int main(void) {
          f"-Wl,-rpath,{os.path.dirname(_LIB)}"],
         check=True, capture_output=True)
     env = dict(os.environ,
-               LIGHTGBM_TPU_PLATFORM="cpu",
+               JAX_PLATFORMS="cpu",
                LIGHTGBM_TPU_PKG_DIR=pkg_root,
                PYTHONPATH=pkg_root + os.pathsep
                + os.environ.get("PYTHONPATH", ""))

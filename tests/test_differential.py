@@ -415,7 +415,7 @@ def test_weight_column_cli_parity(tmp_path):
         conf.write_text("".join(f"{k} = {v}\n" for k, v in full.items())
                         + f"data = {tr}\noutput_model = "
                         f"{tmp_path}/{out_model}.txt\n")
-        env = dict(os.environ, LIGHTGBM_TPU_PLATFORM="cpu")
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         r = sp.run([*cmd_prefix, f"config={conf}"], capture_output=True,
                    text=True, env=env)
         assert r.returncode == 0, r.stderr[-1500:]

@@ -356,10 +356,12 @@ def test_degenerate_stop_immediate_with_dart():
     assert bst.num_trees() == 1
 
 
-def test_mosaic_compile_failure_degrades_to_onehot(monkeypatch):
-    """A Pallas/Mosaic kernel compile failure mid-training must degrade to
-    the XLA one-hot histogram (with a warning) and produce the same model,
-    not crash (docs/PERF.md round 5: layout legality is invisible off-TPU)."""
+def test_mosaic_compile_failure_raises_and_names_optouts(monkeypatch):
+    """A Pallas/Mosaic kernel compile failure must reach the user — with
+    the compiler's own first line and the explicit opt-outs named — and
+    never be retried on a slower implementation (ISSUE-21: the silent
+    degrade to the XLA one-hot hid that the default TPU path did not
+    compile at all)."""
     from lightgbm_tpu.ops import pallas_histogram
 
     def boom(*a, **k):
@@ -370,13 +372,16 @@ def test_mosaic_compile_failure_degrades_to_onehot(monkeypatch):
     monkeypatch.setattr(pallas_histogram, "histogram_flat", boom)
     X, y = make_regression(n_samples=600, n_features=6, noise=0.1,
                            random_state=3)
-    params = {"objective": "regression", "verbosity": -1, "num_leaves": 15,
-              "tpu_histogram_impl": "pallas"}
-    bst = lgb.train(params, lgb.Dataset(X, label=y), 8)
-    ref = lgb.train({**params, "tpu_histogram_impl": "onehot"},
-                    lgb.Dataset(X, label=y), 8)
-    np.testing.assert_allclose(bst.predict(X), ref.predict(X),
-                               rtol=1e-6, atol=1e-6)
+    for impl in ("pallas", "flat"):
+        with pytest.raises(RuntimeError) as ei:
+            lgb.train({"objective": "regression", "verbosity": -1,
+                       "num_leaves": 15, "tpu_histogram_impl": impl},
+                      lgb.Dataset(X, label=y), 8)
+        msg = str(ei.value)
+        assert "unsupported shape cast (simulated)" in msg
+        assert "tpu_histogram_impl=onehot" in msg
+        assert "tpu_wave_kernel=unfused" in msg
+        assert isinstance(ei.value.__cause__, RuntimeError)
 
 
 def test_explicit_impl_failure_raises(monkeypatch):

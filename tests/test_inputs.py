@@ -140,7 +140,7 @@ sys.exit(0 if delta_mb < 900 else 1)
 """
     r = subprocess.run([sys.executable, "-u", "-c", code],
                        capture_output=True, text=True, timeout=600,
-                       env={**os.environ, "LIGHTGBM_TPU_PLATFORM": "cpu"})
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, r.stdout + r.stderr
 
 
@@ -302,7 +302,7 @@ def test_header_names_propagate_to_model(tmp_path):
     np.savetxt(path, mat, delimiter=",", fmt="%.8g",
                header="lab,wt,alpha,beta", comments="")
     out = tmp_path / "m.txt"
-    env = dict(os.environ, LIGHTGBM_TPU_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run(
         [sys.executable, "-m", "lightgbm_tpu", "task=train",
          "objective=binary", "header=true", f"data={path}",

@@ -161,3 +161,43 @@ def test_native_predict_multiclass():
     assert p.shape == (600, 3)
     np.testing.assert_allclose(p.sum(axis=1), 1.0, rtol=1e-5)
     assert (p.argmax(axis=1) == y).mean() > 0.7
+
+
+def test_cached_library_is_keyed_by_source_not_mtime(tmp_path):
+    """utils/sobuild: the cached .so is named by a hash of its source (and
+    whatever the build bakes in), so a binary built from other source — or
+    for another checkout path — cannot load, whatever the file times say
+    (a copy or a checkout makes them arbitrary)."""
+    import shutil
+
+    from lightgbm_tpu.utils.sobuild import cached_library
+
+    if shutil.which("g++") is None:
+        pytest.skip("no g++")
+    src = tmp_path / "csrc" / "lib.cpp"
+    src.parent.mkdir()
+    src.write_text('extern "C" int answer() { return 1; }\n')
+
+    def cmd(out):
+        return ["g++", "-shared", "-fPIC", "-o", out, str(src)]
+
+    first = cached_library(str(src), "_lib", cmd, baked="/checkout/a")
+    assert first and os.path.dirname(first) == str(tmp_path)
+    assert cached_library(str(src), "_lib", cmd, baked="/checkout/a") == first
+    # an OLDER source file with different text still rebuilds
+    src.write_text('extern "C" int answer() { return 2; }\n')
+    os.utime(src, (0, 0))
+    second = cached_library(str(src), "_lib", cmd, baked="/checkout/a")
+    assert second and second != first
+    assert not os.path.exists(first)          # the stale binary is gone
+    import ctypes
+    assert ctypes.CDLL(second).answer() == 2
+    # same source, another baked-in path: another binary
+    third = cached_library(str(src), "_lib", cmd, baked="/checkout/b")
+    assert third not in (first, second)
+    # a failing toolchain reports None and leaves no partial file behind
+    assert cached_library(str(src), "_lib",
+                          lambda out: ["g++", "--no-such-flag", str(src)],
+                          baked="/checkout/c") is None
+    assert sorted(os.listdir(tmp_path)) == ["_lib-" + third.split("_lib-")[1],
+                                            "csrc"]

@@ -285,7 +285,7 @@ def test_two_round_loading_matches_direct(tmp_path):
          "num_iterations=5", "two_round=true", "verbosity=-1",
          "max_bin=63", f"output_model={model}"],
         capture_output=True, text=True, timeout=600,
-        env={**os.environ, "LIGHTGBM_TPU_PLATFORM": "cpu",
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
              "PYTHONPATH": os.pathsep.join(
                  [os.path.dirname(os.path.dirname(os.path.abspath(
                      __file__)))] + os.environ.get(

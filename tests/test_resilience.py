@@ -386,12 +386,32 @@ def test_engine_preflight_wedged_raises(monkeypatch):
     BEFORE the trainer touches the device — within the probe budget."""
     monkeypatch.setenv(watchdog.WATCHDOG_ENV, "1")
     monkeypatch.setenv(faults.ENV_VAR, "wedge_dispatch:600")
+    # the test process initialised its backend long ago; stand in for a
+    # fresh one, where the preflight is allowed to probe
+    monkeypatch.setattr(watchdog, "_backend_initialised", lambda: False)
     X, y = _data(100, 4)
     t0 = time.time()
     with pytest.raises(watchdog.BackendWedgedError, match="wedged"):
         lgb.train(dict(BASE, tpu_probe_timeout=1.5),
                   lgb.Dataset(X, label=y), num_boost_round=1)
     assert time.time() - t0 < 30.0
+
+
+def test_engine_preflight_refuses_once_backend_is_live(monkeypatch, capsys):
+    """One process per chip: a process whose backend is initialised holds
+    the device, so an armed preflight must NOT start a probe child (whose
+    failure to take the chip would read as "wedged") — it says so and
+    training proceeds."""
+    import jax
+    jax.devices()
+    monkeypatch.setenv(watchdog.WATCHDOG_ENV, "1")
+    monkeypatch.setattr(
+        watchdog, "probe_backend",
+        lambda *a, **k: pytest.fail("probe child started under a live "
+                                    "in-process backend"))
+    assert watchdog.preflight({"tpu_probe_timeout": 1.0}) is None
+    out = capsys.readouterr()
+    assert "not probing from a second process" in out.out + out.err
 
 
 def test_unknown_fault_name_ignored():

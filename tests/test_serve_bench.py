@@ -18,7 +18,6 @@ def test_serve_bench_smoke():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(
         os.environ,
-        LIGHTGBM_TPU_PLATFORM="cpu",
         JAX_PLATFORMS="cpu",
         SERVE_BENCH_ROWS="1500",
         SERVE_BENCH_ITERS="3",

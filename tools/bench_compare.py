@@ -15,10 +15,9 @@ name), compares each consecutive pair of metric-bearing rounds, and
 reports rounds with no salvageable metric (wedged attempts) instead of
 dying on them.
 
-**Platform honesty** (the PR-6 ``detail.probe`` block): a CPU-fallback
-blob is NEVER comparable to a live-accelerator blob — the r02 (TPU) ->
-r03+ (CPU fallback, wedged plugin) discontinuity in this repo's own
-trajectory is a ~30x throughput cliff that is a backend event, not a code
+**Platform honesty** (the ``detail.device`` block): a CPU blob is NEVER
+comparable to an accelerator blob — a TPU round followed by a CPU round
+is a ~30x throughput cliff that is a backend event, not a code
 regression.  Pair mode REFUSES such a comparison (exit 3); trajectory
 mode flags the pair ``probe-mismatch`` and skips it.
 
@@ -110,12 +109,11 @@ def _dig(d, *path):
 
 
 def load_blob(path: str) -> Optional[dict]:
-    """Load one metric blob.  Accepts three shapes: a raw bench.py metric
-    line (``{"metric": ..., "detail": ...}``), a driver wrapper
+    """Load one metric blob.  Accepts two shapes: a raw bench.py metric
+    line (``{"metric": ..., "detail": ...}``) and a driver wrapper
     (``BENCH_r*.json``: the metric blob under ``"parsed"`` — ``null`` for
-    rounds whose metric line was lost to a wedge), and a
-    ``bench_result.json`` side file (under ``"result"``).  Returns None
-    for a wrapper whose round salvaged no metric."""
+    rounds that produced no metric line).  Returns None for a wrapper
+    whose round has no metric."""
     with open(path) as fh:
         obj = json.load(fh)
     if not isinstance(obj, dict):
@@ -127,18 +125,19 @@ def load_blob(path: str) -> Optional[dict]:
         if parsed is not None and "metric" not in parsed:
             raise ValueError(f"{path}: 'parsed' is not a metric blob")
         return parsed
-    if "result" in obj:
-        return obj["result"]
-    raise ValueError(f"{path}: no metric blob (expected a bench.py line, "
-                     f"a BENCH_r*.json wrapper or bench_result.json)")
+    raise ValueError(f"{path}: no metric blob (expected a bench.py line "
+                     f"or a BENCH_r*.json wrapper)")
 
 
 def blob_platform(blob: dict) -> str:
-    """Effective backend, preferring the watchdog probe's verdict block
-    over the self-reported platform tag."""
+    """The backend a blob was measured on: the ``detail.device`` identity
+    block bench.py writes (jax's own report), else an older blob's probe
+    verdict, else its self-reported platform tag."""
     d = blob.get("detail") or {}
+    device = d.get("device") or {}
     probe = d.get("probe") or {}
-    return str(probe.get("backend") or d.get("platform") or "unknown")
+    return str(device.get("platform") or probe.get("backend")
+               or d.get("platform") or "unknown")
 
 
 def is_cpu_fallback(blob: dict) -> bool:

@@ -20,6 +20,7 @@ def main():
     import jax.numpy as jnp
 
     from lightgbm_tpu.ops.histogram import pack_bins4
+    from lightgbm_tpu.ops.pallas_common import interpret_mode
     from lightgbm_tpu.ops.pallas_histogram import histogram_flat
 
     rng = np.random.RandomState(0)
@@ -27,7 +28,7 @@ def main():
     vals = jnp.asarray(rng.randn(rows, 3).astype(np.float32))
     packed = pack_bins4(bins)
     B = 16
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
 
     def rate(fn, reps=10):
         fn().block_until_ready()                  # compile

@@ -27,11 +27,14 @@ _DESCRIPTIONS = {
     "tree_learner": (
         "serial, or data/feature/voting — which device-mesh sharding the "
         "tree learner uses (parallel/mesh.py)"),
-    "device_type": "tpu (any jax backend; cpu runs the identical programs)",
+    "device_type": (
+        "tpu (any jax backend; cpu runs the identical programs) — but an "
+        "EXPLICIT tpu|gpu|cuda that resolves to the cpu backend is an "
+        "error"),
     "tpu_histogram_impl": (
         "histogram kernel: auto|pallas|flat_bf16|onehot|segment (auto = "
-        "pallas on TPU with runtime degrade to onehot on a Mosaic compile "
-        "failure)"),
+        "pallas on TPU, segment elsewhere; a kernel that fails to compile "
+        "raises — onehot is the explicit XLA opt-out, never a fallback)"),
     "tpu_rows_block": "rows per histogram-kernel block",
     "tpu_4bit_bins": (
         "auto 4-bit bin packing when every feature fits 16 bins "

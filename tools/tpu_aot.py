@@ -1,0 +1,138 @@
+"""Compile the Pallas kernels for a TPU v5e WITHOUT a chip.
+
+``jax.experimental.topologies`` hands out compile-only TPU devices from the
+installed libtpu, so ``jit(f).lower(...).compile()`` runs the full XLA TPU
+pipeline — Mosaic's layout inference and VMEM allocation included — on a
+CPU-only box.  That catches the class of error ``lower(lowering_platforms=
+("tpu",))`` cannot see (tests/test_tpu_lowering.py runs both).  It compiles;
+it does not execute: whether the compiled kernel computes the right answer
+is ``chip_smoke.py``'s job, on the chip.
+
+    python tools/tpu_aot.py            # the three kernels, Higgs shape
+
+prints one ``[OK]``/``[FAIL]`` line per kernel (with the compiler's message)
+and exits non-zero when any failed, or 3 when libtpu offers no topology.
+"""
+
+from __future__ import annotations
+
+import functools
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+TOPOLOGY = "v5e:2x2"        # smallest host libtpu describes; one device used
+
+
+def tpu_sharding():
+    """A single-device sharding on a compile-only v5e device."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    # libtpu reads these at load; without them it logs errors about a
+    # missing accelerator type before describing the topology anyway
+    os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name=TOPOLOGY)
+    return NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)),
+                         PartitionSpec())
+
+
+def compile_for_tpu(fn, *args):
+    """AOT-compile ``fn`` at ``args`` (``jax.ShapeDtypeStruct``s carrying
+    :func:`tpu_sharding`); returns the compiled executable or raises the
+    compiler's error."""
+    import jax
+    return jax.jit(fn).lower(*args).compile()
+
+
+def higgs_kernel_cases(sharding):
+    """(name, fn, args) for the three kernels at the Higgs headline shape:
+    28 features, max_bin=255, 255 leaves, leaf_batch=16 — the shapes
+    ``chip_smoke.py`` runs."""
+    import jax
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops.pallas_common import C_PAD
+    from lightgbm_tpu.ops.pallas_histogram import histogram_flat
+    from lightgbm_tpu.ops.pallas_traverse import fused_class_sums
+    from lightgbm_tpu.ops.pallas_wave import (STAT_LANES, fused_wave_call,
+                                              wave_layout)
+    from lightgbm_tpu.ops.split import SplitConfig
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    f, b, leaves, w, n = 28, 255, 255, 16, 65536
+    cases = []
+    for dtype, vd in (("f32", jnp.float32), ("int8", jnp.int8)):
+        cases.append((
+            f"histogram_flat {dtype} B={b}",
+            functools.partial(histogram_flat, num_bins=b, dtype=dtype),
+            (sds((n, f), jnp.uint8), sds((n, 3), vd))))
+    cases.append((
+        "histogram_flat f32 packed4 B=15",
+        functools.partial(histogram_flat, num_bins=15, dtype="f32",
+                          packed4=True, features=f),
+        (sds((n, f // 2), jnp.uint8), sds((n, 3), jnp.float32))))
+    scfg = SplitConfig(min_data_in_leaf=0, min_sum_hessian_in_leaf=100.0,
+                       has_nan=True, has_categorical=False,
+                       use_sorted_categorical=False, has_monotone=False)
+    for dtype, vd in (("f32", jnp.float32), ("int8", jnp.int8)):
+        lay = wave_layout(f, b, dtype)
+        fb = lay["ftile"] * lay["b_pad"]
+        acc = jnp.int32 if dtype == "int8" else jnp.float32
+        args = [sds((w, n, lay["cols_tile"]), jnp.uint8),
+                sds((w, C_PAD, n), vd), sds((w, C_PAD, fb), acc),
+                sds((w, 2, STAT_LANES), jnp.float32),
+                sds((lay["ftile"], 8), jnp.int32)]
+        if dtype == "int8":
+            args.append(sds((1, 4), jnp.float32))
+        cases.append((
+            f"fused_wave_call {dtype}",
+            functools.partial(fused_wave_call, num_bins=b, features=f,
+                              rows_block=16384, dtype=dtype, scfg=scfg),
+            tuple(args)))
+
+    t, m, bb = 8, leaves - 1, 32
+
+    def traverse(sf, sb, dl, ic, cb, lc, rc, lq, bins, nanb):
+        pack = dict(split_feature=sf, split_bin=sb, default_left=dl,
+                    is_cat=ic, cat_bits=cb, left_child=lc, right_child=rc,
+                    leaf_q=lq, num_bins=256, depth=32)
+        return fused_class_sums(pack, bins, nanb)
+
+    i16, u8 = jnp.int16, jnp.uint8
+    cases.append((
+        f"fused_class_sums {t}x{leaves}-leaf int8 pack",
+        traverse,
+        (sds((t, m), i16), sds((t, m), i16), sds((t, m), u8),
+         sds((t, m), u8), sds((t, m, bb), u8), sds((t, m), i16),
+         sds((t, m), i16), sds((t, leaves), jnp.int8),
+         sds((8192, f), u8), sds((f,), jnp.int32))))
+    return cases
+
+
+def main() -> int:
+    try:
+        sharding = tpu_sharding()
+    except Exception as e:  # noqa: BLE001 — no libtpu / no topology support
+        print(f"tpu_aot: no compile-only TPU topology here: {e!r}"[:300])
+        return 3
+    failed = 0
+    for name, fn, args in higgs_kernel_cases(sharding):
+        try:
+            compile_for_tpu(fn, *args)
+            print(f"[OK] {name}", flush=True)
+        except Exception as e:  # noqa: BLE001 — report every kernel
+            failed += 1
+            print(f"[FAIL] {name}: {type(e).__name__}: {str(e)[:1500]}",
+                  flush=True)
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
