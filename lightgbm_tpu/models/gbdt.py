@@ -24,7 +24,7 @@ import numpy as np
 from ..config import Config
 from ..dataset import TrainData
 from ..metrics import Metric
-from ..telemetry import span, watch_compiles
+from ..telemetry import phase, span, watch_compiles
 from ..objectives import ObjectiveFunction, create_objective
 from ..sampling import FeatureSampler, SampleStrategy
 from ..ops.split import SplitConfig
@@ -568,7 +568,8 @@ class GBDT:
         elif self.objective.stochastic_gradients:
             self._grad_fn = self.objective.get_gradients
         else:
-            self._grad_fn = jax.jit(self.objective.get_gradients)
+            self._grad_fn = jax.jit(
+                phase("boost/gradients")(self.objective.get_gradients))
         self._build_iter_fns()
 
     def _build_iter_fns(self) -> None:
@@ -593,21 +594,24 @@ class GBDT:
                 meta["is_categorical"], meta["monotone"],
                 cegb_coupled, cegb_lazy, quant_key, split_key,
                 self._fg_dev, self._fo_dev)
-            grew = arrays.num_leaves > 1
-            lv = jnp.where(grew, arrays.leaf_value * shrink, 0.0)
-            # Defined rounding for the score update (docs/STREAMING.md):
-            # without the barrier XLA may (or may not, per surrounding
-            # graph) refuse to materialize lv and instead fuse the shrink
-            # multiply into the gather+add as an FMA — a per-program
-            # 1-ULP coin flip.  The barrier pins the semantics to
-            # "materialized lv, then one exact add per row", the ONE
-            # arithmetic every path (fused/unfused/pack/streamed)
-            # reproduces, which is what makes streamed==in-core bitwise
-            # provable instead of fusion-heuristic-dependent.
-            lv = jax.lax.optimization_barrier(lv)
-            arrays = arrays._replace(
-                leaf_value=lv, internal_value=arrays.internal_value * shrink)
-            return scores_k + lv[row_leaf], arrays, row_leaf
+            with phase("boost/score_update"):
+                grew = arrays.num_leaves > 1
+                lv = jnp.where(grew, arrays.leaf_value * shrink, 0.0)
+                # Defined rounding for the score update
+                # (docs/STREAMING.md): without the barrier XLA may (or may
+                # not, per surrounding graph) refuse to materialize lv and
+                # instead fuse the shrink multiply into the gather+add as
+                # an FMA — a per-program 1-ULP coin flip.  The barrier pins
+                # the semantics to "materialized lv, then one exact add per
+                # row", the ONE arithmetic every path
+                # (fused/unfused/pack/streamed) reproduces, which is what
+                # makes streamed==in-core bitwise provable instead of
+                # fusion-heuristic-dependent.
+                lv = jax.lax.optimization_barrier(lv)
+                arrays = arrays._replace(
+                    leaf_value=lv,
+                    internal_value=arrays.internal_value * shrink)
+                return scores_k + lv[row_leaf], arrays, row_leaf
 
         self._grow_apply = jax.jit(grow_apply)
 
@@ -635,16 +639,17 @@ class GBDT:
                       split_key=None, it=None, goss_key=None,
                       cegb_used=None):
                 from ..sampling import goss_mask_device
-                grad, hess = obj.get_gradients(scores)
-                if goss_in_trace:
-                    # Same score/key stream as the standalone device mask
-                    # (_iter_masks): |g*h| summed across classes, key
-                    # folded by the absolute iteration number.
-                    gs = grad.reshape(n_rows, -1).sum(axis=1)
-                    hs = hess.reshape(n_rows, -1).sum(axis=1)
-                    mask = goss_mask_device(
-                        gs, hs, jax.random.fold_in(goss_key, it),
-                        goss_top_k, goss_other_k, goss_amp)
+                with phase("boost/gradients"):
+                    grad, hess = obj.get_gradients(scores)
+                    if goss_in_trace:
+                        # Same score/key stream as the standalone device
+                        # mask (_iter_masks): |g*h| summed across classes,
+                        # key folded by the absolute iteration number.
+                        gs = grad.reshape(n_rows, -1).sum(axis=1)
+                        hs = hess.reshape(n_rows, -1).sum(axis=1)
+                        mask = goss_mask_device(
+                            gs, hs, jax.random.fold_in(goss_key, it),
+                            goss_top_k, goss_other_k, goss_amp)
                 coupled = lazy = None
                 if use_cegb:
                     coupled = cegb_coupled_raw * (~cegb_used)
@@ -675,9 +680,11 @@ class GBDT:
                     # folded into this same program, so the guard adds no
                     # extra dispatch (profile-census invariant)
                     from ..resilience.health import health_vector
-                    hv = health_vector(
-                        grad, hess,
-                        tuple(a.leaf_value for a, _rl in outs), new_scores)
+                    with phase("boost/score_update"):
+                        hv = health_vector(
+                            grad, hess,
+                            tuple(a.leaf_value for a, _rl in outs),
+                            new_scores)
                 if use_cegb:
                     new_used = cegb_used
                     if track_used:

@@ -74,6 +74,7 @@ import numpy as np
 from ..ops.histogram import histogram_from_vals, unpack_bins4
 from ..ops.split import (BestSplit, SplitConfig, best_split, leaf_gain,
                          leaf_output, smoothed_output, sync_best_split)
+from ..telemetry.spans import kernel_rows, phase
 
 _NEG_INF = -jnp.inf
 _MIN_BUCKET = 2048
@@ -290,6 +291,14 @@ class _GrowState(NamedTuple):
     tree: TreeArrays
 
 
+@phase("grow/reduce")
+def _psum(x, axis):
+    """Every cross-shard sum of the learners goes through here, so a device
+    trace finds the collectives under one name (``grow/reduce``)."""
+    return jax.lax.psum(x, axis)
+
+
+@phase("grow/update")
 def _store_best(state: _GrowState, leaf: jnp.ndarray, bs: BestSplit,
                 depth_ok: jnp.ndarray) -> _GrowState:
     gain = jnp.where(depth_ok, bs.gain, _NEG_INF)
@@ -575,6 +584,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             fmask = jnp.where(jnp.any(sel & fmask), fmask & sel, fmask)
         return fmask, rand_bins
 
+    @phase("grow/scan")
     def _best_for(hist, pg, ph, pc, meta, feature_mask, penalty=None,
                   parent_out=None, key=None, path=None, groups_mat=None,
                   out_lo=None, out_hi=None, leaf_depth=None, rs=None):
@@ -628,6 +638,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             fmaskk = fmaskk & _allowed_for_paths(pathk, groups_mat)
         return fmaskk, randk
 
+    @phase("grow/scan")
     def _best_for_batch(histk, pgk, phk, pck, meta, feature_mask,
                         penaltyk=None, parent_outk=None, key=None,
                         pathk=None, groups_mat=None, boundsk=None,
@@ -765,6 +776,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         bookkeeping, shared by the perm (W=1) and wave (W>1) bodies."""
         IMAX = jnp.iinfo(jnp.int32).max
 
+        @phase("grow/update")
         def claim(st, sp, active, miss):
             """Claim pool slots for W splitting leaves: each active leaf j
             needs one fresh slot for its smaller child's histogram; the
@@ -808,6 +820,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 jnp.where(ev >= 0, ev, L)].set(-1, mode="drop")
             return st._replace(leaf_slot=leaf_slot), ss, sb
 
+        @phase("grow/update")
         def assign(st, children, slots):
             """Record ownership + LRU stamps for 2W (child leaf, slot)
             pairs; sentinel indices (leaf >= L / slot >= P) drop."""
@@ -828,11 +841,12 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         pool_on = P < L
         pool_claim, pool_assign = _pool_ops(P) if pool_on else (None, None)
 
+        @phase("grow/reduce")
         def reduce_hist(h):
             if axis is None:
                 return h
             return rs["scatter"](h) if rs is not None \
-                else jax.lax.psum(h, axis)
+                else _psum(h, axis)
 
         return P, pool_on, pool_claim, pool_assign, reduce_hist
     if inter and cfg.voting:
@@ -857,6 +871,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
     if cfg.packed4 and (cfg.bundled or fp_capable):
         raise ValueError("packed4 bins do not compose with EFB bundling or "
                          "the feature-parallel layout (caller gates this)")
+    @phase("grow/scan")
     def _vote_best_batch(hist_loc, pgk, phk, pck, poutk, scale3, meta,
                          feature_mask, boundsk, depthk, axis,
                          penaltyk=None, key=None, pathk=None,
@@ -916,8 +931,8 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         _, top_idx = jax.lax.top_k(fg, kk)
         votes = jnp.zeros((k_child, f), jnp.int32).at[
             jnp.arange(k_child)[:, None], top_idx].add(1)
-        votes = jax.lax.psum(votes, axis)
-        gsum = jax.lax.psum(jnp.where(jnp.isfinite(fg), fg, 0.0), axis)
+        votes = _psum(votes, axis)
+        gsum = _psum(jnp.where(jnp.isfinite(fg), fg, 0.0), axis)
         # Rank by votes with gain strictly as tie-break (reference
         # GlobalVoting orders by vote count): normalize gains into [0, 1)
         # so they can never outweigh one vote.
@@ -930,13 +945,13 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             # expansion already happened (linear, psum-compatible)
             hist_sel = jnp.take_along_axis(
                 hist_loc_s, sel[:, :, None, None], axis=1)
-            hist_sel = jax.lax.psum(hist_sel, axis)    # ONLY winners cross
+            hist_sel = _psum(hist_sel, axis)    # ONLY winners cross
         else:
             # psum the RAW slices (integer tensors under quantized
             # training, bin.h:48-81); scale after the reduce.
             hist_sel = jnp.take_along_axis(
                 hist_loc, sel[:, :, None, None], axis=1)
-            hist_sel = _scale_hist(jax.lax.psum(hist_sel, axis), scale3)
+            hist_sel = _scale_hist(_psum(hist_sel, axis), scale3)
 
         def one(h, pg, ph, pc, po, selj, lo, hi, dep, fm, rb, pen):
             bs = best_split(
@@ -1036,6 +1051,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             tree=tree,
         )
 
+    @phase("grow/update")
     def _update_tree(st: _GrowState, leaf, new_leaf, node, pg, ph, pc):
         """Shared tree bookkeeping for one executed split."""
         tr = st.tree
@@ -1060,6 +1076,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             internal_count=tr.internal_count.at[node].set(pc),
         )
 
+    @phase("grow/finish")
     def _finish(state: _GrowState) -> TreeArrays:
         leaf_ids = jnp.arange(L)
         active = leaf_ids < state.num_leaves
@@ -1073,6 +1090,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             num_leaves=state.num_leaves,
         )
 
+    @phase("grow/update")
     def _children_updates(st, leaf, new_leaf, hist_left, hist_right,
                           gl, hl, cl, gr, hr, cr, meta, feature_mask,
                           cegb=None, groups_mat=None, scale3=None,
@@ -1379,6 +1397,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         upper = adj & ((inc & (o_hi <= t_lo)) | (dec & (t_hi <= o_lo)))
         return jnp.any(upper, axis=-1) & alive[:, None] & alive[None, :]
 
+    @phase("grow/scan")
     def _inter_refresh(st, scale3, meta, feature_mask, cegb=None,
                        groups_mat=None):
         """Intermediate monotone mode, per-step bound + best-split refresh.
@@ -1506,6 +1525,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         gp = go * rs_shards
         g_lo = (jax.lax.axis_index(axis) * go).astype(jnp.int32)
 
+        @phase("grow/reduce")
         def scatter(h):
             d = h.ndim - 3                     # the feature axis of (…,G,B,3)
             if gp != hist_cols:
@@ -1517,7 +1537,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 # sum elementwise, so fitting int16 here is exact — no
                 # overflow at any reduction step.  f32 compare is exact for
                 # ints < 2^24; anything larger fails the guard anyway.
-                bound = jax.lax.psum(
+                bound = _psum(
                     jnp.max(jnp.abs(h)).astype(jnp.float32), axis)
                 from ..resilience import faults
                 if faults.active("overflow_hist"):
@@ -1578,7 +1598,8 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         return {
             "go": go, "gp": gp, "g_lo": g_lo, "own_f": own_f,
             "scatter": scatter, "meta_s": meta_s, "project": project,
-            "sync": lambda bs: _fp_sync_best(bs, foff, axis, rs_shards),
+            "sync": phase("grow/reduce")(
+                lambda bs: _fp_sync_best(bs, foff, axis, rs_shards)),
         }
 
     def _fp_go_left(bins_pad, nan_bins, feat_g, sbin, dleft, scat, cmask,
@@ -1595,7 +1616,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         gl = jnp.where(scat, cmask[col], col <= sbin)
         gl = jnp.where(is_nan & ~scat, dleft, gl)
         gl = jnp.where(owns, gl, False)
-        return jax.lax.psum(gl.astype(jnp.float32), faxis) > 0.5
+        return _psum(gl.astype(jnp.float32), faxis) > 0.5
 
     def _partition_scatter(perm, start, seg, valid, go_left, S):
         """Stable two-way partition of a contiguous perm slice given its
@@ -1617,6 +1638,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         """Partition branch over a precomputed row-id-indexed go-left
         vector (feature-parallel path: the split column lives on one
         shard; see _fp_go_left)."""
+        @phase("grow/partition")
         def branch(perm, start, cnt, glv):
             seg = jax.lax.dynamic_slice(perm, (start,), (S,))
             valid = jnp.arange(S, dtype=jnp.int32) < cnt
@@ -1627,6 +1649,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         """Partition one leaf's contiguous perm slice of static size S
         (cheap S-ops; no histogram).  Shared by the perm and wave layouts.
         Under EFB the split feature's column is decoded from its bundle."""
+        @phase("grow/partition")
         def branch(perm, start, cnt, feat, sbin, dleft, scat, cmask):
             seg = jax.lax.dynamic_slice(perm, (start,), (S,))
             valid = jnp.arange(S, dtype=jnp.int32) < cnt
@@ -1698,6 +1721,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         smaller sibling — the larger one comes from parent-hist subtraction,
         the reference's FeatureHistogram::Subtract).  Padded slots hit the
         phantom zero row.  Shared by the perm and wave layouts."""
+        @phase("grow/hist")
         def branch(perm, start, cnt):
             seg = jax.lax.dynamic_slice(perm, (start,), (S,))
             valid = jnp.arange(S, dtype=jnp.int32) < cnt
@@ -1709,6 +1733,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 packed4=cfg.packed4, features=nf)
         return branch
 
+    @phase("grow/select")
     def _apply_forced(st, scale3, meta, hist_of=None):
         """When the current step has a pending forced split (reference
         ForceSplits, serial_tree_learner.cpp:620), overwrite that leaf's
@@ -1782,6 +1807,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         )
         return st, use, si
 
+    @phase("grow/update")
     def _record_forced_children(st, use, si, leaf, new_leaf):
         """Map the executed forced node's forced children onto the two
         result leaves."""
@@ -1817,6 +1843,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             bs = rs["sync"](bs)
         return state, bs
 
+    @phase("grow/setup")
     def _perm_setup(bins, vals, scale3, meta, feature_mask, cegb, key,
                     groups_mat=None, axis=None, rs=None, pool_slots=None):
         """Shared permutation-layout prologue: padded arrays, buckets, root
@@ -1846,7 +1873,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             # histograms LOCAL and reduces only vote winners;
             # reduce-scatter mode keeps only the owned feature block.
             root_hist = (rs["scatter"](root_hist) if rs is not None
-                         else jax.lax.psum(root_hist, axis))
+                         else _psum(root_hist, axis))
         if rs is not None:
             # Every feature's bins sum to the leaf totals; the owner of
             # histogram column 0 (shard 0) computes them from its reduced
@@ -1854,13 +1881,13 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             # value the allreduce path would see.
             tot0 = jnp.sum(_scale_hist(root_hist[0:1], scale3)[0], axis=0)
             mine0 = jax.lax.axis_index(axis) == 0
-            root_tot = jax.lax.psum(
+            root_tot = _psum(
                 jnp.where(mine0, tot0, jnp.zeros_like(tot0)), axis)
         else:
             root_tot = jnp.sum(_scale_hist(root_hist[0:1], scale3)[0],
                                axis=0)
             if voting:
-                root_tot = jax.lax.psum(root_tot, axis)
+                root_tot = _psum(root_tot, axis)
         root_g, root_h, root_c = root_tot[0], root_tot[1], root_tot[2]
         # leaf_hist columns live in HISTOGRAM feature space, which under
         # packed4 is the unpacked F (bins columns are nibble pairs) and
@@ -1895,6 +1922,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         state = _store_best(state, jnp.asarray(0), root_bs, jnp.asarray(True))
         return state, bins_pad, vals_pad, buckets, buckets_arr, max_bucket
 
+    @phase("grow/finish")
     def _row_leaf_from_perm(state, n, max_bucket):
         """row -> leaf assignment from the final grouped permutation:
         position i belongs to the leaf whose [start, start+rows) range
@@ -1926,8 +1954,8 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         groups_mat = _groups_matrix(f) if use_groups else None
         foffset = (jax.lax.axis_index(faxis) * f if faxis is not None
                    else None)
-        fp_sync = (None if faxis is None else
-                   lambda bs: _fp_sync_best(bs, foffset, faxis, fp_shards))
+        fp_sync = (None if faxis is None else phase("grow/reduce")(
+            lambda bs: _fp_sync_best(bs, foffset, faxis, fp_shards)))
         fp_mono = None
         if faxis is not None and cfg.split.has_monotone:
             def fp_mono(feat_g):
@@ -1936,7 +1964,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 lf = feat_g - foffset
                 owns = (lf >= 0) & (lf < f)
                 m = jnp.where(owns, meta[3][jnp.clip(lf, 0, f - 1)], 0)
-                return jax.lax.psum(m, faxis)
+                return _psum(m, faxis)
         rs = None
         hist_cols = f if cfg.packed4 else bins.shape[1]
         if axis is not None and rs_on:
@@ -1998,74 +2026,83 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 lambda _: st.leaf_hist[jnp.clip(sl, 0, P - 1)], None)
 
         def body(st: _GrowState) -> _GrowState:
-            use_f = jnp.asarray(False)
-            si = jnp.asarray(0)
-            if n_forced:
-                st, use_f, si = _apply_forced(
-                    st, scale3, meta,
-                    hist_of=_pool_hist_of if pool_on else None)
-                leaf = jnp.where(use_f, st.forced_leaf[si],
-                                 jnp.argmax(st.best_gain)).astype(jnp.int32)
-            else:
-                leaf = jnp.argmax(st.best_gain).astype(jnp.int32)
-            node = st.num_leaves - 1
-            new_leaf = st.num_leaves
-            start = st.leaf_start[leaf]
-            cnt = st.leaf_rows[leaf]
-            pg, ph, pc = (st.leaf_sum_grad[leaf], st.leaf_sum_hess[leaf],
-                          st.leaf_count[leaf])
-            gl, hl, cl = st.best_gl[leaf], st.best_hl[leaf], st.best_cl[leaf]
-            gr, hr, cr = pg - gl, ph - hl, pc - cl
+            with phase("grow/select"):
+                use_f = jnp.asarray(False)
+                si = jnp.asarray(0)
+                if n_forced:
+                    st, use_f, si = _apply_forced(
+                        st, scale3, meta,
+                        hist_of=_pool_hist_of if pool_on else None)
+                    leaf = jnp.where(
+                        use_f, st.forced_leaf[si],
+                        jnp.argmax(st.best_gain)).astype(jnp.int32)
+                else:
+                    leaf = jnp.argmax(st.best_gain).astype(jnp.int32)
+                node = st.num_leaves - 1
+                new_leaf = st.num_leaves
+                start = st.leaf_start[leaf]
+                cnt = st.leaf_rows[leaf]
+                pg, ph, pc = (st.leaf_sum_grad[leaf], st.leaf_sum_hess[leaf],
+                              st.leaf_count[leaf])
+                gl, hl, cl = (st.best_gl[leaf], st.best_hl[leaf],
+                              st.best_cl[leaf])
+                gr, hr, cr = pg - gl, ph - hl, pc - cl
             if pool_on:
                 # Parent histogram BEFORE the partition reorders the
                 # segment: resident slot, or recompute-on-miss from the
                 # leaf's rows in their creation-time order.
-                sp = st.leaf_slot[leaf]
-                hist_parent = _pool_hist_of(st, leaf)
+                with phase("grow/hist"):
+                    sp = st.leaf_slot[leaf]
+                    hist_parent = _pool_hist_of(st, leaf)
 
-            if faxis is not None:
-                glv = _fp_go_left(
-                    bins_pad, nan_bins, st.best_feature[leaf],
-                    st.best_bin[leaf], st.best_default_left[leaf],
-                    st.best_is_cat[leaf], st.best_cat_mask[leaf],
-                    foffset, f, faxis)
-                perm, nl_phys = jax.lax.switch(
-                    _bucket_of(cnt), part_branches, st.perm, start, cnt,
-                    glv)
-            else:
-                perm, nl_phys = jax.lax.switch(
-                    _bucket_of(cnt), part_branches, st.perm, start, cnt,
-                    st.best_feature[leaf], st.best_bin[leaf],
-                    st.best_default_left[leaf], st.best_is_cat[leaf],
-                    st.best_cat_mask[leaf])
-            # Histogram ONLY the physically smaller child's contiguous range
-            # (its own, usually much smaller, bucket) — the expensive op scales
-            # with the smaller sibling, exactly like the reference's serial
-            # learner; the sibling comes from parent-hist subtraction.  Under
-            # a mesh the small/large choice must be GLOBAL so every shard
-            # histograms the same side.
-            if axis is None:
-                small_left = nl_phys <= cnt - nl_phys
-            else:
-                nl_g = jax.lax.psum(nl_phys, axis)
-                cnt_g = jax.lax.psum(cnt, axis)
-                small_left = nl_g <= cnt_g - nl_g
-            hs_start = jnp.where(small_left, start, start + nl_phys)
-            hs_cnt = jnp.where(small_left, nl_phys, cnt - nl_phys)
-            hist_small = jax.lax.switch(
-                _bucket_of(hs_cnt), hist_branches, perm, hs_start, hs_cnt)
+            with phase("grow/partition"):
+                if faxis is not None:
+                    glv = _fp_go_left(
+                        bins_pad, nan_bins, st.best_feature[leaf],
+                        st.best_bin[leaf], st.best_default_left[leaf],
+                        st.best_is_cat[leaf], st.best_cat_mask[leaf],
+                        foffset, f, faxis)
+                    perm, nl_phys = jax.lax.switch(
+                        _bucket_of(cnt), part_branches, st.perm, start, cnt,
+                        glv)
+                else:
+                    perm, nl_phys = jax.lax.switch(
+                        _bucket_of(cnt), part_branches, st.perm, start, cnt,
+                        st.best_feature[leaf], st.best_bin[leaf],
+                        st.best_default_left[leaf], st.best_is_cat[leaf],
+                        st.best_cat_mask[leaf])
+            with phase("grow/select"):
+                # Histogram ONLY the physically smaller child's contiguous
+                # range (its own, usually much smaller, bucket) — the
+                # expensive op scales with the smaller sibling, exactly like
+                # the reference's serial learner; the sibling comes from
+                # parent-hist subtraction.  Under a mesh the small/large
+                # choice must be GLOBAL so every shard histograms the same
+                # side.
+                if axis is None:
+                    small_left = nl_phys <= cnt - nl_phys
+                else:
+                    nl_g = _psum(nl_phys, axis)
+                    cnt_g = _psum(cnt, axis)
+                    small_left = nl_g <= cnt_g - nl_g
+                hs_start = jnp.where(small_left, start, start + nl_phys)
+                hs_cnt = jnp.where(small_left, nl_phys, cnt - nl_phys)
+            with phase("grow/hist"):
+                hist_small = jax.lax.switch(
+                    _bucket_of(hs_cnt), hist_branches, perm, hs_start, hs_cnt)
             if axis is not None:
                 # The reference's per-step histogram reduce: full psum
                 # (replicated scan) or feature-sliced reduce-scatter
                 # (slice-local scan + SplitInfo payload sync).
                 hist_small = (rs["scatter"](hist_small) if rs is not None
-                              else jax.lax.psum(hist_small, axis))
+                              else _psum(hist_small, axis))
 
-            if not pool_on:
-                hist_parent = st.leaf_hist[leaf]
-            hist_big = hist_parent - hist_small
-            hist_left = jnp.where(small_left, hist_small, hist_big)
-            hist_right = jnp.where(small_left, hist_big, hist_small)
+            with phase("grow/subtract"):
+                if not pool_on:
+                    hist_parent = st.leaf_hist[leaf]
+                hist_big = hist_parent - hist_small
+                hist_left = jnp.where(small_left, hist_small, hist_big)
+                hist_right = jnp.where(small_left, hist_big, hist_small)
 
             slots2 = None
             if pool_on:
@@ -2078,14 +2115,15 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                                     jnp.where(small_left, s_big, s_small)])
                 st = pool_assign(st, jnp.stack([leaf, new_leaf]), slots2)
 
-            tree = _update_tree(st, leaf, new_leaf, node, pg, ph, pc)
-            st = st._replace(
-                perm=perm,
-                tree=tree,
-                leaf_start=st.leaf_start.at[new_leaf].set(start + nl_phys),
-                leaf_rows=st.leaf_rows.at[leaf].set(nl_phys)
-                                      .at[new_leaf].set(cnt - nl_phys),
-            )
+            with phase("grow/update"):
+                tree = _update_tree(st, leaf, new_leaf, node, pg, ph, pc)
+                st = st._replace(
+                    perm=perm,
+                    tree=tree,
+                    leaf_start=st.leaf_start.at[new_leaf].set(start + nl_phys),
+                    leaf_rows=st.leaf_rows.at[leaf].set(nl_phys)
+                                          .at[new_leaf].set(cnt - nl_phys),
+                )
             st = _children_updates(st, leaf, new_leaf, hist_left,
                                     hist_right, gl, hl, cl, gr, hr, cr,
                                     meta, feature_mask, cegb, groups_mat,
@@ -2182,17 +2220,22 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 exactly), build + subtract + scan in VMEM, and return
                 ``(hist_left, hist_right, bs)`` with the 2W-child
                 BestSplit batch in the unfused path's cat2 ordering."""
-                parent_flat = hist_to_flat(parent_hist, _lay["ftile"],
-                                           _lay["b_pad"], _w_order)
-                sl2 = jnp.broadcast_to(
-                    small_left.astype(jnp.float32)[:, None], g2c.shape)
-                act2 = jnp.broadcast_to(
-                    active.astype(jnp.float32)[:, None], g2c.shape)
-                z2 = jnp.zeros_like(g2c)
-                stats = jnp.stack([g2c, h2c, c2c, o2c, sl2, act2, z2, z2],
-                                  axis=-1)                   # (W, 2, 8)
+                with phase("grow/wave_unpack"):
+                    parent_flat = hist_to_flat(parent_hist, _lay["ftile"],
+                                               _lay["b_pad"], _w_order)
+                    sl2 = jnp.broadcast_to(
+                        small_left.astype(jnp.float32)[:, None], g2c.shape)
+                    act2 = jnp.broadcast_to(
+                        active.astype(jnp.float32)[:, None], g2c.shape)
+                    z2 = jnp.zeros_like(g2c)
+                    stats = jnp.stack(
+                        [g2c, h2c, c2c, o2c, sl2, act2, z2, z2],
+                        axis=-1)                             # (W, 2, 8)
 
                 def branch_for(S):
+                    # the gather that feeds the kernel, and the kernel
+                    # launch, whose path ends in the rows it is handed
+                    @phase("grow/wave_gather")
                     def br(_):
                         seg = jax.vmap(
                             lambda s0: jax.lax.dynamic_slice(
@@ -2205,89 +2248,94 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                             jnp.pad(vals_pad[seg],
                                     ((0, 0), (0, 0), (0, C_PAD - 3))),
                             (0, 2, 1))                       # (W, C_PAD, S)
-                        return fused_wave_call(
-                            gbins, gvT, parent_flat, stats, wave_meta_w,
-                            wave_scale, num_bins=HB, features=f,
-                            rows_block=min(cfg.rows_block, S),
-                            dtype=wave_dtype, packed4=cfg.packed4,
-                            scfg=cfg.split, interpret=interpret_mode())
+                        with kernel_rows(W * S):
+                            return fused_wave_call(
+                                gbins, gvT, parent_flat, stats, wave_meta_w,
+                                wave_scale, num_bins=HB, features=f,
+                                rows_block=min(cfg.rows_block, S),
+                                dtype=wave_dtype, packed4=cfg.packed4,
+                                scfg=cfg.split, interpret=interpret_mode())
                     return br
 
-                bi = jnp.max(jnp.where(active, _bucket_of(small_cnt), 0))
+                with phase("grow/select"):
+                    bi = jnp.max(jnp.where(active, _bucket_of(small_cnt), 0))
                 hist2, payload = jax.lax.switch(
                     bi, [branch_for(S) for S in buckets], 0)
-                child = hist_from_flat(hist2, f, HB, _lay["b_pad"],
-                                       _w_inv)               # (W,2,F,HB,3)
-                bs = payload_to_best(jnp.concatenate(
-                    [payload[:, 0], payload[:, 1]], axis=0))
+                with phase("grow/wave_unpack"):
+                    child = hist_from_flat(hist2, f, HB, _lay["b_pad"],
+                                           _w_inv)           # (W,2,F,HB,3)
+                    bs = payload_to_best(jnp.concatenate(
+                        [payload[:, 0], payload[:, 1]], axis=0))
                 return child[:, 0], child[:, 1], bs
 
         def body(st: _GrowState) -> _GrowState:
-            budget = L - st.num_leaves
-            top_g, top_l = jax.lax.top_k(st.best_gain, W)
-            slot = jnp.arange(W, dtype=jnp.int32)
-            active = (top_g > _NEG_INF) & (slot < budget)
-            if inter:
-                # Conflict-free wave (per-wave bound recomputation): two
-                # leaves ORDERED by a monotone relation must not split in
-                # the same wave — each one's pre-wave bound assumes the
-                # other's output stays put for the wave.  Greedily keep
-                # candidates in gain order that are unordered w.r.t. every
-                # kept candidate; skipped leaves stay pending, so the
-                # executed split sequence remains best-first.
-                pu = _pair_up(st, meta[3])
-                rel = pu | pu.T
-                cand_rel = rel[top_l][:, top_l]                # (W, W)
-                wslot = jnp.arange(W)
+            with phase("grow/select"):
+                budget = L - st.num_leaves
+                top_g, top_l = jax.lax.top_k(st.best_gain, W)
+                slot = jnp.arange(W, dtype=jnp.int32)
+                active = (top_g > _NEG_INF) & (slot < budget)
+                if inter:
+                    # Conflict-free wave (per-wave bound recomputation): two
+                    # leaves ORDERED by a monotone relation must not split in
+                    # the same wave — each one's pre-wave bound assumes the
+                    # other's output stays put for the wave.  Greedily keep
+                    # candidates in gain order that are unordered w.r.t. every
+                    # kept candidate; skipped leaves stay pending, so the
+                    # executed split sequence remains best-first.
+                    pu = _pair_up(st, meta[3])
+                    rel = pu | pu.T
+                    cand_rel = rel[top_l][:, top_l]                # (W, W)
+                    wslot = jnp.arange(W)
 
-                def _sel(j, keep):
-                    clash = jnp.any(keep & (wslot < j) & cand_rel[j])
-                    return keep.at[j].set(keep[j] & ~clash)
+                    def _sel(j, keep):
+                        clash = jnp.any(keep & (wslot < j) & cand_rel[j])
+                        return keep.at[j].set(keep[j] & ~clash)
 
-                keep = jax.lax.fori_loop(0, W, _sel, jnp.ones(W, bool))
-                active = active & keep
-            n_act = jnp.sum(active.astype(jnp.int32))
-            rank = (jnp.cumsum(active.astype(jnp.int32))
-                    - active.astype(jnp.int32))
-            # Inactive slots scatter out-of-bounds (dropped by XLA).
-            node_j = jnp.where(active, st.num_leaves - 1 + rank, M + L)
-            newleaf_j = jnp.where(active, st.num_leaves + rank, L + M)
-            leaf_j = jnp.where(active, top_l, L + M)
+                    keep = jax.lax.fori_loop(0, W, _sel, jnp.ones(W, bool))
+                    active = active & keep
+                n_act = jnp.sum(active.astype(jnp.int32))
+                rank = (jnp.cumsum(active.astype(jnp.int32))
+                        - active.astype(jnp.int32))
+                # Inactive slots scatter out-of-bounds (dropped by XLA).
+                node_j = jnp.where(active, st.num_leaves - 1 + rank, M + L)
+                newleaf_j = jnp.where(active, st.num_leaves + rank, L + M)
+                leaf_j = jnp.where(active, top_l, L + M)
 
-            starts = st.leaf_start[top_l]
-            cnts = jnp.where(active, st.leaf_rows[top_l], 0)
-            feats = st.best_feature[top_l]
-            sbins = st.best_bin[top_l]
-            dlefts = st.best_default_left[top_l]
-            scats = st.best_is_cat[top_l]
-            cmasks = st.best_cat_mask[top_l]
-            raw_dtype = jnp.int32 if cfg.quantized else jnp.float32
+                starts = st.leaf_start[top_l]
+                cnts = jnp.where(active, st.leaf_rows[top_l], 0)
+                feats = st.best_feature[top_l]
+                sbins = st.best_bin[top_l]
+                dlefts = st.best_default_left[top_l]
+                scats = st.best_is_cat[top_l]
+                cmasks = st.best_cat_mask[top_l]
+                raw_dtype = jnp.int32 if cfg.quantized else jnp.float32
 
             if pool_on:
-                # W parent histograms BEFORE the partition reorders their
-                # segments: resident slots, or recompute-on-miss from the
-                # leaf's rows in creation-time order (reference
-                # HistogramPool::Get miss -> reconstruct), re-reduced
-                # across shards exactly like the smaller-sibling path.
-                spW = st.leaf_slot[top_l]                       # (W,)
-                missW = active & (spW < 0)
+                with phase("grow/hist"):
+                    # W parent histograms BEFORE the partition reorders their
+                    # segments: resident slots, or recompute-on-miss from the
+                    # leaf's rows in creation-time order (reference
+                    # HistogramPool::Get miss -> reconstruct), re-reduced
+                    # across shards exactly like the smaller-sibling path.
+                    spW = st.leaf_slot[top_l]                       # (W,)
+                    missW = active & (spW < 0)
 
-                def parent_one(j, ph):
-                    def rec(_):
-                        h = jax.lax.switch(
-                            _bucket_of(cnts[j]), hist_branches, st.perm,
-                            starts[j], cnts[j])
-                        return _reduce_hist(h)
+                    def parent_one(j, ph):
+                        def rec(_):
+                            h = jax.lax.switch(
+                                _bucket_of(cnts[j]), hist_branches, st.perm,
+                                starts[j], cnts[j])
+                            return _reduce_hist(h)
 
-                    h = jax.lax.cond(
-                        missW[j], rec,
-                        lambda _: st.leaf_hist[jnp.clip(spW[j], 0, P - 1)],
-                        None)
-                    return ph.at[j].set(h)
+                        h = jax.lax.cond(
+                            missW[j], rec,
+                            lambda _: st.leaf_hist[jnp.clip(spW[j], 0, P - 1)],
+                            None)
+                        return ph.at[j].set(h)
 
-                parent_hist = jax.lax.fori_loop(
-                    0, W, parent_one,
-                    jnp.zeros((W,) + st.leaf_hist.shape[1:], raw_dtype))
+                    parent_hist = jax.lax.fori_loop(
+                        0, W, parent_one,
+                        jnp.zeros((W,) + st.leaf_hist.shape[1:], raw_dtype))
 
             def part_one(j, carry):
                 perm, nls = carry
@@ -2303,32 +2351,36 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                     perm)
                 return perm, nls.at[j].set(nl)
 
-            perm, nl_phys = jax.lax.fori_loop(
-                0, W, part_one, (st.perm, jnp.zeros(W, jnp.int32)))
+            with phase("grow/partition"):
+                perm, nl_phys = jax.lax.fori_loop(
+                    0, W, part_one, (st.perm, jnp.zeros(W, jnp.int32)))
 
-            if axis is None:
-                small_left = nl_phys <= cnts - nl_phys
-            else:
-                # Global small/large choice so every shard histograms the
-                # same side (reference data-parallel smaller-leaf sync,
-                # data_parallel_tree_learner.cpp:224).
-                nl_g = jax.lax.psum(nl_phys, axis)
-                cnt_g = jax.lax.psum(cnts, axis)
-                small_left = nl_g <= cnt_g - nl_g
-            small_start = jnp.where(small_left, starts, starts + nl_phys)
-            small_cnt = jnp.where(small_left, nl_phys, cnts - nl_phys)
+            with phase("grow/select"):
+                if axis is None:
+                    small_left = nl_phys <= cnts - nl_phys
+                else:
+                    # Global small/large choice so every shard histograms the
+                    # same side (reference data-parallel smaller-leaf sync,
+                    # data_parallel_tree_learner.cpp:224).
+                    nl_g = _psum(nl_phys, axis)
+                    cnt_g = _psum(cnts, axis)
+                    small_left = nl_g <= cnt_g - nl_g
+                small_start = jnp.where(small_left, starts, starts + nl_phys)
+                small_cnt = jnp.where(small_left, nl_phys, cnts - nl_phys)
 
-            pg = st.leaf_sum_grad[top_l]
-            ph = st.leaf_sum_hess[top_l]
-            pc = st.leaf_count[top_l]
-            gl, hl, cl = st.best_gl[top_l], st.best_hl[top_l], st.best_cl[top_l]
-            gr, hr, cr = pg - gl, ph - hl, pc - cl
-            pout = st.leaf_out[top_l]
-            out_l = smoothed_output(gl, hl, cl, pout, cfg.split)
-            out_r = smoothed_output(gr, hr, cr, pout, cfg.split)
+                pg = st.leaf_sum_grad[top_l]
+                ph = st.leaf_sum_hess[top_l]
+                pc = st.leaf_count[top_l]
+                gl, hl, cl = (st.best_gl[top_l], st.best_hl[top_l],
+                              st.best_cl[top_l])
+                gr, hr, cr = pg - gl, ph - hl, pc - cl
+                pout = st.leaf_out[top_l]
+                out_l = smoothed_output(gl, hl, cl, pout, cfg.split)
+                out_r = smoothed_output(gr, hr, cr, pout, cfg.split)
 
-            if not pool_on:
-                parent_hist = st.leaf_hist[top_l]
+                if not pool_on:
+                    parent_hist = st.leaf_hist[top_l]
+
             fused_bs = None
             if use_fused:
                 # ONE fused pallas dispatch for the whole wave (ISSUE-7):
@@ -2348,10 +2400,11 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                         small_start[j], small_cnt[j])
                     return hs.at[j].set(h)
 
-                hist_small = jax.lax.fori_loop(
-                    0, W, hist_one,
-                    jnp.zeros((W, f if cfg.packed4 else gcols, HB, 3),
-                              raw_dtype))                     # (W, G, B, 3)
+                with phase("grow/hist"):
+                    hist_small = jax.lax.fori_loop(
+                        0, W, hist_one,
+                        jnp.zeros((W, f if cfg.packed4 else gcols, HB, 3),
+                                  raw_dtype))                 # (W, G, B, 3)
                 if axis is not None and not voting:
                     # ONE cross-shard reduce per wave — integer tensors
                     # under quantized training (bin.h:48-81; int16 on the
@@ -2362,169 +2415,178 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                     # ReduceScatter, data_parallel_tree_learner.cpp:284).
                     hist_small = (rs["scatter"](hist_small)
                                   if rs is not None
-                                  else jax.lax.psum(hist_small, axis))
+                                  else _psum(hist_small, axis))
 
-                hist_big = parent_hist - hist_small
-                sl = small_left[:, None, None, None]
-                hist_left = jnp.where(sl, hist_small, hist_big)
-                hist_right = jnp.where(sl, hist_big, hist_small)
-            bounds2 = None
-            if cfg.split.has_monotone and inter:
-                # Intermediate/advanced: clip to the pre-wave refreshed
-                # bounds (per-threshold slices when advanced); children
-                # inherit the parent bounds verbatim and the REAL bounds
-                # come from the post-wave refresh.  Track child bin
-                # rectangles for the adjacency pass.
-                plo, phi = st.leaf_lo[top_l], st.leaf_hi[top_l]
-                if adv:
-                    out_l = jnp.clip(out_l, st.adv_llo[top_l],
-                                     st.adv_lhi[top_l])
-                    out_r = jnp.clip(out_r, st.adv_rlo[top_l],
-                                     st.adv_rhi[top_l])
-                else:
+                with phase("grow/subtract"):
+                    hist_big = parent_hist - hist_small
+                    sl = small_left[:, None, None, None]
+                    hist_left = jnp.where(sl, hist_small, hist_big)
+                    hist_right = jnp.where(sl, hist_big, hist_small)
+
+            with phase("grow/update"):
+                bounds2 = None
+                if cfg.split.has_monotone and inter:
+                    # Intermediate/advanced: clip to the pre-wave refreshed
+                    # bounds (per-threshold slices when advanced); children
+                    # inherit the parent bounds verbatim and the REAL bounds
+                    # come from the post-wave refresh.  Track child bin
+                    # rectangles for the adjacency pass.
+                    plo, phi = st.leaf_lo[top_l], st.leaf_hi[top_l]
+                    if adv:
+                        out_l = jnp.clip(out_l, st.adv_llo[top_l],
+                                         st.adv_lhi[top_l])
+                        out_r = jnp.clip(out_r, st.adv_rlo[top_l],
+                                         st.adv_rhi[top_l])
+                    else:
+                        out_l = jnp.clip(out_l, plo, phi)
+                        out_r = jnp.clip(out_r, plo, phi)
+                    cut = (sbins + 1)[:, None]
+                    lo_p = st.leaf_bin_lo[top_l]                   # (W, F)
+                    hi_p = st.leaf_bin_hi[top_l]
+                    fhot1 = (jnp.arange(lo_p.shape[1])[None, :]
+                             == feats[:, None])
+                    isnum = (~scats)[:, None]
+                    hi_l_r = jnp.where(fhot1 & isnum,
+                                       jnp.minimum(hi_p, cut), hi_p)
+                    lo_r_r = jnp.where(fhot1 & isnum,
+                                       jnp.maximum(lo_p, cut), lo_p)
+                    pair_idx = jnp.concatenate([leaf_j, newleaf_j])
+                    st = st._replace(
+                        leaf_bin_lo=st.leaf_bin_lo.at[pair_idx].set(
+                            jnp.concatenate([lo_p, lo_r_r]), mode="drop"),
+                        leaf_bin_hi=st.leaf_bin_hi.at[pair_idx].set(
+                            jnp.concatenate([hi_l_r, hi_p]), mode="drop"),
+                        leaf_lo=st.leaf_lo.at[pair_idx].set(
+                            jnp.concatenate([plo, plo]), mode="drop"),
+                        leaf_hi=st.leaf_hi.at[pair_idx].set(
+                            jnp.concatenate([phi, phi]), mode="drop"))
+                    # bounds2 stays None: the children best-split pass is
+                    # skipped on this path (the per-wave refresh recomputes
+                    # every leaf's split against fresh bounds)
+                elif cfg.split.has_monotone:
+                    plo, phi = st.leaf_lo[top_l], st.leaf_hi[top_l]
                     out_l = jnp.clip(out_l, plo, phi)
                     out_r = jnp.clip(out_r, plo, phi)
-                cut = (sbins + 1)[:, None]
-                lo_p = st.leaf_bin_lo[top_l]                   # (W, F)
-                hi_p = st.leaf_bin_hi[top_l]
-                fhot1 = jnp.arange(lo_p.shape[1])[None, :] == feats[:, None]
-                isnum = (~scats)[:, None]
-                hi_l_r = jnp.where(fhot1 & isnum,
-                                   jnp.minimum(hi_p, cut), hi_p)
-                lo_r_r = jnp.where(fhot1 & isnum,
-                                   jnp.maximum(lo_p, cut), lo_p)
-                pair_idx = jnp.concatenate([leaf_j, newleaf_j])
-                st = st._replace(
-                    leaf_bin_lo=st.leaf_bin_lo.at[pair_idx].set(
-                        jnp.concatenate([lo_p, lo_r_r]), mode="drop"),
-                    leaf_bin_hi=st.leaf_bin_hi.at[pair_idx].set(
-                        jnp.concatenate([hi_l_r, hi_p]), mode="drop"),
-                    leaf_lo=st.leaf_lo.at[pair_idx].set(
-                        jnp.concatenate([plo, plo]), mode="drop"),
-                    leaf_hi=st.leaf_hi.at[pair_idx].set(
-                        jnp.concatenate([phi, phi]), mode="drop"))
-                # bounds2 stays None: the children best-split pass is
-                # skipped on this path (the per-wave refresh recomputes
-                # every leaf's split against fresh bounds)
-            elif cfg.split.has_monotone:
-                plo, phi = st.leaf_lo[top_l], st.leaf_hi[top_l]
-                out_l = jnp.clip(out_l, plo, phi)
-                out_r = jnp.clip(out_r, plo, phi)
-                mono_t = meta[3][feats]
-                is_num = ~scats
-                mid = (out_l + out_r) / 2.0
-                lo_l = jnp.where((mono_t < 0) & is_num,
-                                 jnp.maximum(plo, mid), plo)
-                hi_l = jnp.where((mono_t > 0) & is_num,
-                                 jnp.minimum(phi, mid), phi)
-                lo_r = jnp.where((mono_t > 0) & is_num,
-                                 jnp.maximum(plo, mid), plo)
-                hi_r = jnp.where((mono_t < 0) & is_num,
-                                 jnp.minimum(phi, mid), phi)
-                st = st._replace(
-                    leaf_lo=st.leaf_lo.at[
-                        jnp.concatenate([leaf_j, newleaf_j])].set(
-                        jnp.concatenate([lo_l, lo_r]), mode="drop"),
-                    leaf_hi=st.leaf_hi.at[
-                        jnp.concatenate([leaf_j, newleaf_j])].set(
-                        jnp.concatenate([hi_l, hi_r]), mode="drop"))
-                bounds2 = (jnp.concatenate([lo_l, lo_r]),
-                           jnp.concatenate([hi_l, hi_r]))
+                    mono_t = meta[3][feats]
+                    is_num = ~scats
+                    mid = (out_l + out_r) / 2.0
+                    lo_l = jnp.where((mono_t < 0) & is_num,
+                                     jnp.maximum(plo, mid), plo)
+                    hi_l = jnp.where((mono_t > 0) & is_num,
+                                     jnp.minimum(phi, mid), phi)
+                    lo_r = jnp.where((mono_t > 0) & is_num,
+                                     jnp.maximum(plo, mid), plo)
+                    hi_r = jnp.where((mono_t < 0) & is_num,
+                                     jnp.minimum(phi, mid), phi)
+                    st = st._replace(
+                        leaf_lo=st.leaf_lo.at[
+                            jnp.concatenate([leaf_j, newleaf_j])].set(
+                            jnp.concatenate([lo_l, lo_r]), mode="drop"),
+                        leaf_hi=st.leaf_hi.at[
+                            jnp.concatenate([leaf_j, newleaf_j])].set(
+                            jnp.concatenate([hi_l, hi_r]), mode="drop"))
+                    bounds2 = (jnp.concatenate([lo_l, lo_r]),
+                               jnp.concatenate([hi_l, hi_r]))
 
-            # ---- tree updates (batched scatters over W nodes)
-            tr = st.tree
-            parent = st.leaf_parent[top_l]
-            was_left = st.leaf_is_left[top_l]
-            pl_idx = jnp.where(active & (parent >= 0) & was_left,
-                               jnp.maximum(parent, 0), M + L)
-            pr_idx = jnp.where(active & (parent >= 0) & ~was_left,
-                               jnp.maximum(parent, 0), M + L)
-            left_child = tr.left_child.at[pl_idx].set(node_j, mode="drop")
-            right_child = tr.right_child.at[pr_idx].set(node_j, mode="drop")
-            tree = tr._replace(
-                split_feature=tr.split_feature.at[node_j].set(
-                    feats, mode="drop"),
-                split_bin=tr.split_bin.at[node_j].set(sbins, mode="drop"),
-                default_left=tr.default_left.at[node_j].set(
-                    dlefts, mode="drop"),
-                is_cat=tr.is_cat.at[node_j].set(scats, mode="drop"),
-                cat_mask=tr.cat_mask.at[node_j].set(cmasks, mode="drop"),
-                left_child=left_child.at[node_j].set(~leaf_j, mode="drop"),
-                right_child=right_child.at[node_j].set(
-                    ~newleaf_j, mode="drop"),
-                split_gain=tr.split_gain.at[node_j].set(top_g, mode="drop"),
-                internal_value=tr.internal_value.at[node_j].set(
-                    pout, mode="drop"),
-                internal_count=tr.internal_count.at[node_j].set(
-                    pc, mode="drop"),
-            )
+                # ---- tree updates (batched scatters over W nodes)
+                tr = st.tree
+                parent = st.leaf_parent[top_l]
+                was_left = st.leaf_is_left[top_l]
+                pl_idx = jnp.where(active & (parent >= 0) & was_left,
+                                   jnp.maximum(parent, 0), M + L)
+                pr_idx = jnp.where(active & (parent >= 0) & ~was_left,
+                                   jnp.maximum(parent, 0), M + L)
+                left_child = tr.left_child.at[pl_idx].set(node_j, mode="drop")
+                right_child = tr.right_child.at[pr_idx].set(
+                    node_j, mode="drop")
+                tree = tr._replace(
+                    split_feature=tr.split_feature.at[node_j].set(
+                        feats, mode="drop"),
+                    split_bin=tr.split_bin.at[node_j].set(sbins, mode="drop"),
+                    default_left=tr.default_left.at[node_j].set(
+                        dlefts, mode="drop"),
+                    is_cat=tr.is_cat.at[node_j].set(scats, mode="drop"),
+                    cat_mask=tr.cat_mask.at[node_j].set(cmasks, mode="drop"),
+                    left_child=left_child.at[node_j].set(~leaf_j, mode="drop"),
+                    right_child=right_child.at[node_j].set(
+                        ~newleaf_j, mode="drop"),
+                    split_gain=tr.split_gain.at[node_j].set(
+                        top_g, mode="drop"),
+                    internal_value=tr.internal_value.at[node_j].set(
+                        pout, mode="drop"),
+                    internal_count=tr.internal_count.at[node_j].set(
+                        pc, mode="drop"),
+                )
 
-            # ---- per-leaf state (batched scatters over 2W children)
-            idx2 = jnp.concatenate([leaf_j, newleaf_j])
-            cat2 = lambda a, b: jnp.concatenate([a, b])
-            depth = st.leaf_depth[top_l] + 1
-            hist_idx2 = idx2
-            if pool_on:
-                # Claim W smaller-sibling slots (+ replacements for missed
-                # parents); larger siblings take over their parents' slots.
-                st, ssW, sbW = pool_claim(st, spW, active, missW)
-                slot_l = jnp.where(small_left, ssW, sbW)
-                slot_r = jnp.where(small_left, sbW, ssW)
-                hist_idx2 = cat2(slot_l, slot_r)
-                st = pool_assign(st, idx2, hist_idx2)
-            st = st._replace(
-                perm=perm,
-                tree=tree,
-                num_leaves=st.num_leaves + n_act,
-                leaf_start=st.leaf_start.at[newleaf_j].set(
-                    starts + nl_phys, mode="drop"),
-                leaf_rows=st.leaf_rows.at[leaf_j].set(nl_phys, mode="drop")
-                                     .at[newleaf_j].set(cnts - nl_phys,
-                                                        mode="drop"),
-                leaf_hist=st.leaf_hist.at[hist_idx2].set(
-                    cat2(hist_left, hist_right), mode="drop"),
-                leaf_sum_grad=st.leaf_sum_grad.at[idx2].set(
-                    cat2(gl, gr), mode="drop"),
-                leaf_sum_hess=st.leaf_sum_hess.at[idx2].set(
-                    cat2(hl, hr), mode="drop"),
-                leaf_count=st.leaf_count.at[idx2].set(
-                    cat2(cl, cr), mode="drop"),
-                leaf_depth=st.leaf_depth.at[idx2].set(
-                    cat2(depth, depth), mode="drop"),
-                leaf_parent=st.leaf_parent.at[idx2].set(
-                    cat2(node_j, node_j), mode="drop"),
-                leaf_is_left=st.leaf_is_left.at[idx2].set(
-                    cat2(jnp.ones(W, bool), jnp.zeros(W, bool)),
-                    mode="drop"),
-                leaf_out=st.leaf_out.at[idx2].set(
-                    cat2(out_l, out_r), mode="drop"),
-            )
-
-            # ---- path tracking (CEGB / interaction constraints)
-            penalty2 = None
-            path2 = None
-            if track_path:
-                fhot = (jnp.arange(f)[None, :] == feats[:, None]) \
-                    & active[:, None]                        # (W, F)
-                child_path = st.leaf_path[top_l] | fhot      # (W, F)
-                path2 = cat2(child_path, child_path)
+                # ---- per-leaf state (batched scatters over 2W children)
+                idx2 = jnp.concatenate([leaf_j, newleaf_j])
+                cat2 = lambda a, b: jnp.concatenate([a, b])
+                depth = st.leaf_depth[top_l] + 1
+                hist_idx2 = idx2
+                if pool_on:
+                    # Claim W smaller-sibling slots (+ replacements for missed
+                    # parents); larger siblings take over their parents' slots.
+                    st, ssW, sbW = pool_claim(st, spW, active, missW)
+                    slot_l = jnp.where(small_left, ssW, sbW)
+                    slot_r = jnp.where(small_left, sbW, ssW)
+                    hist_idx2 = cat2(slot_l, slot_r)
+                    st = pool_assign(st, idx2, hist_idx2)
                 st = st._replace(
-                    leaf_path=st.leaf_path.at[idx2].set(path2, mode="drop"))
-            if cfg.split.use_cegb and cegb is not None:
-                coupled, lazy = cegb
-                feat_used = st.feat_used | jnp.any(fhot, axis=0)
-                st = st._replace(feat_used=feat_used)
-                if not inter:
-                    # the inter path's refresh recomputes penaltyL for all
-                    # leaves; computing the per-child pair here would be
-                    # dead work in the jitted hot loop
-                    pen_l = jax.vmap(
-                        lambda c, p: _cegb_penalty(c, feat_used, p, coupled,
-                                                   lazy))(cl, child_path)
-                    pen_r = jax.vmap(
-                        lambda c, p: _cegb_penalty(c, feat_used, p, coupled,
-                                                   lazy))(cr, child_path)
-                    penalty2 = cat2(pen_l, pen_r)
+                    perm=perm,
+                    tree=tree,
+                    num_leaves=st.num_leaves + n_act,
+                    leaf_start=st.leaf_start.at[newleaf_j].set(
+                        starts + nl_phys, mode="drop"),
+                    leaf_rows=st.leaf_rows.at[leaf_j].set(nl_phys, mode="drop")
+                                         .at[newleaf_j].set(cnts - nl_phys,
+                                                            mode="drop"),
+                    leaf_hist=st.leaf_hist.at[hist_idx2].set(
+                        cat2(hist_left, hist_right), mode="drop"),
+                    leaf_sum_grad=st.leaf_sum_grad.at[idx2].set(
+                        cat2(gl, gr), mode="drop"),
+                    leaf_sum_hess=st.leaf_sum_hess.at[idx2].set(
+                        cat2(hl, hr), mode="drop"),
+                    leaf_count=st.leaf_count.at[idx2].set(
+                        cat2(cl, cr), mode="drop"),
+                    leaf_depth=st.leaf_depth.at[idx2].set(
+                        cat2(depth, depth), mode="drop"),
+                    leaf_parent=st.leaf_parent.at[idx2].set(
+                        cat2(node_j, node_j), mode="drop"),
+                    leaf_is_left=st.leaf_is_left.at[idx2].set(
+                        cat2(jnp.ones(W, bool), jnp.zeros(W, bool)),
+                        mode="drop"),
+                    leaf_out=st.leaf_out.at[idx2].set(
+                        cat2(out_l, out_r), mode="drop"),
+                )
+
+                # ---- path tracking (CEGB / interaction constraints)
+                penalty2 = None
+                path2 = None
+                if track_path:
+                    fhot = (jnp.arange(f)[None, :] == feats[:, None]) \
+                        & active[:, None]                        # (W, F)
+                    child_path = st.leaf_path[top_l] | fhot      # (W, F)
+                    path2 = cat2(child_path, child_path)
+                    st = st._replace(
+                        leaf_path=st.leaf_path.at[idx2].set(
+                            path2, mode="drop"))
+                if cfg.split.use_cegb and cegb is not None:
+                    coupled, lazy = cegb
+                    feat_used = st.feat_used | jnp.any(fhot, axis=0)
+                    st = st._replace(feat_used=feat_used)
+                    if not inter:
+                        # the inter path's refresh recomputes penaltyL for all
+                        # leaves; computing the per-child pair here would be
+                        # dead work in the jitted hot loop
+                        pen_l = jax.vmap(
+                            lambda c, p: _cegb_penalty(
+                                c, feat_used, p, coupled, lazy))(
+                                    cl, child_path)
+                        pen_r = jax.vmap(
+                            lambda c, p: _cegb_penalty(
+                                c, feat_used, p, coupled, lazy))(
+                                    cr, child_path)
+                        penalty2 = cat2(pen_l, pen_r)
 
             if inter:
                 # Per-wave bound + best-split refresh over ALL leaves — the
@@ -2536,10 +2598,11 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
 
             # ---- best splits for all 2W children in one vmapped search
             # (already computed IN the kernel on the fused path)
-            node_key = None
-            if need_key:
-                rng, node_key = jax.random.split(st.rng)
-                st = st._replace(rng=rng)
+            with phase("grow/scan"):
+                node_key = None
+                if need_key:
+                    rng, node_key = jax.random.split(st.rng)
+                    st = st._replace(rng=rng)
             if use_fused:
                 bs = fused_bs
             elif voting:
@@ -2550,9 +2613,10 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                     penaltyk=penalty2, key=node_key, pathk=path2,
                     groups_mat=groups_mat)
             else:
-                hist2s = _expand_hist_batch(
-                    _scale_hist(cat2(hist_left, hist_right), scale3), meta,
-                    cat2(gl, gr), cat2(hl, hr), cat2(cl, cr), rs)
+                with phase("grow/scan"):
+                    hist2s = _expand_hist_batch(
+                        _scale_hist(cat2(hist_left, hist_right), scale3), meta,
+                        cat2(gl, gr), cat2(hl, hr), cat2(cl, cr), rs)
                 bs = _best_for_batch(hist2s, cat2(gl, gr), cat2(hl, hr),
                                      cat2(cl, cr), meta, feature_mask,
                                      penalty2, cat2(out_l, out_r), node_key,
@@ -2562,29 +2626,30 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                     # All 2W slice-local winners globalize in one vmapped
                     # payload broadcast (SyncUpGlobalBestSplit).
                     bs = rs["sync"](bs)
-            if cfg.max_depth <= 0:
-                depth_ok = jnp.ones(2 * W, bool)
-            else:
-                depth_ok = cat2(depth, depth) < cfg.max_depth
-            gain2 = jnp.where(depth_ok, bs.gain, _NEG_INF)
-            return st._replace(
-                best_gain=st.best_gain.at[idx2].set(gain2, mode="drop"),
-                best_feature=st.best_feature.at[idx2].set(
-                    bs.feature, mode="drop"),
-                best_bin=st.best_bin.at[idx2].set(bs.bin, mode="drop"),
-                best_default_left=st.best_default_left.at[idx2].set(
-                    bs.default_left, mode="drop"),
-                best_is_cat=st.best_is_cat.at[idx2].set(
-                    bs.is_cat, mode="drop"),
-                best_cat_mask=st.best_cat_mask.at[idx2].set(
-                    bs.cat_mask, mode="drop"),
-                best_gl=st.best_gl.at[idx2].set(
-                    bs.sum_grad_left, mode="drop"),
-                best_hl=st.best_hl.at[idx2].set(
-                    bs.sum_hess_left, mode="drop"),
-                best_cl=st.best_cl.at[idx2].set(
-                    bs.count_left, mode="drop"),
-            )
+            with phase("grow/update"):
+                if cfg.max_depth <= 0:
+                    depth_ok = jnp.ones(2 * W, bool)
+                else:
+                    depth_ok = cat2(depth, depth) < cfg.max_depth
+                gain2 = jnp.where(depth_ok, bs.gain, _NEG_INF)
+                return st._replace(
+                    best_gain=st.best_gain.at[idx2].set(gain2, mode="drop"),
+                    best_feature=st.best_feature.at[idx2].set(
+                        bs.feature, mode="drop"),
+                    best_bin=st.best_bin.at[idx2].set(bs.bin, mode="drop"),
+                    best_default_left=st.best_default_left.at[idx2].set(
+                        bs.default_left, mode="drop"),
+                    best_is_cat=st.best_is_cat.at[idx2].set(
+                        bs.is_cat, mode="drop"),
+                    best_cat_mask=st.best_cat_mask.at[idx2].set(
+                        bs.cat_mask, mode="drop"),
+                    best_gl=st.best_gl.at[idx2].set(
+                        bs.sum_grad_left, mode="drop"),
+                    best_hl=st.best_hl.at[idx2].set(
+                        bs.sum_hess_left, mode="drop"),
+                    best_cl=st.best_cl.at[idx2].set(
+                        bs.count_left, mode="drop"),
+                )
 
         def cond(st: _GrowState):
             return (st.num_leaves < L) & (jnp.max(st.best_gain) > _NEG_INF)
@@ -2608,6 +2673,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             mask_impl = ("onehot" if jax.default_backend() == "tpu"
                          else "segment")
 
+        @phase("grow/hist")
         def hist_for(mask):
             # vals already carries bagging weights + in-bag zeroing; the
             # per-leaf predicate is the only extra mask needed.  RAW output;
@@ -2617,67 +2683,76 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 bins, masked, num_bins=HB,
                 impl=mask_impl, rows_block=cfg.rows_block)
 
-        nan_bins = meta[1]
-        root_hist = histogram_from_vals(
-            bins, vals, num_bins=HB, impl=mask_impl,
-            rows_block=cfg.rows_block)
-        root_tot = jnp.sum(_scale_hist(root_hist[0:1], scale3)[0], axis=0)
-        root_g, root_h, root_c = root_tot[0], root_tot[1], root_tot[2]
-        state = _init_state(n, f, gcols, root_hist, root_g, root_h, root_c,
-                            key)
-        row_leaf0 = jnp.zeros(n, jnp.int32)
-        root_pen = None
-        if cfg.split.use_cegb and cegb is not None:
-            root_pen = _cegb_penalty(root_c, state.feat_used,
-                                     state.leaf_path[0], *cegb)
-        state, root_bs = _root_best(state, scale3, meta, feature_mask,
-                                    root_pen, groups_mat)
-        state = _store_best(state, jnp.asarray(0), root_bs, jnp.asarray(True))
+        with phase("grow/setup"):
+            nan_bins = meta[1]
+            root_hist = histogram_from_vals(
+                bins, vals, num_bins=HB, impl=mask_impl,
+                rows_block=cfg.rows_block)
+            root_tot = jnp.sum(_scale_hist(root_hist[0:1], scale3)[0], axis=0)
+            root_g, root_h, root_c = root_tot[0], root_tot[1], root_tot[2]
+            state = _init_state(n, f, gcols, root_hist, root_g, root_h, root_c,
+                                key)
+            row_leaf0 = jnp.zeros(n, jnp.int32)
+            root_pen = None
+            if cfg.split.use_cegb and cegb is not None:
+                root_pen = _cegb_penalty(root_c, state.feat_used,
+                                         state.leaf_path[0], *cegb)
+            state, root_bs = _root_best(state, scale3, meta, feature_mask,
+                                        root_pen, groups_mat)
+            state = _store_best(state, jnp.asarray(0), root_bs,
+                                jnp.asarray(True))
 
         def body(carry):
             st, row_leaf = carry
-            use_f = jnp.asarray(False)
-            si = jnp.asarray(0)
-            if n_forced:
-                st, use_f, si = _apply_forced(st, scale3, meta)
-                leaf = jnp.where(use_f, st.forced_leaf[si],
-                                 jnp.argmax(st.best_gain)).astype(jnp.int32)
-            else:
-                leaf = jnp.argmax(st.best_gain).astype(jnp.int32)
-            node = st.num_leaves - 1
-            new_leaf = st.num_leaves
+            with phase("grow/select"):
+                use_f = jnp.asarray(False)
+                si = jnp.asarray(0)
+                if n_forced:
+                    st, use_f, si = _apply_forced(st, scale3, meta)
+                    leaf = jnp.where(
+                        use_f, st.forced_leaf[si],
+                        jnp.argmax(st.best_gain)).astype(jnp.int32)
+                else:
+                    leaf = jnp.argmax(st.best_gain).astype(jnp.int32)
+                node = st.num_leaves - 1
+                new_leaf = st.num_leaves
 
-            feat = st.best_feature[leaf]
-            sbin = st.best_bin[leaf]
-            dleft = st.best_default_left[leaf]
-            scat = st.best_is_cat[leaf]
-            cmask = st.best_cat_mask[leaf]
+                feat = st.best_feature[leaf]
+                sbin = st.best_bin[leaf]
+                dleft = st.best_default_left[leaf]
+                scat = st.best_is_cat[leaf]
+                cmask = st.best_cat_mask[leaf]
 
-            gcol = meta[4][feat] if cfg.bundled else feat
-            col = _decode_col(jnp.take(bins, gcol, axis=1).astype(jnp.int32),
-                              feat, meta)
-            is_nan = col == nan_bins[feat]
-            go_left = jnp.where(scat, cmask[col], col <= sbin)
-            go_left = jnp.where(is_nan & ~scat, dleft, go_left)
-            mine = row_leaf == leaf
-            row_leaf = jnp.where(mine & ~go_left, new_leaf, row_leaf)
+            with phase("grow/partition"):
+                gcol = meta[4][feat] if cfg.bundled else feat
+                col = _decode_col(
+                    jnp.take(bins, gcol, axis=1).astype(jnp.int32), feat,
+                    meta)
+                is_nan = col == nan_bins[feat]
+                go_left = jnp.where(scat, cmask[col], col <= sbin)
+                go_left = jnp.where(is_nan & ~scat, dleft, go_left)
+                mine = row_leaf == leaf
+                row_leaf = jnp.where(mine & ~go_left, new_leaf, row_leaf)
 
-            pg, ph, pc = (st.leaf_sum_grad[leaf], st.leaf_sum_hess[leaf],
-                          st.leaf_count[leaf])
-            gl, hl, cl = st.best_gl[leaf], st.best_hl[leaf], st.best_cl[leaf]
-            gr, hr, cr = pg - gl, ph - hl, pc - cl
+            with phase("grow/select"):
+                pg, ph, pc = (st.leaf_sum_grad[leaf], st.leaf_sum_hess[leaf],
+                              st.leaf_count[leaf])
+                gl, hl, cl = (st.best_gl[leaf], st.best_hl[leaf],
+                              st.best_cl[leaf])
+                gr, hr, cr = pg - gl, ph - hl, pc - cl
 
-            small_is_left = cl <= cr
-            target = jnp.where(small_is_left, leaf, new_leaf)
-            # row_leaf tracks ALL rows (out-of-bag included, they need score
-            # updates later); out-of-bag rows contribute zeros via the
-            # pre-masked vals, so the count channel stays consistent with the
-            # root histogram.
+                small_is_left = cl <= cr
+                target = jnp.where(small_is_left, leaf, new_leaf)
+                # row_leaf tracks ALL rows (out-of-bag included, they need
+                # score updates later); out-of-bag rows contribute zeros via
+                # the pre-masked vals, so the count channel stays consistent
+                # with the root histogram.
             hist_small = hist_for(row_leaf == target)
-            hist_parent = st.leaf_hist[leaf]
-            hist_big = hist_parent - hist_small
-            hist_left = jnp.where(small_is_left, hist_small, hist_big)
-            hist_right = jnp.where(small_is_left, hist_big, hist_small)
+            with phase("grow/subtract"):
+                hist_parent = st.leaf_hist[leaf]
+                hist_big = hist_parent - hist_small
+                hist_left = jnp.where(small_is_left, hist_small, hist_big)
+                hist_right = jnp.where(small_is_left, hist_big, hist_small)
 
             tree = _update_tree(st, leaf, new_leaf, node, pg, ph, pc)
             st = st._replace(tree=tree)
@@ -2720,18 +2795,19 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         fl = -(-bins.shape[1] // S)
         fp_width = fl * S
         nbpf, nanb, iscat, mono = meta[:4]
-        fmask = feature_mask
-        if bins.shape[1] != fp_width:
-            # dummy columns: all-zero bins (callers may pre-pad bins once)
-            bins = jnp.pad(bins, ((0, 0), (0, fp_width - bins.shape[1])))
-        padm = fp_width - nbpf.shape[0]
-        if padm:
-            # pad metadata to the bins width; mask False = never selectable
-            fmask = jnp.pad(fmask, (0, padm))
-            nbpf = jnp.pad(nbpf, (0, padm), constant_values=2)
-            nanb = jnp.pad(nanb, (0, padm), constant_values=HB)
-            iscat = jnp.pad(iscat, (0, padm))
-            mono = jnp.pad(mono, (0, padm))
+        with phase("grow/setup"):
+            fmask = feature_mask
+            if bins.shape[1] != fp_width:
+                # dummy columns: all-zero bins (callers may pre-pad bins once)
+                bins = jnp.pad(bins, ((0, 0), (0, fp_width - bins.shape[1])))
+            padm = fp_width - nbpf.shape[0]
+            if padm:
+                # pad metadata to the bins width; mask False = never selectable
+                fmask = jnp.pad(fmask, (0, padm))
+                nbpf = jnp.pad(nbpf, (0, padm), constant_values=2)
+                nanb = jnp.pad(nanb, (0, padm), constant_values=HB)
+                iscat = jnp.pad(iscat, (0, padm))
+                mono = jnp.pad(mono, (0, padm))
         have_scale = scale3 is not None
         have_key = split_key is not None
         extras, especs = [], []
@@ -2849,46 +2925,52 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             lazy = (cegb_lazy if cegb_lazy is not None
                     else jnp.zeros(f, jnp.float32))
             cegb = (coupled, lazy)
-        g = grad * sample_mask
-        h = hess * sample_mask
-        in_bag = sample_mask > 0.0
-        if cfg.quantized:
-            # Reference GradientDiscretizer (gradient_discretizer.hpp:128):
-            # int8 levels + per-iteration scales; histograms accumulate s32
-            # and are rescaled to f32 right before the split scan.
-            from ..ops.quantize import discretize_gradients, gradient_scales
-            if quant_key is None:
-                quant_key = jax.random.PRNGKey(0)
-            g_scale, h_scale = gradient_scales(g, h, cfg.num_grad_quant_bins)
-            gq, hq = discretize_gradients(g, h, g_scale, h_scale, quant_key,
-                                          cfg.stochastic_rounding)
-            vals = jnp.stack([gq, hq, in_bag.astype(jnp.int8)], axis=-1)
-            scale3 = jnp.stack(
-                [g_scale, h_scale, jnp.asarray(1.0, jnp.float32)])
-        else:
-            vals = jnp.stack([g, h, in_bag.astype(jnp.float32)], axis=-1)
-            scale3 = None
-        # Defined rounding for the histogram inputs (docs/STREAMING.md):
-        # without the barrier XLA may fuse the grad*sample_mask multiply
-        # into the histogram scatter-add as an FMA — a per-program 1-ULP
-        # coin flip the streamed chunk programs cannot replicate (it only
-        # surfaces when the mask is inexact, e.g. GOSS amplification).
-        # Materialized vals make every downstream histogram an adds-only
-        # fold, the one arithmetic all layouts and the stream kit share.
-        vals = jax.lax.optimization_barrier(vals)
+        with phase("grow/setup"):
+            g = grad * sample_mask
+            h = hess * sample_mask
+            in_bag = sample_mask > 0.0
+            if cfg.quantized:
+                # Reference GradientDiscretizer (gradient_discretizer.hpp:128):
+                # int8 levels + per-iteration scales; histograms accumulate s32
+                # and are rescaled to f32 right before the split scan.
+                from ..ops.quantize import (discretize_gradients,
+                                            gradient_scales)
+                if quant_key is None:
+                    quant_key = jax.random.PRNGKey(0)
+                g_scale, h_scale = gradient_scales(
+                    g, h, cfg.num_grad_quant_bins)
+                gq, hq = discretize_gradients(g, h, g_scale, h_scale,
+                                              quant_key,
+                                              cfg.stochastic_rounding)
+                vals = jnp.stack([gq, hq, in_bag.astype(jnp.int8)], axis=-1)
+                scale3 = jnp.stack(
+                    [g_scale, h_scale, jnp.asarray(1.0, jnp.float32)])
+            else:
+                vals = jnp.stack([g, h, in_bag.astype(jnp.float32)], axis=-1)
+                scale3 = None
+            # Defined rounding for the histogram inputs (docs/STREAMING.md):
+            # without the barrier XLA may fuse the grad*sample_mask multiply
+            # into the histogram scatter-add as an FMA — a per-program 1-ULP
+            # coin flip the streamed chunk programs cannot replicate (it only
+            # surfaces when the mask is inexact, e.g. GOSS amplification).
+            # Materialized vals make every downstream histogram an adds-only
+            # fold, the one arithmetic all layouts and the stream kit share.
+            vals = jax.lax.optimization_barrier(vals)
         if need_key and split_key is None:
             split_key = jax.random.PRNGKey(0)
         n = grad.shape[0]
         dshards = 1 if mesh is None else int(mesh.shape[data_axis])
-        if mesh is not None and cfg.gather_rows:
-            # shard_map needs even row shards; zero-valued pad rows
-            # contribute nothing to any histogram.  Callers avoid the bins
-            # copy by pre-padding the bins array once.
-            pad = (-bins.shape[0]) % dshards
-            if pad:
-                bins = jnp.pad(bins, ((0, pad), (0, 0)))
-        if bins.shape[0] != vals.shape[0]:
-            vals = jnp.pad(vals, ((0, bins.shape[0] - vals.shape[0]), (0, 0)))
+        with phase("grow/setup"):
+            if mesh is not None and cfg.gather_rows:
+                # shard_map needs even row shards; zero-valued pad rows
+                # contribute nothing to any histogram.  Callers avoid the bins
+                # copy by pre-padding the bins array once.
+                pad = (-bins.shape[0]) % dshards
+                if pad:
+                    bins = jnp.pad(bins, ((0, pad), (0, 0)))
+            if bins.shape[0] != vals.shape[0]:
+                vals = jnp.pad(
+                    vals, ((0, bins.shape[0] - vals.shape[0]), (0, 0)))
         use_sharded = (mesh is not None and cfg.gather_rows
                        and bins.shape[0] // dshards > _MIN_BUCKET)
         if fp_capable and bins.shape[1] != meta[0].shape[0] \
@@ -2918,17 +3000,18 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 bins = unpack_bins4(bins, meta[0].shape[0])
             tree, row_leaf = _grow_mask(bins, vals, scale3, feature_mask,
                                         meta, cegb, split_key)
-        row_leaf = row_leaf[:n]
-        if cfg.quantized and cfg.quant_renew_leaf:
-            # quant_train_renew_leaf: recompute leaf outputs from the TRUE
-            # (unquantized) gradients (reference RenewIntGradTreeOutput).
-            g_leaf = jax.ops.segment_sum(g, row_leaf, num_segments=L)
-            h_leaf = jax.ops.segment_sum(h, row_leaf, num_segments=L)
-            renewed = leaf_output(g_leaf, h_leaf, cfg.split)
-            active = jnp.arange(L) < tree.num_leaves
-            tree = tree._replace(
-                leaf_value=jnp.where(active, renewed, 0.0),
-                leaf_weight=jnp.where(active, h_leaf, 0.0))
+        with phase("grow/finish"):
+            row_leaf = row_leaf[:n]
+            if cfg.quantized and cfg.quant_renew_leaf:
+                # quant_train_renew_leaf: recompute leaf outputs from the TRUE
+                # (unquantized) gradients (reference RenewIntGradTreeOutput).
+                g_leaf = jax.ops.segment_sum(g, row_leaf, num_segments=L)
+                h_leaf = jax.ops.segment_sum(h, row_leaf, num_segments=L)
+                renewed = leaf_output(g_leaf, h_leaf, cfg.split)
+                active = jnp.arange(L) < tree.num_leaves
+                tree = tree._replace(
+                    leaf_value=jnp.where(active, renewed, 0.0),
+                    leaf_weight=jnp.where(active, h_leaf, 0.0))
         return tree, row_leaf
 
     # ------------------------------------------------- streaming grow kit
@@ -2954,6 +3037,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                        rows_block=cfg.rows_block, packed4=cfg.packed4,
                        features=f if cfg.packed4 else 0)
 
+        @phase("grow/setup")
         def _prep(grad, hess, sample_mask, quant_key=None):
             """(vals, scale3) for one tree — the exact _grow_impl prologue
             (GOSS/bagging weights folded, quantized discretization keyed
@@ -2981,6 +3065,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             # so chunked folds replay the in-core adds exactly
             return jax.lax.optimization_barrier(vals), None
 
+        @phase("grow/hist")
         def _chunk_root(acc, bins_c, vals_c, count):
             """Accumulate one chunk's rows into the root histogram.
             ``count`` masks the static-shape pad tail: the driver slices
@@ -2993,6 +3078,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                                jnp.zeros_like(vals_c))
             return histogram_from_vals(bins_c, vals_c, init=acc, **hist_kw)
 
+        @phase("grow/setup")
         def _sk_init(root_hist, n_rows, scale3=None, meta=None,
                      feature_mask=None, key=None):
             # exact _grow_mask root block: per-channel totals from feature
@@ -3007,6 +3093,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             return _store_best(state, jnp.asarray(0), root_bs,
                                jnp.asarray(True))
 
+        @phase("grow/select")
         def _sk_select(st):
             """This step's split decision, read from the resident state —
             the scalars every chunk's partition/histogram pass consumes."""
@@ -3021,28 +3108,33 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                     st.best_is_cat[leaf], st.best_cat_mask[leaf],
                     target, small_is_left)
 
+        @phase("grow/hist")
         def _sk_chunk(acc, bins_c, vals_c, row_leaf_c, sel, nan_bins):
             """One chunk's share of one split: partition update for the
             chunk's rows + the smaller sibling's partial histogram.  Pad
             rows carry ``row_leaf == -1`` and contribute nothing."""
             (leaf, new_leaf, feat, sbin, dleft, scat, cmask,
              target, _sl) = sel
-            if cfg.packed4:
-                byte = jnp.take(bins_c, feat // 2, axis=1).astype(jnp.int32)
-                col = jnp.where(feat % 2 == 0, byte & 15, (byte >> 4) & 15)
-            else:
-                col = jnp.take(bins_c, feat, axis=1).astype(jnp.int32)
-            is_nan = col == nan_bins[feat]
-            go_left = jnp.where(scat, cmask[col], col <= sbin)
-            go_left = jnp.where(is_nan & ~scat, dleft, go_left)
-            mine = row_leaf_c == leaf
-            row_leaf_c = jnp.where(mine & ~go_left, new_leaf, row_leaf_c)
+            with phase("grow/partition"):
+                if cfg.packed4:
+                    byte = jnp.take(bins_c, feat // 2,
+                                    axis=1).astype(jnp.int32)
+                    col = jnp.where(feat % 2 == 0, byte & 15,
+                                    (byte >> 4) & 15)
+                else:
+                    col = jnp.take(bins_c, feat, axis=1).astype(jnp.int32)
+                is_nan = col == nan_bins[feat]
+                go_left = jnp.where(scat, cmask[col], col <= sbin)
+                go_left = jnp.where(is_nan & ~scat, dleft, go_left)
+                mine = row_leaf_c == leaf
+                row_leaf_c = jnp.where(mine & ~go_left, new_leaf, row_leaf_c)
             mask = row_leaf_c == target
             masked = jnp.where(mask[:, None], vals_c,
                                jnp.zeros_like(vals_c))
             acc = histogram_from_vals(bins_c, masked, init=acc, **hist_kw)
             return acc, row_leaf_c
 
+        @phase("grow/subtract")
         def _sk_apply(st, sel, hist_small, scale3=None, meta=None,
                       feature_mask=None):
             """Execute the selected split from the chunk-accumulated
@@ -3064,6 +3156,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                                      hist_right, gl, hl, cl, gr, hr, cr,
                                      meta, feature_mask, None, None, scale3)
 
+        @phase("grow/select")
         def _sk_probe(st):
             """(num_leaves, max_gain) — the while-loop condition scalars
             (the streaming driver's one tiny host sync per split)."""

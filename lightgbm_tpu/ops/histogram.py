@@ -28,6 +28,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.spans import kernel_rows
+
 
 def pack_bins4(bins: jnp.ndarray) -> jnp.ndarray:
     """Pack a (N, F) bin matrix whose bins all fit 4 bits (max_num_bins <=
@@ -176,10 +178,12 @@ def histogram_from_vals(
             dtype = "int8"
         else:
             dtype = "bf16" if impl == "flat_bf16" else "f32"
-        out = histogram_flat(bins, vals, num_bins=num_bins,
-                             rows_block=rows_block, dtype=dtype,
-                             packed4=packed4, features=features,
-                             interpret=interpret_mode())
+        # the launch's last scope segment: the rows this kernel is handed
+        with kernel_rows(bins.shape[0]):
+            out = histogram_flat(bins, vals, num_bins=num_bins,
+                                 rows_block=rows_block, dtype=dtype,
+                                 packed4=packed4, features=features,
+                                 interpret=interpret_mode())
         return out if init is None else init + out
     if impl == "onehot":
         return histogram_onehot(bins, vals, num_bins=num_bins,

@@ -11,6 +11,9 @@ publishes (docs/OBSERVABILITY.md):
   ``jax.profiler.TraceAnnotation`` + the lock-guarded hierarchical host
   timer behind one context manager.  Host-side, at dispatch boundaries
   only — ``tpu_telemetry=off`` compiles bitwise-identical programs.
+  Inside the compiled iteration, ``phase(name)`` (a ``jax.named_scope``
+  from the one list ``PHASES``) names each phase in the HLO metadata, so
+  a device trace splits by the program's own names.
 - **JSONL events** (:mod:`.events`): ``tpu_telemetry_log=<path>`` streams
   schema-versioned, monotonic-clocked events (``train.iter`` per committed
   round with dispatch-wait vs host-bookkeeping wall split, checkpoint
@@ -35,17 +38,19 @@ from .memory import (MEMORY_MODES, MemoryTracker, arm_memory_from_config,
                      set_memory_mode)
 from .prometheus import render_prometheus
 from .registry import (Counter, Gauge, Histogram, MetricsRegistry, registry)
-from .spans import (enabled, instrument, reset_spans, set_enabled, span,
-                    span_totals, watch_compiles)
+from .spans import (PHASES, enabled, instrument, kernel_rows, phase,
+                    reset_spans, set_enabled, span, span_totals,
+                    watch_compiles)
 
 __all__ = [
-    "MEMORY_MODES", "SCHEMA_VERSION", "Counter", "Gauge", "Histogram",
+    "MEMORY_MODES", "PHASES", "SCHEMA_VERSION", "Counter", "Gauge",
+    "Histogram",
     "JsonlSink", "MemoryTracker", "MetricsRegistry", "TrainTelemetry",
     "active_sink", "arm_from_config", "arm_memory_from_config",
     "close_log", "configure_log", "device_memory_stats", "emit", "enabled",
-    "host_peak_rss_mb", "instrument", "live_buffer_census",
+    "host_peak_rss_mb", "instrument", "kernel_rows", "live_buffer_census",
     "memory_analysis_summary", "memory_block", "memory_mode",
-    "note_compile", "registry", "render_prometheus", "reset_spans",
+    "note_compile", "phase", "registry", "render_prometheus", "reset_spans",
     "set_enabled", "set_memory_mode", "span", "span_totals",
     "telemetry_block", "train_session", "watch_compiles",
 ]

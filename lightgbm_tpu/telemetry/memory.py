@@ -95,7 +95,14 @@ def device_memory_stats(device=None) -> Optional[Dict[str, int]]:
     """``device.memory_stats()`` snapshot (``bytes_in_use`` always,
     ``peak_bytes_in_use``/``bytes_limit`` where the allocator reports
     them) — or ``None``, gracefully, on backends without memory
-    accounting (CPU jax returns None) or before jax is importable."""
+    accounting (CPU jax returns None) or before jax is importable.
+
+    ``peak_bytes_in_use`` counts the buffers the process held (bins,
+    scores, trees); the arena the runtime reserves for the loaded
+    programs' temporaries is ``peak_bytes_reserved`` (on a v5e 6.38 of the
+    6.54 GB of a Higgs iteration, PERF.md Findings PR 24).  Where the
+    runtime gives both, ``peak_bytes_total`` is their sum: the peak a
+    capacity decision wants."""
     try:
         if device is None:
             import jax
@@ -106,9 +113,13 @@ def device_memory_stats(device=None) -> Optional[Dict[str, int]]:
     if not stats or "bytes_in_use" not in stats:
         return None
     out = {"bytes_in_use": int(stats["bytes_in_use"])}
-    for key in ("peak_bytes_in_use", "bytes_limit", "largest_alloc_size"):
+    for key in ("peak_bytes_in_use", "peak_bytes_reserved", "bytes_limit",
+                "largest_alloc_size"):
         if key in stats:
             out[key] = int(stats[key])
+    if "peak_bytes_in_use" in out and "peak_bytes_reserved" in out:
+        out["peak_bytes_total"] = (out["peak_bytes_in_use"]
+                                   + out["peak_bytes_reserved"])
     return out
 
 
@@ -275,6 +286,11 @@ def span_end(path: str, token) -> None:
         reg.gauge("memory.bytes_in_use").set(stats["bytes_in_use"])
         if stats.get("peak_bytes_in_use") is not None:
             reg.gauge("memory.peak_bytes").set(stats["peak_bytes_in_use"])
+        if "peak_bytes_reserved" in stats:
+            # the loaded programs' temporaries (see device_memory_stats)
+            fields["peak_bytes_reserved"] = stats["peak_bytes_reserved"]
+            reg.gauge("memory.peak_bytes_reserved").set(
+                stats["peak_bytes_reserved"])
     else:
         # graceful-None contract: the event still lands (a CPU run's log
         # shows WHICH spans were tracked), just with no device numbers

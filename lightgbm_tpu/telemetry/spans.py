@@ -13,6 +13,11 @@ compiled program (and any blocking fetch), never code inside a trace —
 ``tpu_telemetry=off`` therefore compiles bitwise-identical programs and
 the dispatch census stays pinned (tests/test_telemetry.py).  Disabled
 spans cost one flag read.
+
+INSIDE a compiled program the names are phase scopes: ``with
+phase("grow/partition")`` is ``jax.named_scope`` — HLO metadata only, no
+operation, no knob, the same binary — so a device trace can say which
+phase an operation belongs to.  ``PHASES`` is the one list of those names.
 """
 
 from __future__ import annotations
@@ -29,10 +34,19 @@ from .registry import registry
 # keep whatever the last constructed booster asked for (default: on).
 _enabled = True
 
-# Dedicated span timer (not utils.timer.global_timer: the LGBM_TPU_TIMETAG
-# summary stays the legacy FunctionTimer surface; span totals are read
-# programmatically via span_totals / the bench telemetry block).
+# Span totals are read programmatically (span_totals / the bench
+# telemetry block).
 _span_timer = Timer()
+
+# The phases of one boosting iteration INSIDE the compiled program, in the
+# order the work runs (docs/OBSERVABILITY.md says what each holds).  The
+# program's scopes, the docs and benchmark/scope_names.json all name these.
+PHASES = (
+    "boost/gradients", "boost/score_update",
+    "grow/setup", "grow/select", "grow/partition", "grow/wave_gather",
+    "grow/wave_unpack", "grow/hist", "grow/subtract", "grow/scan",
+    "grow/reduce", "grow/update", "grow/finish",
+)
 
 _local = threading.local()
 
@@ -44,6 +58,24 @@ def set_enabled(on: bool) -> None:
 
 def enabled() -> bool:
     return _enabled
+
+
+def phase(name: str):
+    """``with phase("grow/scan"):`` (or ``@phase("grow/scan")`` on a
+    function) around code that is being TRACED: the operations it emits
+    carry ``name`` in their HLO ``op_name``.  Refuses a name that is not
+    in ``PHASES``."""
+    if name not in PHASES:
+        raise ValueError(f"phase {name!r} is not in telemetry.PHASES")
+    import jax
+    return jax.named_scope(name)
+
+
+def kernel_rows(rows: int):
+    """The last scope segment of a histogram-kernel launch: ``rows<R>``,
+    ``R`` the static number of rows that launch is handed."""
+    import jax
+    return jax.named_scope(f"rows{int(rows)}")
 
 
 def _stack():

@@ -1,2 +1,2 @@
 from .log import Log, register_log_callback  # noqa: F401
-from .timer import FunctionTimer, Timer, global_timer  # noqa: F401
+from .timer import Timer  # noqa: F401

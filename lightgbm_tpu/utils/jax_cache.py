@@ -27,11 +27,19 @@ def enable_compile_cache() -> str:
     one-second "worth caching" threshold off — a program that compiles in
     0.99 s one run and 1.01 s the next would otherwise enter the cache on
     the second run, and "a warm run adds no entries" could not be
-    checked."""
+    checked.
+
+    Either way the cache key keeps the operations' metadata.  jax strips
+    it by default, so two programs that differ only in their
+    ``jax.named_scope`` paths (``telemetry.PHASES``) share one entry, and
+    whichever compiled first decides the names a profiler trace of the
+    other shows: a trace taken after a cache hit could read another
+    version's phase names, or none."""
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir
-    import jax
     path = os.path.join(_CHECKOUT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)

@@ -1,12 +1,8 @@
-"""Hierarchical wall-clock timers + device-trace integration.
+"""Hierarchical wall-clock timer: per-name totals and counts.
 
-Reference: ``Common::Timer``/``FunctionTimer`` RAII spans aggregated per name and
-printed at exit under ``USE_TIMETAG`` (``utils/common.h:973-1057``; global
-instance ``src/boosting/gbdt.cpp:22``).
-
-TPU addition: named spans also open ``jax.profiler.TraceAnnotation`` regions so
-the same span set shows up in TPU profiler traces (the reference's hand
-instrumentation of hot paths, e.g. ``serial_tree_learner.cpp:180``).
+Reference: ``Common::Timer`` (``utils/common.h:973``).  The one span system
+built on it is ``telemetry.spans`` (``span`` opens the profiler annotation
+and adds the duration here); nothing else times spans.
 
 Thread-safety: concurrent serve threads (MicroBatcher worker + caller
 threads) time spans on the SAME instance, so every mutation is
@@ -16,12 +12,10 @@ STACK — nested same-name spans on one thread are re-entrancy-safe (each
 
 from __future__ import annotations
 
-import atexit
 import collections
-import os
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Tuple
 
 
 class Timer:
@@ -75,42 +69,3 @@ class Timer:
         for name, secs, cnt in self.snapshot():
             lines.append(f"  {name}: {secs:.3f}s (x{cnt})")
         return "\n".join(lines)
-
-    def print_at_exit(self) -> None:
-        # Through Log (stderr / the registered callback), never raw
-        # stdout: the atexit summary must not corrupt parseable CLI or
-        # bench JSON output.
-        def _emit():
-            from .log import Log
-            Log.info(self.summary())
-        atexit.register(_emit)
-
-
-global_timer = Timer()
-if os.environ.get("LGBM_TPU_TIMETAG"):
-    global_timer.print_at_exit()
-
-
-class FunctionTimer:
-    """Context-manager span: host timer + device trace annotation."""
-
-    def __init__(self, name: str, timer: Optional[Timer] = None):
-        self.name = name
-        self.timer = timer or global_timer
-        self._trace = None
-
-    def __enter__(self):
-        self.timer.start(self.name)
-        try:
-            import jax.profiler
-            self._trace = jax.profiler.TraceAnnotation(self.name)
-            self._trace.__enter__()
-        except Exception:
-            self._trace = None
-        return self
-
-    def __exit__(self, *exc):
-        if self._trace is not None:
-            self._trace.__exit__(*exc)
-        self.timer.stop(self.name)
-        return False

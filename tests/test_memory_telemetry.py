@@ -175,6 +175,32 @@ def test_device_stats_graceful_none_on_cpu():
         assert stats is not None and stats["bytes_in_use"] >= 0
 
 
+class _FakeDevice:
+    """A device whose runtime reports what a v5e's does (PERF.md, Findings
+    PR 24: most of an iteration's peak is the reserved arena)."""
+
+    def __init__(self, stats):
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize("stats, want", [
+    ({"bytes_in_use": 100, "peak_bytes_in_use": 160,
+      "peak_bytes_reserved": 6380, "bytes_limit": 16000},
+     {"bytes_in_use": 100, "peak_bytes_in_use": 160,
+      "peak_bytes_reserved": 6380, "peak_bytes_total": 6540,
+      "bytes_limit": 16000}),
+    # a runtime without the reserved arena: no sum is made up
+    ({"bytes_in_use": 100, "peak_bytes_in_use": 160},
+     {"bytes_in_use": 100, "peak_bytes_in_use": 160}),
+    ({}, None),
+])
+def test_device_stats_report_reserved_peak(stats, want):
+    assert memory.device_memory_stats(_FakeDevice(stats)) == want
+
+
 def test_host_rss_watermark_positive_and_resettable():
     ok = memory.MemoryTracker.reset_host_peak()
     v = memory.MemoryTracker.host_peak_rss_mb(use_hwm=ok)

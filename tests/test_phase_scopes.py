@@ -1,0 +1,187 @@
+"""Phase scopes inside the compiled iteration (telemetry.PHASES).
+
+``phase(name)`` is ``jax.named_scope``: HLO metadata only.  These tests
+lower the fused iteration, the unfused wave grower and the data-parallel
+grower at a tiny shape on the CPU and check that every name of ``PHASES``
+shows up in the lowered module's debug locations on the path that reaches
+it, that every histogram-kernel launch ends its scope path in ``rows<R>``
+with ``R`` the rows the launch is handed, and that the scopes change no
+tree.  (That the compiled TPU binary is the same is shown once, by hand,
+with the v5e compiler — PERF.md, Findings PR 25.)
+"""
+
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu import telemetry
+from lightgbm_tpu.telemetry import PHASES
+
+N, F = 6000, 6           # > _MIN_BUCKET rows: the permutation layouts run
+PARAMS = {"objective": "binary", "num_leaves": 15, "verbosity": -1,
+          "min_data_in_leaf": 5, "tpu_leaf_batch": 4, "metric": "none",
+          "tpu_histogram_impl": "pallas"}      # interpreted on the CPU
+PATHS = {
+    # the default program of higgs.train: ONE fused Pallas call per wave
+    "fused": ("boost/gradients", "boost/score_update", "grow/setup",
+              "grow/select", "grow/partition", "grow/wave_gather",
+              "grow/wave_unpack", "grow/scan", "grow/update", "grow/finish"),
+    # msltr.train's program: per-leaf histogram_flat, XLA subtract + scan
+    "unfused": ("boost/gradients", "boost/score_update", "grow/setup",
+                "grow/select", "grow/partition", "grow/hist",
+                "grow/subtract", "grow/scan", "grow/update", "grow/finish"),
+    # the data-parallel learner: every collective sits under grow/reduce
+    "sharded": ("grow/reduce", "grow/hist", "grow/partition"),
+}
+
+
+def _data():
+    rng = np.random.RandomState(0)
+    X = rng.randn(N, F).astype(np.float32)
+    y = (X[:, 0] + 0.5 * X[:, 1] * X[:, 2] + 0.2 * rng.randn(N) > 0)
+    return X, y.astype(np.float32)
+
+
+def _iteration(wave_kernel: str):
+    """``(fn, args)``: the fused iteration of a tiny Booster."""
+    X, y = _data()
+    params = dict(PARAMS, tpu_wave_kernel=wave_kernel)
+    ds = lgb.Dataset(X, label=y)
+    ds.construct(params)
+    g = lgb.Booster(params=params, train_set=ds)._gbdt
+    assert g.fused_path_active
+    assert g.wave_fused_active is (wave_kernel == "fused")
+    mask, fmask, _ = g._iter_masks(None, None)
+    return g._fused_core, (g.bins_dev, g.scores, mask, fmask,
+                           g.cfg.learning_rate)
+
+
+def _sharded_grow():
+    """``(fn, args)``: the wave grower per shard of a 2-device mesh."""
+    import lightgbm_tpu.models.grower as G
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.dataset import TrainData
+    from lightgbm_tpu.models.gbdt import _split_config
+    from lightgbm_tpu.parallel.mesh import DATA_AXIS, make_mesh
+
+    X, y = _data()
+    cfg = Config({"objective": "binary", "num_leaves": 15,
+                  "min_data_in_leaf": 5, "verbosity": -1})
+    td = TrainData.build(X.astype(np.float64), y.astype(np.float64), cfg)
+    meta = td.feature_meta_device()
+    args = (jnp.asarray(td.binned.bins), jnp.asarray(0.5 - y),
+            jnp.full(N, 0.25, jnp.float32), jnp.ones(N, jnp.float32),
+            jnp.ones(F, bool), meta["num_bins_per_feature"],
+            meta["nan_bins"], meta["is_categorical"], meta["monotone"])
+    gcfg = G.GrowerConfig(num_leaves=15, num_bins=td.binned.max_num_bins,
+                          split=_split_config(cfg), leaf_batch=4)
+    grow = G.make_grower(gcfg, mesh=make_mesh(2, 1), data_axis=DATA_AXIS)
+    return grow.raw, args
+
+
+def _program(path: str):
+    if path == "sharded":
+        return _sharded_grow()
+    return _iteration(path)
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    """Debug-location text of each path's lowered module, made once."""
+    cache = {}
+
+    def get(path):
+        if path not in cache:
+            fn, args = _program(path)
+            cache[path] = jax.jit(fn).lower(*args).as_text(debug_info=True)
+        return cache[path]
+    return get
+
+
+@pytest.mark.parametrize("path, name", [(p, n) for p, names in PATHS.items()
+                                        for n in names])
+def test_phase_scope_in_lowered_debug_locations(lowered, path, name):
+    assert re.search(r'[/"]' + re.escape(name) + r'[/"]', lowered(path)), \
+        f"{name} is not in the debug locations of the {path} program"
+
+
+def test_every_phase_is_reached_and_nothing_else_is_named():
+    assert {n for names in PATHS.values() for n in names} == set(PHASES)
+    assert all(re.fullmatch(r"[a-z]+/[a-z_]+", n) for n in PHASES)
+    with pytest.raises(ValueError, match="PHASES"):
+        telemetry.phase("grow/no_such_phase")
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for x in (v if isinstance(v, (list, tuple)) else [v]):
+            inner = getattr(x, "jaxpr", x)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _walk(jaxpr, prefix=""):
+    """``(equation, scope path)``: a sub-jaxpr's name stacks are relative
+    to the equation that holds it."""
+    for eqn in jaxpr.eqns:
+        full = f"{prefix}/{eqn.source_info.name_stack}".strip("/")
+        yield eqn, full
+        for sub in _sub_jaxprs(eqn):
+            yield from _walk(sub, full)
+
+
+@pytest.mark.parametrize("path", ["fused", "unfused"])
+def test_kernel_launch_sites_end_in_the_rows_they_are_handed(path):
+    fn, args = _program(path)
+    launches = []
+    for eqn, scope in _walk(jax.make_jaxpr(fn)(*args).jaxpr):
+        kernel = eqn.params.get("name")
+        if kernel == "histogram_flat":          # bins (R, F)
+            rows = eqn.invars[0].aval.shape[0]
+        elif kernel == "fused_wave_call":       # gathered bins (W, S, F)
+            rows = int(np.prod(eqn.invars[0].aval.shape[:2]))
+        else:
+            continue
+        launches.append(kernel)
+        last = scope.split("/")[-1]
+        assert last == f"rows{rows}", (kernel, scope, rows)
+        assert any(p in scope for p in PHASES), scope
+    assert "histogram_flat" in launches          # the root pass
+    assert ("fused_wave_call" in launches) is (path == "fused")
+    assert len(launches) > 2                     # one launch per bucket
+
+
+class _NoScope(contextlib.ContextDecorator):
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.mark.parametrize("wave_kernel", ["fused", "unfused"])
+def test_trees_are_bitwise_what_they_are_without_scopes(monkeypatch,
+                                                        wave_kernel):
+    X, y = _data()
+    params = dict(PARAMS, tpu_wave_kernel=wave_kernel)
+
+    def model():
+        return lgb.train(params, lgb.Dataset(X, label=y),
+                         num_boost_round=3).model_to_string()
+
+    scoped = model()
+    monkeypatch.setattr(jax, "named_scope", lambda name: _NoScope())
+    assert model() == scoped
+
+
+def test_the_second_span_system_is_gone():
+    import lightgbm_tpu.utils as utils
+    import lightgbm_tpu.utils.timer as timer
+    for gone in ("FunctionTimer", "global_timer"):
+        assert not hasattr(utils, gone) and not hasattr(timer, gone)
+    assert utils.Timer is timer.Timer        # telemetry.spans keeps it

@@ -66,14 +66,16 @@ def test_compile_cache_helper(monkeypatch):
     # placed from outside: jax reads the variable itself, code sets no
     # other directory
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    keyed = ("jax_compilation_cache_include_metadata_in_key", True)
     assert jax_cache.enable_compile_cache() == "/somewhere/else"
-    assert updates == []
+    assert updates == [keyed]      # phase names are part of the key
+    del updates[:]
     # unset: one fixed path inside the checkout, the same on every call
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     fixed = os.path.join(_ROOT, ".jax_cache")
     assert jax_cache.enable_compile_cache() == fixed
     assert jax_cache.enable_compile_cache() == fixed
-    assert updates == [("jax_compilation_cache_dir", fixed),
+    assert updates == [keyed, ("jax_compilation_cache_dir", fixed),
                        ("jax_persistent_cache_min_compile_time_secs", 0.0)] * 2
 
 
