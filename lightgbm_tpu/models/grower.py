@@ -524,6 +524,33 @@ def _split_buckets(n: int) -> list:
     return sizes
 
 
+# Steps per octave of the ragged fused wave's TOTAL-row ladder.  The kernel
+# skips a step's padding blocks, so only the gather that feeds it pays for
+# them: 2 steps cap that padding at 1.41x (1 step: the power-of-two 2x)
+# for twice the compiled kernel instances.  On a v5e at 1.5 M x 28
+# (PERF.md, Findings PR 26): 1 / 2 / 4 steps hand the kernels 74.8 / 85.9
+# / 92.4 % needed rows, gather 0.199 / 0.157 / 0.151 s an iteration, and
+# compile cold in 75 / 82 / 101 s.
+_WAVE_LADDER_STEPS = 2
+
+
+def _wave_row_ladder(lo: int, hi: int, blk: int) -> list:
+    """Static TOTAL-row sizes of the ragged fused wave, whole row blocks
+    each, ascending: ``hi`` (the most a wave can hold) and
+    ``_WAVE_LADDER_STEPS`` geometric steps per octave below it, down to
+    ``lo`` (never under the perm layouts' smallest bucket; a step within
+    half a step of it is left out)."""
+    lo = max(lo, -(-_MIN_BUCKET // blk) * blk)
+    sizes, k = [], 0
+    while True:
+        t = -(-int(hi / 2.0 ** (k / _WAVE_LADDER_STEPS)) // blk) * blk
+        if t <= lo * 2.0 ** (0.5 / _WAVE_LADDER_STEPS):
+            return [max(lo, t)] + sizes[::-1]
+        if not sizes or t < sizes[-1]:
+            sizes.append(t)
+        k += 1
+
+
 def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
     """Build the jitted ``grow(bins, grad, hess, sample_mask, feature_mask, meta...)``
     function.  All shapes/hyper-params are compile-time; data is traced.
@@ -2156,12 +2183,17 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         """Wave growth (permutation layout): split the top-W leaves per step.
 
         Per wave: partition each chosen leaf's contiguous segment, histogram
-        each SMALLER sibling's contiguous range with the flat kernel (it is
-        HBM-bandwidth-bound, so W sequential bandwidth-optimal calls beat
-        one M-packed multi-sibling kernel — measured ~100x on v5e), get the
-        larger siblings by subtraction, and run one vmapped split search
-        over all 2W children.  Sequential depth per tree drops from
-        num_leaves-1 steps to ~ceil((num_leaves-1)/W)."""
+        each SMALLER sibling's contiguous range, get the larger siblings by
+        subtraction, and search all 2W children's splits.  Unfused that is
+        W per-leaf ``histogram_flat`` calls at each leaf's own bucket, an
+        XLA subtract and one vmapped scan; fused (``_fused_wave``) it is
+        ONE ragged kernel launch over the W segments packed back to back.
+        Either way the cost is the rows handed over — the kernels run at
+        some 21 M rows/s on a v5e, 47 ns a row, nowhere near the HBM
+        stream's rate (PERF_LEDGER.jsonl, PR 25; PERF.md section 5) — so
+        no path pads a wave beyond the rows it holds.  Sequential depth
+        per tree drops from num_leaves-1 steps to
+        ~ceil((num_leaves-1)/W)."""
         n, gcols = bins.shape
         f = meta[0].shape[0]
         W = min(cfg.leaf_batch, max(L - 1, 1))
@@ -2184,9 +2216,9 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                                           meta[0].shape[0])
                          for S in buckets]
 
-        def _bucket_of(cnt):
-            return jnp.clip(jnp.searchsorted(buckets_arr, cnt, side="left"),
-                            0, len(buckets) - 1).astype(jnp.int32)
+        def _bucket_of(cnt, sizes=buckets_arr):
+            return jnp.clip(jnp.searchsorted(sizes, cnt, side="left"),
+                            0, sizes.shape[0] - 1).astype(jnp.int32)
 
         # ---- fused wave kernel (ops/pallas_wave.py): composition gate
         # resolved in make_grower (wave_fused_req), shape gate here —
@@ -2196,7 +2228,8 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             from ..ops.pallas_common import C_PAD, interpret_mode
             from ..ops.pallas_wave import (fused_wave_call, hist_from_flat,
                                            hist_to_flat, payload_to_best,
-                                           plane_order, wave_dtype_for,
+                                           plane_order, wave_block_map,
+                                           wave_block_slots, wave_dtype_for,
                                            wave_layout, wave_meta)
             wave_dtype = wave_dtype_for(cfg)
             _lay = wave_layout(f, HB, wave_dtype, cfg.rows_block,
@@ -2211,15 +2244,25 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                           else jnp.pad(scale3, (0, 1))
                           .reshape(1, 4).astype(jnp.float32))
 
+            blk = _lay["rows_block"]
+            # smaller siblings of disjoint leaves hold at most half the
+            # rows, and each of the W slots rounds up to whole blocks
+            wave_totals = _wave_row_ladder(W * blk,
+                                           (n // (2 * blk) + W) * blk, blk)
+            wave_totals_arr = jnp.asarray(wave_totals, jnp.int32)
+
             def _fused_wave(perm, small_start, small_cnt, small_left,
                             parent_hist, g2c, h2c, c2c, o2c, active):
-                """ONE pallas dispatch for the whole wave: gather the W
-                smaller siblings' contiguous perm segments (padded to the
-                wave's largest bucket — phantom rows hit the zero row, so
-                the accumulated values match the per-leaf buckets
-                exactly), build + subtract + scan in VMEM, and return
-                ``(hist_left, hist_right, bs)`` with the 2W-child
-                BestSplit batch in the unfused path's cat2 ordering."""
+                """ONE pallas dispatch for the whole wave, handed the rows
+                the wave has: the W smaller siblings' contiguous perm
+                segments are packed back to back in whole row blocks
+                (``wave_block_map``; rows past a slot's count hit the
+                phantom zero row), padded only to the next step of the
+                TOTAL-row ladder, and a block -> slot map tells the kernel
+                whose histogram each block belongs to.  Build + subtract +
+                scan in VMEM; returns ``(hist_left, hist_right, bs)`` with
+                the 2W-child BestSplit batch in the unfused path's cat2
+                ordering."""
                 with phase("grow/wave_unpack"):
                     parent_flat = hist_to_flat(parent_hist, _lay["ftile"],
                                                _lay["b_pad"], _w_order)
@@ -2232,35 +2275,40 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                         [g2c, h2c, c2c, o2c, sl2, act2, z2, z2],
                         axis=-1)                             # (W, 2, 8)
 
-                def branch_for(S):
+                with phase("grow/select"):
+                    _, off, nb_total = wave_block_map(small_cnt, blk)
+                    ti = _bucket_of(nb_total * blk, wave_totals_arr)
+
+                def branch_for(T):
                     # the gather that feeds the kernel, and the kernel
                     # launch, whose path ends in the rows it is handed
                     @phase("grow/wave_gather")
                     def br(_):
+                        slot, k = wave_block_slots(off, T // blk)
+                        row0 = k * blk
                         seg = jax.vmap(
                             lambda s0: jax.lax.dynamic_slice(
-                                perm, (s0,), (S,)))(small_start)
-                        valid = (jnp.arange(S, dtype=jnp.int32)[None, :]
-                                 < small_cnt[:, None])
-                        seg = jnp.where(valid, seg, n)
-                        gbins = bins_pad[seg]                # (W, S, ct)
-                        gvT = jnp.transpose(
-                            jnp.pad(vals_pad[seg],
-                                    ((0, 0), (0, 0), (0, C_PAD - 3))),
-                            (0, 2, 1))                       # (W, C_PAD, S)
-                        with kernel_rows(W * S):
+                                perm, (s0,), (blk,)))(
+                                    small_start[slot] + row0)
+                        valid = (row0[:, None]
+                                 + jnp.arange(blk, dtype=jnp.int32)[None, :]
+                                 < small_cnt[slot][:, None])
+                        seg = jnp.where(valid, seg, n).reshape(T)
+                        gbins = bins_pad[seg]                # (T, ct)
+                        gvT = jnp.pad(vals_pad[seg],
+                                      ((0, 0), (0, C_PAD - 3))).T
+                        with kernel_rows(T):
                             return fused_wave_call(
                                 gbins, gvT, parent_flat, stats, wave_meta_w,
-                                wave_scale, num_bins=HB, features=f,
-                                rows_block=min(cfg.rows_block, S),
+                                slot, nb_total[None], wave_scale,
+                                num_bins=HB, features=f,
+                                rows_block=cfg.rows_block,
                                 dtype=wave_dtype, packed4=cfg.packed4,
                                 scfg=cfg.split, interpret=interpret_mode())
                     return br
 
-                with phase("grow/select"):
-                    bi = jnp.max(jnp.where(active, _bucket_of(small_cnt), 0))
                 hist2, payload = jax.lax.switch(
-                    bi, [branch_for(S) for S in buckets], 0)
+                    ti, [branch_for(T) for T in wave_totals], 0)
                 with phase("grow/wave_unpack"):
                     child = hist_from_flat(hist2, f, HB, _lay["b_pad"],
                                            _w_inv)           # (W,2,F,HB,3)

@@ -18,12 +18,14 @@ Why this shape wins on the MXU:
   are chunked at trace time into separate same-shaped calls so the VMEM
   one-hot stays bounded (and every BlockSpec dim is Mosaic-legal: the
   feature dim always equals the array dim, row blocks are 128-multiples).
-- The kernel is HBM-bandwidth-bound (bins + vals streams), so the wave
-  grower issues one bandwidth-optimal call per smaller sibling instead of
-  packing siblings into the matmul M dimension (measured ~100x faster on
-  v5e than an M-packed multi-sibling kernel); the streamed volume stays
-  proportional to the rows actually histogrammed — the reference's
-  smaller-sibling trick (``serial_tree_learner.cpp:369``).
+- The kernel is NOT HBM-bandwidth-bound: on a v5e it runs at 21 M rows/s,
+  47 ns a row at 28 features x 255 bins f32 (PERF_LEDGER.jsonl, PR 25;
+  0.013 % of its byte roofline); what binds instead is not measured (the
+  candidates: the one-hot build on the VPU, the six-pass f32 matmul at
+  M = 4).  Its time is the rows it is handed, so the streamed volume
+  stays proportional to the rows actually histogrammed: the reference's
+  smaller-sibling trick (``serial_tree_learner.cpp:369``), one call per
+  smaller sibling at its own bucket, or the fused wave's one ragged launch.
 - int8 variant: s8 vals x s8 one-hot -> s32 accumulation — the reference's
   quantized-training histograms (``Int32HistogramSumReducer``, ``bin.h:48``)
   on the MXU's double-rate int8 path.

@@ -14,9 +14,14 @@ fusion once the histogram itself is matmul-shaped.
 This kernel runs the whole sequence while the (C_PAD, F*B) accumulators
 are VMEM-resident:
 
-- grid ``(W, row_blocks)`` — one ``pallas_call`` per WAVE, leaf batches
-  pipelined through the leading grid dimension (vs one histogram dispatch
-  per leaf unfused);
+- grid ``(row_blocks,)`` over a RAGGED wave — one ``pallas_call`` per
+  WAVE (vs one histogram dispatch per leaf unfused): the W smaller
+  siblings' rows lie back to back in whole row blocks
+  (:func:`wave_block_map`), a scalar-prefetched block -> slot map steers
+  each block's parent / stats / output blocks, and the launch is handed
+  the rows the wave has (padded to a step of ONE total-row ladder), not
+  W x its largest leaf's bucket.  Blocks past the wave's last real block
+  do nothing and move no data;
 - (a) the smaller sibling accumulates via the SAME in-VMEM one-hot matmul
   as ``histogram_flat`` (``ops/pallas_common.onehot_contract`` — shared
   code, op-for-op identical accumulation, including the packed4 nibble
@@ -33,7 +38,12 @@ are VMEM-resident:
 HBM traffic per wave drops to one bins+vals stream plus the O(W * G * B)
 child-histogram writeback the pool retains and a tiny (W, 2, 16+B)
 SplitInfo payload — the full (L, G, B, 3) tensor never round-trips between
-build and scan (pinned structurally in tests/test_hlo_cost.py).
+build and scan (pinned structurally in tests/test_hlo_cost.py).  The
+kernel is NOT bandwidth-bound: on a v5e it takes 47 ns a row, 21 M rows/s
+at 28 features x 255 bins f32 (PERF_LEDGER.jsonl, PR 25: 2.09 s for
+44.3 M rows; 0.013 % of its byte roofline; what binds instead is not
+measured) — so its time is the rows it is handed, and the ragged packing
+is what keeps that at the rows the tree needs.
 
 Quantized training rides the int8/int32 accumulation path (``DTYPES``),
 subtraction stays exact integer arithmetic, and the per-iteration scales
@@ -97,13 +107,14 @@ def wave_layout(features: int, num_bins: int, dtype: str,
     constraint and the working-set budget in one testable place (the
     ``kernel_layout`` discipline, extended with the fused extras):
 
-    - row blocking comes from ``kernel_layout`` UNCHANGED, resolved at the
-      wave's SHARED bucket (the largest smaller-sibling bucket of the
-      wave) — the unfused path resolves it per leaf, so a leaf whose own
-      bucket is smaller can see different f32 partial-sum grouping; the
-      accumulated VALUES are identical whenever histogram sums are
-      exactly representable (and always under int32 quantized), which is
-      the scope of the bitwise-identity pins;
+    - row blocking comes from ``kernel_layout`` UNCHANGED at the
+      configured ``rows_block``: ONE block size for every launch, and each
+      slot accumulates its rows in blocks of it from its segment's start —
+      what ``histogram_flat`` does on that segment alone, so the f32 sums
+      group identically whenever the per-leaf path resolves the same
+      block (it resolves ``min(rows_block, bucket)``, which differs only
+      where a leaf's bucket is under the layout's block; integer
+      histograms are exact either way);
     - ``single_chunk``: the kernel scans the whole feature space in one
       block — trace-time feature chunking (very wide F) cannot fuse, those
       shapes keep the unfused path (plus the pool + tiled scan that
@@ -228,45 +239,83 @@ def payload_to_best(pay: jnp.ndarray) -> BestSplit:
         sum_grad_right=col(8), sum_hess_right=col(9), count_right=col(10))
 
 
-def _wave_kernel(*refs, nblocks, ftile, b_pad, key_bins, oh_dtype,
-                 acc_dtype, precision, packed4, scfg, has_scale):
-    """Kernel body at grid point (w, rb): accumulate row block ``rb`` of
-    leaf ``w``'s smaller sibling, and at the last block subtract the
-    parent, reorder into (left, right) and scan both children."""
+def wave_block_map(small_cnt: jnp.ndarray, blk: int):
+    """The ragged wave's packing, from the (W,) smaller-sibling row counts:
+    ``(nb, off, nb_total)``.  Slot ``j`` owns the ``nb[j] = max(1,
+    ceil(cnt_j / blk))`` consecutive row blocks starting at block
+    ``off[j]`` — one block even for an empty or inactive slot, so every
+    slot's output block is visited, zero-initialised and scanned — and
+    the wave holds ``nb_total = sum(nb)`` real blocks:
+    ``nb_total * blk <= sum(cnt) + W * blk``."""
+    nb = jnp.maximum((small_cnt.astype(jnp.int32) + (blk - 1)) // blk, 1)
+    off = jnp.cumsum(nb) - nb
+    return nb, off, jnp.sum(nb)
+
+
+def wave_block_slots(off: jnp.ndarray, nblocks: int):
+    """``(blk_slot, blk_in_slot)`` for the ``nblocks`` row blocks of one
+    launch: block ``b`` accumulates into slot ``blk_slot[b]`` and is that
+    slot's ``blk_in_slot[b]``-th block (``b - off[slot]``).  Blocks past
+    the wave's last real block name the last slot (so the kernel's index
+    maps do not move) at a block past its rows (so the gather masks them
+    to the phantom row)."""
+    b = jnp.arange(nblocks, dtype=jnp.int32)
+    slot = jnp.searchsorted(off, b, side="right").astype(jnp.int32) - 1
+    return slot, b - off[slot]
+
+
+def _wave_kernel(*refs, ftile, b_pad, key_bins, oh_dtype, acc_dtype,
+                 precision, packed4, scfg, has_scale):
+    """Kernel body at row block ``b`` of the packed wave: accumulate the
+    block into its slot's smaller-sibling histogram (zeroed at the slot's
+    first block), and at the slot's last block subtract the parent,
+    reorder into (left, right) and scan both children.  Blocks at or past
+    ``nb_total`` are the ladder step's padding and do nothing."""
+    slot_ref, nb_ref = refs[:2]
     if has_scale:
         (bins_ref, valsT_ref, parent_ref, stats_ref, meta_ref, scale_ref,
-         hist_ref, pay_ref) = refs
+         hist_ref, pay_ref) = refs[2:]
     else:
         (bins_ref, valsT_ref, parent_ref, stats_ref, meta_ref,
-         hist_ref, pay_ref) = refs
+         hist_ref, pay_ref) = refs[2:]
         scale_ref = None
-    rb = pl.program_id(1)
+    b = pl.program_id(0)
+    nb_total = nb_ref[0]
+    slot = slot_ref[b]
+    real = b < nb_total
+    # a slot's blocks are consecutive: neighbour compares find its ends
+    first = (b == 0) | (slot_ref[jnp.maximum(b - 1, 0)] != slot)
+    last = ((b == nb_total - 1)
+            | (slot_ref[jnp.minimum(b + 1, pl.num_programs(0) - 1)] != slot))
 
-    @pl.when(rb == 0)
+    @pl.when(real & first)
     def _init():
         hist_ref[:] = jnp.zeros_like(hist_ref)
 
-    bins_blk = bins_ref[0].astype(jnp.int32)             # (blk, ct)
-    valsT = valsT_ref[0]                                 # (C_PAD, blk)
-    if oh_dtype != valsT.dtype:
-        valsT = valsT.astype(oh_dtype)
+    @pl.when(real)
+    def _accumulate():
+        bins_blk = bins_ref[:].astype(jnp.int32)         # (blk, ct)
+        valsT = valsT_ref[:]                             # (C_PAD, blk)
+        if oh_dtype != valsT.dtype:
+            valsT = valsT.astype(oh_dtype)
 
-    def contract(b2d):
-        return onehot_contract(b2d, valsT, num_bins=b_pad,
-                               oh_dtype=oh_dtype, acc_dtype=acc_dtype,
-                               precision=precision)
+        def contract(b2d):
+            return onehot_contract(b2d, valsT, num_bins=b_pad,
+                                   oh_dtype=oh_dtype, acc_dtype=acc_dtype,
+                                   precision=precision)
 
-    if packed4:
-        # Two 4-bit features per streamed byte (reference DenseBin IS_4BIT,
-        # dense_bin.hpp): unpack in VMEM, contract the nibble planes into
-        # contiguous output halves — identical to _flat_kernel.
-        half = (ftile // 2) * b_pad
-        hist_ref[0, 0, :, :half] += contract(bins_blk & 15)
-        hist_ref[0, 0, :, half:] += contract((bins_blk >> 4) & 15)
-    else:
-        hist_ref[0, 0] += contract(bins_blk)
+        if packed4:
+            # Two 4-bit features per streamed byte (reference DenseBin
+            # IS_4BIT, dense_bin.hpp): unpack in VMEM, contract the nibble
+            # planes into contiguous output halves — identical to
+            # _flat_kernel.
+            half = (ftile // 2) * b_pad
+            hist_ref[0, 0, :, :half] += contract(bins_blk & 15)
+            hist_ref[0, 0, :, half:] += contract((bins_blk >> 4) & 15)
+        else:
+            hist_ref[0, 0] += contract(bins_blk)
 
-    @pl.when(rb == nblocks - 1)
+    @pl.when(real & last)
     def _subtract_and_scan():
         small = hist_ref[0, 0, :, :]                     # (C_PAD, fb)
         parent = parent_ref[0]
@@ -329,11 +378,13 @@ def _wave_kernel(*refs, nblocks, ftile, b_pad, key_bins, oh_dtype,
     jax.jit, static_argnames=("num_bins", "features", "rows_block", "dtype",
                               "packed4", "scfg", "interpret"))
 def fused_wave_call(
-    gbins: jnp.ndarray,        # (W, S, ct) gathered smaller-sibling rows
-    gvalsT: jnp.ndarray,       # (W, C_PAD, S) gathered channel values
+    gbins: jnp.ndarray,        # (T, ct) the wave's rows, packed by slot
+    gvalsT: jnp.ndarray,       # (C_PAD, T) their channel values
     parent_flat: jnp.ndarray,  # (W, C_PAD, ftile*b_pad) parent histograms
     stats: jnp.ndarray,        # (W, 2, STAT_LANES) per-child scalars
     meta: jnp.ndarray,         # (ftile, 8) i32 [nbpf|nan|is_cat|fmask|...]
+    blk_slot: jnp.ndarray,     # (T / blk,) i32 row block -> slot
+    nb_total: jnp.ndarray,     # (1,) i32 real row blocks of the wave
     scale3: jnp.ndarray | None = None,   # (1, 4) f32 quantized scales
     *,
     num_bins: int,             # REAL scan bin count (HB)
@@ -344,11 +395,16 @@ def fused_wave_call(
     scfg: SplitConfig = None,
     interpret: bool = False,
 ):
-    """One fused wave: returns ``(child_hists, payload)`` where
-    ``child_hists`` is (W, 2, C_PAD, ftile*b_pad) RAW (left, right)
-    histograms in the flat layout and ``payload`` is the (W, 2,
-    PAYLOAD_SCALARS + num_bins) per-child SplitInfo block."""
-    w, s, ct = gbins.shape
+    """One fused RAGGED wave: the W slots' smaller-sibling rows lie back
+    to back in ``gbins`` / ``gvalsT`` at row-block granularity
+    (:func:`wave_block_map`), slot ``blk_slot[b]`` owning block ``b``;
+    rows past a slot's count inside its last block, and every block at or
+    past ``nb_total``, are phantom (zero values).  Returns ``(child_hists,
+    payload)``: (W, 2, C_PAD, ftile*b_pad) RAW (left, right) histograms in
+    the flat layout and the (W, 2, PAYLOAD_SCALARS + num_bins) per-child
+    SplitInfo block.  ``T`` is a multiple of the layout's row block."""
+    t, ct = gbins.shape
+    w = parent_flat.shape[0]
     oh_dtype, acc_dtype, _ = DTYPES[dtype]
     blk, ftile, cols_tile, b_pad = kernel_layout(
         features, num_bins, dtype, rows_block, packed4)
@@ -357,52 +413,59 @@ def fused_wave_call(
             f"fused wave needs the single-chunk layout: got {ct} bin "
             f"columns / parent width {parent_flat.shape[-1]} vs layout "
             f"({cols_tile}, {ftile * b_pad}); check wave_layout_fits")
+    if t % blk or blk_slot.shape != (t // blk,):
+        raise ValueError(
+            f"fused wave needs whole row blocks and one slot per block: "
+            f"got {t} rows, {blk_slot.shape} slots, row block {blk}")
     precision = (jax.lax.Precision.HIGHEST if dtype == "f32"
                  else jax.lax.Precision.DEFAULT)
-    pad = (-s) % blk
-    if pad:
-        gbins = jnp.pad(gbins, ((0, 0), (0, pad), (0, 0)))
-        gvalsT = jnp.pad(gvalsT, ((0, 0), (0, 0), (0, pad)))
-    nblocks = (s + pad) // blk
     fb = ftile * b_pad
     pay_w = PAYLOAD_SCALARS + num_bins
     has_scale = scale3 is not None
     kern = functools.partial(
-        _wave_kernel, nblocks=nblocks, ftile=ftile, b_pad=b_pad,
-        key_bins=num_bins, oh_dtype=oh_dtype, acc_dtype=acc_dtype,
-        precision=precision, packed4=packed4, scfg=scfg,
-        has_scale=has_scale)
+        _wave_kernel, ftile=ftile, b_pad=b_pad, key_bins=num_bins,
+        oh_dtype=oh_dtype, acc_dtype=acc_dtype, precision=precision,
+        packed4=packed4, scfg=scfg, has_scale=has_scale)
+
+    # Padding blocks re-name the last real block, so no DMA is issued
+    # for them; everything per-slot follows the block's slot.
     in_specs = [
-        pl.BlockSpec((1, blk, ct), lambda i, r: (i, r, 0),
+        pl.BlockSpec((blk, ct),
+                     lambda b, slot, nb: (jnp.minimum(b, nb[0] - 1), 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, C_PAD, blk), lambda i, r: (i, 0, r),
+        pl.BlockSpec((C_PAD, blk),
+                     lambda b, slot, nb: (0, jnp.minimum(b, nb[0] - 1)),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, C_PAD, fb), lambda i, r: (i, 0, 0),
+        pl.BlockSpec((1, C_PAD, fb), lambda b, slot, nb: (slot[b], 0, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, 2, STAT_LANES), lambda i, r: (i, 0, 0),
+        pl.BlockSpec((1, 2, STAT_LANES), lambda b, slot, nb: (slot[b], 0, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((ftile, 8), lambda i, r: (0, 0),
+        pl.BlockSpec((ftile, 8), lambda b, slot, nb: (0, 0),
                      memory_space=pltpu.VMEM),
     ]
     inputs = [gbins, gvalsT, parent_flat, stats, meta]
     if has_scale:
-        in_specs.append(pl.BlockSpec((1, 4), lambda i, r: (0, 0),
+        in_specs.append(pl.BlockSpec((1, 4), lambda b, slot, nb: (0, 0),
                                      memory_space=pltpu.VMEM))
         inputs.append(scale3)
     return pl.pallas_call(
         kern,
-        grid=(w, nblocks),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 2, C_PAD, fb), lambda i, r: (i, 0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 2, pay_w), lambda i, r: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(t // blk,),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, 2, C_PAD, fb),
+                             lambda b, slot, nb: (slot[b], 0, 0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, 2, pay_w),
+                             lambda b, slot, nb: (slot[b], 0, 0),
+                             memory_space=pltpu.VMEM),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((w, 2, C_PAD, fb), acc_dtype),
             jax.ShapeDtypeStruct((w, 2, pay_w), jnp.float32),
         ],
-        compiler_params=compiler_params("arbitrary", "arbitrary"),
+        compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
-    )(*inputs)
+    )(blk_slot.astype(jnp.int32), nb_total.astype(jnp.int32), *inputs)

@@ -291,15 +291,13 @@ def test_comm_ratio_unpadded_and_int16_wire():
     assert re.search(r"s32\[[0-9,]*\][^=]*reduce-scatter", quant)
 
 
-def test_fused_wave_no_hbm_scan_roundtrip():
-    """ISSUE-7 structural pin: the fused wave program must not round-trip
-    the batched child histograms through HBM between build and scan.  The
-    unfused wave feeds all 2W children's (F, B) cumsum/gain tables through
-    a vmapped best_split — the (2W, F, B) f32 scan buffers are its
-    signature shape; the fused program scans per leaf INSIDE the kernel
-    (interpret mode inlines it as per-grid-step (F, b_pad) blocks), so no
-    wave-batched scan tensor may exist anywhere in the compiled text."""
-    NW, FW, BW, LW, WW = 4096, 12, 64, 63, 8
+# the fused-vs-unfused structural pins' shape
+NW, FW, BW, LW, WW = 4096, 12, 64, 63, 8
+
+
+@pytest.fixture(scope="module")
+def wave_pair():
+    """Compiled text of the SAME small wave grower, fused and unfused."""
     scfg = G.SplitConfig(has_nan=False, has_categorical=False,
                          use_sorted_categorical=False, has_monotone=False,
                          min_data_in_leaf=1)
@@ -317,7 +315,18 @@ def test_fused_wave_no_hbm_scan_roundtrip():
         assert grow.wave_fused == (mode == "fused")
         return grow.lower(*args).compile().as_text()
 
-    fused, unfused = compile_txt("fused"), compile_txt("unfused")
+    return {"fused": compile_txt("fused"), "unfused": compile_txt("unfused")}
+
+
+def test_fused_wave_no_hbm_scan_roundtrip(wave_pair):
+    """ISSUE-7 structural pin: the fused wave program must not round-trip
+    the batched child histograms through HBM between build and scan.  The
+    unfused wave feeds all 2W children's (F, B) cumsum/gain tables through
+    a vmapped best_split — the (2W, F, B) f32 scan buffers are its
+    signature shape; the fused program scans per leaf INSIDE the kernel
+    (interpret mode inlines it as per-grid-step (F, b_pad) blocks), so no
+    wave-batched scan tensor may exist anywhere in the compiled text."""
+    fused, unfused = wave_pair["fused"], wave_pair["unfused"]
     scan_buf = f"f32[{2 * WW},{FW},{BW}]"
     assert scan_buf in unfused, "unfused signature shape missing"
     assert scan_buf not in fused, (
@@ -326,6 +335,26 @@ def test_fused_wave_no_hbm_scan_roundtrip():
     # tensor; the fused kernel accumulates per leaf in VMEM, so the only
     # wave-batched histogram left is the (W, 2, ...) child writeback
     assert f"f32[{WW},{FW},{BW},3]" in unfused
+
+
+def test_fused_wave_gathers_no_more_than_a_wave_holds(wave_pair):
+    """ISSUE-26 structural pin: the compiled fused program hands the
+    gather and the kernel the rows the wave has.  No gathered-bins tensor
+    anywhere in it has more rows than a wave can hold — half the rows (the
+    smaller siblings of disjoint leaves) plus one row block per slot — or
+    than the data itself; the (W, S, F) form, W x the wave's largest
+    bucket (at this shape 16 384 and 32 768 rows), is gone."""
+    from lightgbm_tpu.ops.pallas_wave import wave_layout
+
+    blk = wave_layout(FW, BW, "f32")["rows_block"]
+    cap = (NW // (2 * blk) + WW) * blk
+    assert cap < WW * G._MIN_BUCKET       # below the old form's SMALLEST
+    rows = {int(np.prod([int(d) for d in dims.split(",")][:-1]))
+            for dt, dims in _parse_shapes(wave_pair["fused"])
+            if dt == "u8" and dims.endswith(f",{FW}")}
+    assert cap in rows, sorted(rows)      # the ladder's top step is there
+    assert max(rows) <= max(cap, NW + 1), sorted(rows)
+    assert not re.search(rf"u8\[{WW},\d+,{FW}\]", wave_pair["fused"])
 
 
 def test_program_flops_bounded(hlo):

@@ -143,8 +143,8 @@ def test_kernel_launch_sites_end_in_the_rows_they_are_handed(path):
         kernel = eqn.params.get("name")
         if kernel == "histogram_flat":          # bins (R, F)
             rows = eqn.invars[0].aval.shape[0]
-        elif kernel == "fused_wave_call":       # gathered bins (W, S, F)
-            rows = int(np.prod(eqn.invars[0].aval.shape[:2]))
+        elif kernel == "fused_wave_call":       # packed wave rows (T, F)
+            rows = eqn.invars[0].aval.shape[0]
         else:
             continue
         launches.append(kernel)
@@ -154,6 +154,39 @@ def test_kernel_launch_sites_end_in_the_rows_they_are_handed(path):
     assert "histogram_flat" in launches          # the root pass
     assert ("fused_wave_call" in launches) is (path == "fused")
     assert len(launches) > 2                     # one launch per bucket
+
+
+def test_fused_wave_gather_is_handed_the_rows_the_wave_has():
+    """The ragged wave: nothing under ``grow/wave_gather`` is larger than
+    the most a wave can hold — the smaller siblings of disjoint leaves, at
+    most half the rows, each of the W slots rounded up to whole row blocks
+    — and the launches are the steps of ONE total-row ladder ending there.
+    (The old form gathered W x the wave's largest bucket: up to W * N.)"""
+    import lightgbm_tpu.models.grower as G
+    from lightgbm_tpu.ops.pallas_wave import wave_layout
+
+    fn, args = _program("fused")
+    w = PARAMS["tpu_leaf_batch"]
+    blk = wave_layout(F, 256, "f32")["rows_block"]
+    cap = (N // (2 * blk) + w) * blk
+    # the pin can tell them apart: the old form's SMALLEST launch
+    # (W x the smallest bucket) is more than the ragged form's largest
+    assert cap < w * G._MIN_BUCKET
+    handed, largest = [], 0
+    for eqn, scope in _walk(jax.make_jaxpr(fn)(*args).jaxpr):
+        if "grow/wave_gather" not in scope:
+            continue
+        if eqn.params.get("name") == "fused_wave_call":
+            handed.append(eqn.invars[0].aval.shape[0])
+            assert eqn.invars[0].aval.ndim == 2     # (T, F), never (W, S, F)
+            continue        # its (W, 2, C_PAD, F * b_pad) output has no rows
+        # everything else here is indexed by packed rows: no dimension of
+        # it may pass the cap
+        largest = max([largest] + [d for v in eqn.outvars
+                                   for d in getattr(v.aval, "shape", ())])
+    assert handed == G._wave_row_ladder(w * blk, cap, blk)
+    assert handed[-1] == cap and all(t % blk == 0 for t in handed)
+    assert largest == cap, (largest, cap)
 
 
 class _NoScope(contextlib.ContextDecorator):

@@ -170,6 +170,188 @@ def test_fused_bitwise_onehot_categorical():
         :int(t0.num_leaves) - 1])), "no categorical split won — dead pin"
 
 
+def _ragged_case(dtype, packed4):
+    """Uneven segments through ``fused_wave_call`` itself: returns what the
+    kernel gave and, per slot, what the per-leaf kernel + the unfused scan
+    give on the same rows."""
+    from lightgbm_tpu.ops.histogram import pack_bins4
+    from lightgbm_tpu.ops.pallas_common import C_PAD
+    from lightgbm_tpu.ops.pallas_histogram import histogram_flat
+    from lightgbm_tpu.ops.pallas_wave import (
+        STAT_LANES, fused_wave_call, hist_from_flat, hist_to_flat,
+        payload_to_best, plane_order, wave_block_map, wave_block_slots,
+        wave_layout, wave_meta)
+    from lightgbm_tpu.ops.split import SplitConfig, best_split
+
+    f, nbins = (9, 16) if packed4 else (6, 64)
+    lay = wave_layout(f, nbins, dtype, packed4=packed4)
+    blk = lay["rows_block"]
+    # one slot with almost all rows, a slot of 1 row, an exact multiple of
+    # blk, an inactive (empty) slot, a slot one row over a block
+    cnts = np.array([5 * blk + 37, 1, 2 * blk, 0, blk + 1], np.int32)
+    active = cnts > 0
+    w = len(cnts)
+    rng = np.random.RandomState(17)
+    n = int(cnts.sum()) * 2 + 50
+    bins = rng.randint(0, nbins, (n, f)).astype(np.uint8)
+    if dtype == "int8":
+        vals = np.stack([rng.randint(-100, 100, n), rng.randint(1, 100, n),
+                         np.ones(n)], 1).astype(np.int8)
+        scale = np.array([0.013, 0.007, 1.0], np.float32)
+    else:
+        vals = np.stack([rng.randn(n), rng.rand(n) + 0.1, np.ones(n)],
+                        1).astype(np.float32)
+        scale = None
+    sbins = np.asarray(pack_bins4(jnp.asarray(bins))) if packed4 else bins
+    hist_kw = dict(num_bins=nbins, dtype=dtype, packed4=packed4, features=f,
+                   interpret=True)
+
+    # disjoint leaves: slot j's parent is its rows plus as many again
+    order = rng.permutation(n)
+    small_rows, parent_rows, at = [], [], 0
+    for c in cnts:
+        small_rows.append(order[at:at + c])
+        parent_rows.append(order[at:at + 2 * c + 3])
+        at += 2 * c + 3
+    parent = jnp.stack([histogram_flat(jnp.asarray(sbins[r]),
+                                       jnp.asarray(vals[r]), **hist_kw)
+                        for r in parent_rows])            # (W, F, B, 3)
+    small_left = np.array([True, False, True, True, False])
+
+    # the packing under test, from the pure block map
+    _, off, nb_total = wave_block_map(jnp.asarray(cnts), blk)
+    total = 4 * int(nb_total) * blk      # nb_total well under the step
+    slot, k = (np.asarray(a) for a in wave_block_slots(off, total // blk))
+    seg = np.full((total // blk, blk), n, np.int64)       # phantom row n
+    for b in range(int(nb_total)):
+        rows = small_rows[slot[b]][k[b] * blk:(k[b] + 1) * blk]
+        seg[b, :len(rows)] = rows
+    seg = seg.reshape(-1)
+    bins_pad = np.concatenate([sbins, np.zeros((1, sbins.shape[1]),
+                                               np.uint8)])
+    vals_pad = np.concatenate([vals, np.zeros((1, 3), vals.dtype)])
+    gvT = np.pad(vals_pad[seg], ((0, 0), (0, C_PAD - 3))).T
+
+    scfg = SplitConfig(min_data_in_leaf=1, has_nan=False,
+                       has_categorical=False, use_sorted_categorical=False,
+                       has_monotone=False)
+    meta_kw = dict(num_bins_per_feature=jnp.full(f, nbins, jnp.int32),
+                   nan_bins=jnp.full(f, nbins, jnp.int32),
+                   is_categorical=jnp.zeros(f, bool),
+                   feature_mask=jnp.ones(f, bool))
+
+    def scaled(h):
+        return h if scale is None else h.astype(jnp.float32) * scale
+
+    # per-leaf: the flat kernel on the slot's rows, XLA subtract, scan
+    want = []
+    for j in range(w):
+        sm = (histogram_flat(jnp.asarray(sbins[small_rows[j]]),
+                             jnp.asarray(vals[small_rows[j]]), **hist_kw)
+              if cnts[j] else jnp.zeros_like(parent[j]))
+        big = parent[j] - sm
+        lr = (sm, big) if small_left[j] else (big, sm)
+        stats_j, bests = [], []
+        for h in lr:
+            g, hs, c = (jnp.asarray(v, jnp.float32)
+                        for v in np.asarray(scaled(h))[0].sum(axis=0))
+            stats_j.append((g, hs, c))
+            bests.append(best_split(scaled(h), g, hs, c, monotone=None,
+                                    cfg=scfg, parent_output=jnp.float32(0),
+                                    **meta_kw))
+        want.append((lr, stats_j, bests))
+
+    stats = np.zeros((w, 2, STAT_LANES), np.float32)
+    for j in range(w):
+        for ci in range(2):
+            stats[j, ci, :3] = [float(v) for v in want[j][1][ci]]
+        stats[j, :, 4] = small_left[j]
+        stats[j, :, 5] = active[j]
+    order_p, inv_p = plane_order(f, packed4)
+    hist2, payload = fused_wave_call(
+        jnp.asarray(bins_pad[seg]), jnp.asarray(gvT),
+        hist_to_flat(parent, lay["ftile"], lay["b_pad"], order_p),
+        jnp.asarray(stats),
+        wave_meta(meta_kw["num_bins_per_feature"], meta_kw["nan_bins"],
+                  meta_kw["is_categorical"], meta_kw["feature_mask"],
+                  features=f, num_bins=nbins, packed4=packed4),
+        jnp.asarray(slot), jnp.asarray(nb_total)[None],
+        None if scale is None else jnp.asarray(np.pad(scale, (0, 1))[None]),
+        num_bins=nbins, features=f, rows_block=0, dtype=dtype,
+        packed4=packed4, scfg=scfg, interpret=True)
+    child = hist_from_flat(hist2, f, nbins, lay["b_pad"], inv_p)
+    got = payload_to_best(jnp.concatenate([payload[:, 0], payload[:, 1]]))
+    return child, got, want, active
+
+
+@pytest.mark.parametrize("dtype, packed4", [("f32", False), ("int8", False),
+                                            ("f32", True)],
+                         ids=["f32", "int8", "packed4"])
+def test_ragged_kernel_matches_per_leaf(dtype, packed4):
+    """The ragged launch against the per-leaf path, slot by slot: (left,
+    right) histograms bitwise those of ``histogram_flat`` on the slot's
+    rows -/+ the parent (same row blocks from the segment's start, so the
+    f32 sums group identically), the payload the unfused ``best_split``'s
+    — with one slot holding almost all rows, a slot of one row, an exact
+    multiple of the row block, an inactive slot, and ``nb_total`` a
+    quarter of the launch's blocks."""
+    child, got, want, active = _ragged_case(dtype, packed4)
+    w = len(want)
+    for j, (lr, _stats, bests) in enumerate(want):
+        for ci in range(2):
+            np.testing.assert_array_equal(
+                np.asarray(child[j, ci]), np.asarray(lr[ci]),
+                err_msg=f"slot {j} child {ci}")
+            i, ref = ci * w + j, bests[ci]
+            if not active[j]:
+                assert float(got.gain[i]) == -np.inf
+                continue
+            assert float(got.gain[i]) == float(ref.gain), (j, ci)
+            assert int(got.feature[i]) == int(ref.feature)
+            assert int(got.bin[i]) == int(ref.bin)
+            assert bool(got.default_left[i]) == bool(ref.default_left)
+            for name in ("sum_grad_left", "sum_hess_left", "count_left",
+                         "sum_grad_right", "sum_hess_right", "count_right"):
+                assert (float(getattr(got, name)[i])
+                        == float(getattr(ref, name))), (j, ci, name)
+    assert any(float(got.gain[i]) > 0 for i in range(2 * w))
+
+
+@pytest.mark.parametrize("cnts, blk", [
+    ([1500, 1, 512, 0, 257], 256),          # uneven, empty, exact multiple
+    ([0, 0, 0, 0], 128),                    # nothing to do: W blocks
+    ([4096], 1024),                         # W = 1
+    ([300] * 16, 256),                      # equal segments
+], ids=["uneven", "empty", "w1", "equal"])
+def test_wave_block_map_packs_every_row_once(cnts, blk):
+    """The block map alone: a slot's blocks are consecutive, every real
+    row of every slot appears exactly once, ``nb_total * blk <= sum + W *
+    blk``, and blocks past ``nb_total`` mask to nothing."""
+    from lightgbm_tpu.ops.pallas_wave import (wave_block_map,
+                                              wave_block_slots)
+
+    cnts = np.asarray(cnts, np.int32)
+    w = len(cnts)
+    nb, off, nb_total = (np.asarray(a) for a in
+                         wave_block_map(jnp.asarray(cnts), blk))
+    assert np.all(nb >= 1) and nb_total == nb.sum()
+    assert nb_total * blk <= cnts.sum() + w * blk
+    nblocks = int(nb_total) + 5
+    slot, k = (np.asarray(a) for a in wave_block_slots(jnp.asarray(off),
+                                                       nblocks))
+    real = slot[:nb_total]
+    assert np.all(np.diff(real) >= 0)                 # consecutive slots
+    assert np.array_equal(np.unique(real), np.arange(w))   # all visited
+    assert np.all(slot[nb_total:] == w - 1)           # index maps stay put
+    seen = [np.zeros(c, np.int32) for c in cnts]
+    for b in range(nblocks):
+        rows = k[b] * blk + np.arange(blk)
+        rows = rows[rows < cnts[slot[b]]]             # the gather's mask
+        assert b < nb_total or rows.size == 0
+        seen[slot[b]][rows] += 1
+    assert all(np.all(s == 1) for s in seen)
+
+
 def test_small_n_reports_fused_inactive():
     """n <= _MIN_BUCKET routes to the mask layout (no wave at all):
     wave_fused_active — and everything the census/bench derive from it —

@@ -50,8 +50,9 @@ _DESCRIPTIONS = {
         "VMEM-resident (vs one histogram dispatch per leaf plus two more "
         "HBM passes unfused); quantized trees are bitwise-identical "
         "either way, fp32 trees are identical whenever histogram sums "
-        "are exactly representable (otherwise ULP-level — the wave's "
-        "shared row bucket may regroup f32 partial sums, the histogram "
+        "are exactly representable (otherwise ULP-level — the per-leaf "
+        "path may pick another row block at a small bucket and regroup "
+        "f32 partial sums, the histogram "
         "pool's recompute caveat; tests/test_wave_fused.py, "
         "docs/PERF.md round 9).  auto = fused only where the "
         "capability checks pass (no mesh/voting/EFB/monotone/"

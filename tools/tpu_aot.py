@@ -85,10 +85,12 @@ def higgs_kernel_cases(sharding):
         lay = wave_layout(f, b, dtype)
         fb = lay["ftile"] * lay["b_pad"]
         acc = jnp.int32 if dtype == "int8" else jnp.float32
-        args = [sds((w, n, lay["cols_tile"]), jnp.uint8),
-                sds((w, C_PAD, n), vd), sds((w, C_PAD, fb), acc),
+        args = [sds((n, lay["cols_tile"]), jnp.uint8),
+                sds((C_PAD, n), vd), sds((w, C_PAD, fb), acc),
                 sds((w, 2, STAT_LANES), jnp.float32),
-                sds((lay["ftile"], 8), jnp.int32)]
+                sds((lay["ftile"], 8), jnp.int32),
+                sds((n // lay["rows_block"],), jnp.int32),
+                sds((1,), jnp.int32)]
         if dtype == "int8":
             args.append(sds((1, 4), jnp.float32))
         cases.append((
