@@ -2188,10 +2188,11 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         W per-leaf ``histogram_flat`` calls at each leaf's own bucket, an
         XLA subtract and one vmapped scan; fused (``_fused_wave``) it is
         ONE ragged kernel launch over the W segments packed back to back.
-        Either way the cost is the rows handed over — the kernels run at
-        some 21 M rows/s on a v5e, 47 ns a row, nowhere near the HBM
-        stream's rate (PERF_LEDGER.jsonl, PR 25; PERF.md section 5) — so
-        no path pads a wave beyond the rows it holds.  Sequential depth
+        Either way the cost is the rows handed over — the kernels take
+        6 ns a row at 28 columns and 25 at 137 on a v5e, 0.18-0.21 ns a
+        row-column, instruction-bound and nowhere near the HBM stream's
+        rate (my chip runs, PR 28; PERF.md section 5) — so no path pads a
+        wave beyond the rows it holds.  Sequential depth
         per tree drops from num_leaves-1 steps to
         ~ceil((num_leaves-1)/W)."""
         n, gcols = bins.shape

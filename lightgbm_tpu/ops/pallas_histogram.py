@@ -4,28 +4,34 @@ Reference counterpart: the CUDA shared-memory histogram kernels
 (``src/treelearner/cuda/cuda_histogram_constructor.cu:31-66`` — per-block
 shared-mem scatter-add + atomics).  TPUs have no atomics and scatters
 serialize, so the kernel computes the histogram as a **matmul against a
-flattened one-hot**, generated inside VMEM:
+flattened one-hot**, generated inside the kernel:
 
     out[c, f*B + b] = sum_n  vals[n, c] * (bins[n, f] == b)
 
 Why this shape wins on the MXU:
 
-- The one-hot (the big streamed operand) never touches HBM: it is built in
-  VMEM from the (blk, ft) uint8 bin tile, so HBM traffic is just bins + vals.
+- The one-hot (the big operand) never touches HBM — nor VMEM: it is built
+  from the (blk, ft) uint8 bin tile one vector register at a time and goes
+  straight into the MXU's weight latch (``pallas_common.onehot_contract``),
+  so HBM traffic is just bins + vals and Mosaic's scoped VMEM is the
+  kernel's blocks.
 - A whole feature CHUNK shares ONE dot per row-block (N = ft*B lanes),
   instead of per-feature M=8 matmuls — fewer, larger matmuls with identical
   streamed volume.  The grid iterates row-blocks only; very wide datasets
-  are chunked at trace time into separate same-shaped calls so the VMEM
-  one-hot stays bounded (and every BlockSpec dim is Mosaic-legal: the
-  feature dim always equals the array dim, row blocks are 128-multiples).
-- The kernel is NOT HBM-bandwidth-bound: on a v5e it runs at 21 M rows/s,
-  47 ns a row at 28 features x 255 bins f32 (PERF_LEDGER.jsonl, PR 25;
-  0.013 % of its byte roofline); what binds instead is not measured (the
-  candidates: the one-hot build on the VPU, the six-pass f32 matmul at
-  M = 4).  Its time is the rows it is handed, so the streamed volume
-  stays proportional to the rows actually histogrammed: the reference's
-  smaller-sibling trick (``serial_tree_learner.cpp:369``), one call per
-  smaller sibling at its own bucket, or the fused wave's one ragged launch.
+  are chunked at trace time into separate same-shaped, BALANCED calls so
+  one step's unrolled program stays bounded (and every BlockSpec dim is
+  Mosaic-legal: the feature dim always equals the array dim, row blocks
+  are 128-multiples).
+- The kernel is NOT HBM-bandwidth-bound, it is INSTRUCTION-bound: its time
+  is 0.18-0.23 ns per LLO line of one grid step (Mosaic's
+  ``post-finalize-llo`` dump; PERF.md sections 6 and 7).  On a v5e: 5.94 ns
+  a row a launch at 28 features x 255 bins f32 and 25.5 at 137, 0.19-0.21
+  ns a row-column (my chip run, PR 28).  What binds is one compare + one
+  select + one weight push per register of one-hot.  Its time is the rows
+  it is handed, so the streamed volume stays proportional to the rows
+  actually histogrammed: the reference's smaller-sibling trick
+  (``serial_tree_learner.cpp:369``), one call per smaller sibling at its
+  own bucket, or the fused wave's one ragged launch.
 - int8 variant: s8 vals x s8 one-hot -> s32 accumulation — the reference's
   quantized-training histograms (``Int32HistogramSumReducer``, ``bin.h:48``)
   on the MXU's double-rate int8 path.
@@ -48,26 +54,54 @@ from .pallas_common import (C_PAD, DTYPES as _DTYPES, compiler_params,
                             onehot_contract)
 
 
-def _pick_tiles(f: int, num_bins: int, itemsize: int, rows_block: int,
-                acc_size: int = 4):
-    """(rows_block, features_per_chunk) bounding the kernel's VMEM working
-    set (the in-VMEM one-hot PLUS the (C_PAD, ft*B) accumulator block).
+# One grid step unrolls one compare -> select -> latch per vector register
+# of one-hot, so the COLUMNS one launch takes are bounded by the step's
+# program, not by VMEM (the one-hot is never stored; what Mosaic allocates
+# is the accumulator block, 548 KB at 137 columns x 256 bins).  8 Mi
+# elements are 8 192 registers, some 34 K LLO lines; the largest step
+# measured, 256 rows x 137 columns x 256 bins (9.0 M), took 0.9 s of Mosaic
+# compile and 0.180 ns a row-column on a v5e against 0.212 at 28 columns
+# (my chip run, PR 28) — wider steps are a little FASTER a cell, so the
+# bound is there for the compile's sake.
+_STEP_ELEMS = 8 * 1024 * 1024
+
+
+# The row-block rule's budget and formula: the VMEM model of a one-hot held
+# in VMEM twice over, which the kernel no longer holds
+# (``pallas_common.onehot_contract``).  Kept as the DEFINITION of the row
+# block (``_pick_tiles``) and of the widths the fused wave admits
+# (``pallas_wave.wave_layout``), so that neither moved with the kernel.
+BLOCK_BUDGET = 16 * 1024 * 1024
+
+
+def block_model_bytes(blk: int, ft: int, num_bins: int, itemsize: int,
+                      acc_size: int = 4) -> int:
+    return ft * num_bins * (blk * 2 * itemsize + C_PAD * acc_size)
+
+
+def _pick_tiles(f: int, num_bins: int, itemsize: int, rows_block: int):
+    """(rows_block, features_per_chunk) of one ``histogram_flat`` launch.
     ``num_bins`` here is the LANE-PADDED bin count (multiple of 128).
 
     Mosaic requires each BlockSpec's last dim to be a multiple of 128 or
     equal to the full array dim, so the kernel never tiles features inside
     one ``pallas_call``: the bins block spans the WHOLE (chunk) feature
     width, and wide datasets are chunked at trace time into separate
-    same-shaped calls.  Row blocks stay multiples of 128 (the sublane-
-    aligned choice for every dtype used here).
+    same-shaped calls.  Row blocks stay multiples of 128 (the rows are the
+    one-hot's lanes).
 
-    The 2x on the one-hot bytes models Mosaic's observed scoped-stack peak
-    (the (blk, ft, B) compare plus its (blk, ft*B) reshape copy coexist)."""
-    budget = 16 * 1024 * 1024
+    ROWS: the largest power of two from 1024 down to 128 at which
+    ``block_model_bytes`` stays under ``BLOCK_BUDGET`` — the block every
+    shape has run since PR 21.  It stays because the fused wave pads every
+    slot to whole blocks (256 at Higgs' 28 columns: ``hist_rows_useful``
+    85.9 %) and because a larger block buys little (28 columns on a v5e:
+    6.72 ns a row a launch at 128, 5.94 at 256, 5.64 at 512, 5.39 at 1024;
+    my chip run, PR 28).
 
-    def bytes_for(blk, ft):
-        return ft * num_bins * (blk * 2 * itemsize + C_PAD * acc_size)
-
+    COLUMNS: at most ``_STEP_ELEMS`` one-hot elements a grid step, in
+    BALANCED chunks — ``nchunks = ceil(f / ft_max)``, ``ft = ceil(f /
+    nchunks)`` — so a launch sequence hands at most ``f + nchunks - 1``
+    columns: 137 features are one launch of 137, never 3 x 63 = 189."""
     # rows_block > 4096 means "tuned for the XLA einsum path" — auto-pick.
     # Powers of two >= 128 keep every halving on the 128-multiple lattice
     # Mosaic requires for the valsT block's last dim.
@@ -75,14 +109,12 @@ def _pick_tiles(f: int, num_bins: int, itemsize: int, rows_block: int,
         blk = 1024
     else:
         blk = max(128, 1 << (int(rows_block).bit_length() - 1))
-    while blk > 128 and bytes_for(blk, f) > budget:
+    while (blk > 128
+           and block_model_bytes(blk, f, num_bins, itemsize) > BLOCK_BUDGET):
         blk //= 2
-    if bytes_for(blk, f) <= budget:
-        return blk, f
-    # Very wide data: fix the minimum row block and chunk the features.
-    ft = max(1, budget // (num_bins * (blk * 2 * itemsize
-                                       + C_PAD * acc_size)))
-    return blk, ft
+    ft_max = max(1, _STEP_ELEMS // (blk * num_bins))
+    nchunks = -(-f // ft_max)
+    return blk, -(-f // nchunks)
 
 
 def kernel_layout(f: int, num_bins: int, dtype: str, rows_block: int = 0,
@@ -90,10 +122,10 @@ def kernel_layout(f: int, num_bins: int, dtype: str, rows_block: int = 0,
     """(rows_block, ftile, cols_tile, b_pad) for one ``histogram_flat``
     config.  Every Mosaic legality constraint lives here so it is testable
     without hardware: the bin axis is padded to a 128-multiple (bin ids are
-    < num_bins, so phantom bins stay exactly zero), which keeps the
-    kernel's one-hot flatten — and, under packed4, each nibble plane's
-    contiguous output half — lane-aligned."""
-    isz = _DTYPES[dtype][2]
+    < num_bins, so phantom bins stay exactly zero), which keeps each
+    column's slab of the flat histogram — and, under packed4, each nibble
+    plane's contiguous output half — lane-aligned."""
+    isz = _DTYPES[dtype][1]
     b_pad = -(-num_bins // 128) * 128
     rows_block, ftile = _pick_tiles(f, b_pad, isz, rows_block)
     if packed4 and ftile % 2:
@@ -122,13 +154,12 @@ def _prep(bins, vals, rows_block, ftile):
     return bins, valsT, ntot // rows_block, (f + fpad) // ftile
 
 
-def _flat_kernel(bins_ref, valsT_ref, out_ref, *, num_bins, ftile,
-                 oh_dtype, acc_dtype, precision, packed4=False):
-    """``num_bins`` is the lane-padded bin count (multiple of 128): Mosaic
-    only supports the (blk, ft, B) -> (blk, ft*B) one-hot flatten when the
-    merged minor dim stays 128-aligned.  Real bin ids never reach the
-    phantom bins, so their histogram lanes are exact zeros and the caller
-    slices them off."""
+def _flat_kernel(bins_ref, valsT_ref, out_ref, *, num_bins, ftile, dtype,
+                 packed4=False):
+    """``num_bins`` is the lane-padded bin count (multiple of 128): each
+    column's slab of the flat histogram starts on a lane-tile boundary.
+    Real bin ids never reach the phantom bins, so their histogram lanes
+    are exact zeros and the caller slices them off."""
     rb = pl.program_id(0)  # row-block index
 
     @pl.when(rb == 0)
@@ -137,13 +168,9 @@ def _flat_kernel(bins_ref, valsT_ref, out_ref, *, num_bins, ftile,
 
     bins_blk = bins_ref[:].astype(jnp.int32)            # (blk, ct)
     valsT = valsT_ref[:]                                # (C_PAD, blk)
-    if oh_dtype != valsT.dtype:
-        valsT = valsT.astype(oh_dtype)
 
     def contract(b2d):
-        return onehot_contract(b2d, valsT, num_bins=num_bins,
-                               oh_dtype=oh_dtype, acc_dtype=acc_dtype,
-                               precision=precision)
+        return onehot_contract(b2d, valsT, num_bins=num_bins, dtype=dtype)
 
     if packed4:
         # 4-bit mode: the streamed tile carries two features per byte
@@ -168,7 +195,7 @@ def histogram_flat(
     *,
     num_bins: int,
     rows_block: int = 0,
-    dtype: str = "f32",  # one-hot/compute dtype: f32 | bf16 | int8
+    dtype: str = "f32",  # compute dtype: f32 | bf16 | int8
     interpret: bool = False,
     packed4: bool = False,   # two 4-bit features per streamed byte
     features: int = 0,       # real F when packed4
@@ -176,18 +203,13 @@ def histogram_flat(
     """Single-leaf flat-matmul histogram."""
     n, fcols = bins.shape
     f = features if packed4 else fcols
-    oh_dtype, acc_dtype, isz = _DTYPES[dtype]
-    # f32 must accumulate exactly (reference hists are exact f32 sums);
-    # DEFAULT would run the MXU at bf16 and perturb every histogram entry.
-    precision = (jax.lax.Precision.HIGHEST if dtype == "f32"
-                 else jax.lax.Precision.DEFAULT)
+    acc_dtype = _DTYPES[dtype][0]
     rows_block, ftile, cols_tile, b_pad = kernel_layout(
         f, num_bins, dtype, rows_block, packed4)
     bins, valsT, nblocks, nchunks = _prep(bins, vals, rows_block, cols_tile)
     call = pl.pallas_call(
         functools.partial(_flat_kernel, num_bins=b_pad, ftile=ftile,
-                          oh_dtype=oh_dtype, acc_dtype=acc_dtype,
-                          precision=precision, packed4=packed4),
+                          dtype=dtype, packed4=packed4),
         grid=(nblocks,),
         in_specs=[
             pl.BlockSpec((rows_block, cols_tile), lambda i: (i, 0),

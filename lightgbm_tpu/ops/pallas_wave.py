@@ -39,11 +39,13 @@ HBM traffic per wave drops to one bins+vals stream plus the O(W * G * B)
 child-histogram writeback the pool retains and a tiny (W, 2, 16+B)
 SplitInfo payload — the full (L, G, B, 3) tensor never round-trips between
 build and scan (pinned structurally in tests/test_hlo_cost.py).  The
-kernel is NOT bandwidth-bound: on a v5e it takes 47 ns a row, 21 M rows/s
-at 28 features x 255 bins f32 (PERF_LEDGER.jsonl, PR 25: 2.09 s for
-44.3 M rows; 0.013 % of its byte roofline; what binds instead is not
-measured) — so its time is the rows it is handed, and the ragged packing
-is what keeps that at the rows the tree needs.
+kernel is NOT bandwidth-bound but instruction-bound, like
+``histogram_flat`` (``pallas_histogram``'s docstring; same contraction):
+on a v5e the 19 fused launches and the root's flat one together take
+0.0369 s a tree for 6.16 M real rows at 28 features x 255 bins f32, 6.0 ns
+a row, in-kernel scans included (my chip run, PR 28; 47 ns before it)
+— so its time is the rows it is handed, and the ragged packing is what
+keeps that at the rows the tree needs.
 
 Quantized training rides the int8/int32 accumulation path (``DTYPES``),
 subtraction stays exact integer arithmetic, and the per-iteration scales
@@ -65,7 +67,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_common import (C_PAD, DTYPES, compiler_params,
                             onehot_contract)
-from .pallas_histogram import kernel_layout
+from .pallas_histogram import (BLOCK_BUDGET, block_model_bytes,
+                               kernel_layout)
 from .split import BestSplit, SplitConfig, scan_tables, select_payload
 
 # Scalar lanes ahead of the cat_mask in the per-child SplitInfo payload:
@@ -75,13 +78,23 @@ PAYLOAD_SCALARS = 16
 # Per-child scalar-input lanes: [pg, ph, pc, parent_out, small_left, active].
 STAT_LANES = 8
 
-# The fused working set holds the one-hot block PLUS three (C_PAD, F*B)
-# histograms (small accumulator, its sibling slot, the parent) PLUS the
-# scan's (F, B) gain/stat tables — budgeted below VMEM_LIMIT with the same
-# 2x one-hot headroom model as ``_pick_tiles``.  v5e carries 128 MB VMEM;
-# the histogram kernel's own 16 MB budget stays untouched so fused and
-# unfused share identical row blocking (bitwise-identical accumulation).
+# The fused working set is three (C_PAD, F*B) histograms (small
+# accumulator, its sibling slot, the parent) PLUS the scan's (F, B)
+# gain/stat tables PLUS the streamed blocks.  The one-hot is not in it: the
+# contraction feeds it from vector registers (``onehot_contract``).  v5e
+# carries 128 MB VMEM; row blocking is ``kernel_layout``'s, so fused and
+# unfused accumulate in identical blocks (bitwise-identical sums).
 WAVE_VMEM_BUDGET = 48 * 1024 * 1024
+
+# The widths the fused kernel ADMITS are the widths at which
+# ``pallas_histogram``'s row-block rule holds at the smallest row block: 63
+# columns at 255 bins f32, 126 at <= 128 bins, 240 for int8.  The bytes
+# below would admit 137 columns x 256 bins (6 MB), which would move every
+# such model — the benchmark's ``msltr.train`` among them, whose ``why``
+# says "unfused wave" — onto another program without one chip run behind
+# it.  A shape this code observes, not a parameter; lifting it is an issue
+# of its own (PERF.md section 7).
+_ADMIT_BLOCK = 128
 
 # (F, B)-shaped f32 buffers the scan materializes at peak (cum sums, three
 # stats directions x 6, gain/mask tables) — a deliberate over-count.
@@ -118,26 +131,26 @@ def wave_layout(features: int, num_bins: int, dtype: str,
     - ``single_chunk``: the kernel scans the whole feature space in one
       block — trace-time feature chunking (very wide F) cannot fuse, those
       shapes keep the unfused path (plus the pool + tiled scan that
-      already serve them);
-    - ``fits``: single-chunk AND the modeled working set (2x one-hot +
-      3 resident histograms + scan scratch + streamed blocks) stays under
-      ``WAVE_VMEM_BUDGET``."""
+      already serve them) — AND the width is one the row-block rule
+      holds at (``_ADMIT_BLOCK``);
+    - ``fits``: single-chunk AND the modeled working set (3 resident
+      histograms + scan scratch + streamed blocks; the one-hot is never
+      stored) stays under ``WAVE_VMEM_BUDGET``."""
     blk, ftile, cols_tile, b_pad = kernel_layout(
         features, num_bins, dtype, rows_block, packed4)
-    isz = DTYPES[dtype][2]
+    isz = DTYPES[dtype][1]
     fb = ftile * b_pad
     needed_cols = -(-features // 2) if packed4 else features
-    single_chunk = cols_tile >= needed_cols
-    onehot_bytes = 2 * blk * fb * isz
+    single_chunk = (cols_tile >= needed_cols
+                    and block_model_bytes(_ADMIT_BLOCK, features, b_pad, isz)
+                    <= BLOCK_BUDGET)
     hist_block_bytes = 3 * C_PAD * fb * 4
     scan_scratch_bytes = _SCAN_BUFS * fb * 4
-    stream_bytes = blk * cols_tile + C_PAD * blk * isz
-    total = (onehot_bytes + hist_block_bytes + scan_scratch_bytes
-             + stream_bytes)
+    stream_bytes = 2 * (blk * cols_tile + C_PAD * blk * isz)
+    total = hist_block_bytes + scan_scratch_bytes + stream_bytes
     return {
         "rows_block": blk, "ftile": ftile, "cols_tile": cols_tile,
         "b_pad": b_pad, "payload_width": PAYLOAD_SCALARS + num_bins,
-        "onehot_bytes": onehot_bytes,
         "hist_block_bytes": hist_block_bytes,
         "scan_scratch_bytes": scan_scratch_bytes,
         "stream_bytes": stream_bytes, "total_bytes": total,
@@ -264,8 +277,8 @@ def wave_block_slots(off: jnp.ndarray, nblocks: int):
     return slot, b - off[slot]
 
 
-def _wave_kernel(*refs, ftile, b_pad, key_bins, oh_dtype, acc_dtype,
-                 precision, packed4, scfg, has_scale):
+def _wave_kernel(*refs, ftile, b_pad, key_bins, dtype, packed4, scfg,
+                 has_scale):
     """Kernel body at row block ``b`` of the packed wave: accumulate the
     block into its slot's smaller-sibling histogram (zeroed at the slot's
     first block), and at the slot's last block subtract the parent,
@@ -296,13 +309,9 @@ def _wave_kernel(*refs, ftile, b_pad, key_bins, oh_dtype, acc_dtype,
     def _accumulate():
         bins_blk = bins_ref[:].astype(jnp.int32)         # (blk, ct)
         valsT = valsT_ref[:]                             # (C_PAD, blk)
-        if oh_dtype != valsT.dtype:
-            valsT = valsT.astype(oh_dtype)
 
         def contract(b2d):
-            return onehot_contract(b2d, valsT, num_bins=b_pad,
-                                   oh_dtype=oh_dtype, acc_dtype=acc_dtype,
-                                   precision=precision)
+            return onehot_contract(b2d, valsT, num_bins=b_pad, dtype=dtype)
 
         if packed4:
             # Two 4-bit features per streamed byte (reference DenseBin
@@ -405,7 +414,7 @@ def fused_wave_call(
     SplitInfo block.  ``T`` is a multiple of the layout's row block."""
     t, ct = gbins.shape
     w = parent_flat.shape[0]
-    oh_dtype, acc_dtype, _ = DTYPES[dtype]
+    acc_dtype = DTYPES[dtype][0]
     blk, ftile, cols_tile, b_pad = kernel_layout(
         features, num_bins, dtype, rows_block, packed4)
     if ct != cols_tile or parent_flat.shape[-1] != ftile * b_pad:
@@ -417,15 +426,12 @@ def fused_wave_call(
         raise ValueError(
             f"fused wave needs whole row blocks and one slot per block: "
             f"got {t} rows, {blk_slot.shape} slots, row block {blk}")
-    precision = (jax.lax.Precision.HIGHEST if dtype == "f32"
-                 else jax.lax.Precision.DEFAULT)
     fb = ftile * b_pad
     pay_w = PAYLOAD_SCALARS + num_bins
     has_scale = scale3 is not None
     kern = functools.partial(
         _wave_kernel, ftile=ftile, b_pad=b_pad, key_bins=num_bins,
-        oh_dtype=oh_dtype, acc_dtype=acc_dtype, precision=precision,
-        packed4=packed4, scfg=scfg, has_scale=has_scale)
+        dtype=dtype, packed4=packed4, scfg=scfg, has_scale=has_scale)
 
     # Padding blocks re-name the last real block, so no DMA is issued
     # for them; everything per-slot follows the block's slot.

@@ -229,11 +229,88 @@ def test_flat_histogram_bench_bin_count(rng):
     np.testing.assert_allclose(np.asarray(got), ref, rtol=1e-4, atol=1e-4)
 
 
+def test_bf16x3_split_is_exact(rng):
+    """The one-pass f32 contraction rests on this: a float32 is the SUM of
+    its three bfloat16 parts, bit for bit — 1e5 normal values over 30
+    decades, both signs (reassembled in float32: each partial sum is a
+    float32, so nothing is rounded on the way back)."""
+    from lightgbm_tpu.ops.pallas_common import split_bf16x3
+
+    v = (rng.choice([-1.0, 1.0], 100_000) * (1.0 + rng.rand(100_000))
+         * 10.0 ** rng.uniform(-15, 15, 100_000)).astype(np.float32)
+    assert np.all(np.isfinite(v)) and np.all(np.abs(v) > 1e-30)
+    hi, mid, lo = split_bf16x3(jnp.asarray(v))
+    assert hi.dtype == mid.dtype == lo.dtype == jnp.bfloat16
+    back = (hi.astype(jnp.float32) + mid.astype(jnp.float32)
+            + lo.astype(jnp.float32))
+    np.testing.assert_array_equal(np.asarray(back).view(np.uint32),
+                                  v.view(np.uint32))
+
+
+def _bincount_ref(bins, vals, B):
+    """(F, B, C) float64 histogram of ``vals`` (exact for integer values)."""
+    f, c = bins.shape[1], vals.shape[1]
+    ref = np.zeros((f, B, c))
+    for j in range(f):
+        for k in range(c):
+            ref[j, :, k] = np.bincount(bins[:, j], minlength=B,
+                                       weights=vals[:, k].astype(np.float64))
+    return ref
+
+
+def _six_decade_case(rng, n, f, B=255):
+    bins = rng.randint(0, B, size=(n, f)).astype(np.uint8)
+    g = (rng.randn(n) * 10.0 ** rng.uniform(-3, 3, n)).astype(np.float32)
+    h = (rng.rand(n) * 10.0 ** rng.uniform(-3, 3, n)).astype(np.float32)
+    vals = np.stack([g, h, np.ones(n, np.float32)], axis=1)
+    return bins, vals, _bincount_ref(bins, vals, B)
+
+
+# what float32 summation of float32 values gives at 20 000 x 28 (the
+# six-pass HIGHEST contraction this kernel had until PR 28 read 1.25e-7)
+_F32_SUM_BOUND = 2e-7
+
+
+@pytest.mark.parametrize("dtype,within", [("f32", True), ("bf16", False)])
+def test_flat_histogram_f32_is_a_float32_sum(rng, dtype, within):
+    """``dtype="f32"`` is ONE bf16 pass of the MXU, and still a float32 sum
+    of float32 values: against a float64 bincount, on gradients spread over
+    six decades, it meets the float32-summation bound.  The SAME inputs
+    through ``dtype="bf16"`` (values rounded to 8 bits) miss it by three
+    orders — the case that keeps "one pass" from ever meaning that."""
+    from lightgbm_tpu.ops.pallas_histogram import histogram_flat
+
+    bins, vals, ref = _six_decade_case(rng, 20_000, 28)
+    got = np.asarray(histogram_flat(jnp.asarray(bins), jnp.asarray(vals),
+                                    num_bins=255, dtype=dtype,
+                                    interpret=True), np.float64)
+    err = np.linalg.norm(got - ref) / np.linalg.norm(ref)
+    assert (err <= _F32_SUM_BOUND) == within, err
+    if not within:
+        assert err > 100 * _F32_SUM_BOUND, err
+
+
+@pytest.mark.parametrize("f", [28, 63])
+def test_flat_histogram_int8_exact(rng, f):
+    """int8 x int8 -> int32 sums equal the integer reference entry for
+    entry, at the Higgs width and at a wide tile."""
+    from lightgbm_tpu.ops.pallas_histogram import histogram_flat
+
+    n, B = 3000, 255
+    bins = rng.randint(0, B, size=(n, f)).astype(np.uint8)
+    vals8 = rng.randint(-127, 128, size=(n, 3)).astype(np.int8)
+    ref = _bincount_ref(bins, vals8, B).astype(np.int64)
+    got = histogram_flat(jnp.asarray(bins), jnp.asarray(vals8), num_bins=B,
+                         dtype="int8", interpret=True)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got, np.int64), ref)
+
+
 def test_flat_histogram_layout_mosaic_alignment():
     """Hardware-independent guard for the max_bin=255 Mosaic regression:
     interpret-mode parity cannot see layout legality, so pin the
-    constraints structurally — the padded bin axis, the one-hot flatten
-    width, the packed4 half-width, and the row block must all be
+    constraints structurally — the padded bin axis, each launch's flat
+    histogram width, the packed4 half-width, and the row block must all be
     128-aligned for every bin count and dtype."""
     from lightgbm_tpu.ops.pallas_histogram import kernel_layout
 
@@ -249,3 +326,22 @@ def test_flat_histogram_layout_mosaic_alignment():
                 28, num_bins, dtype, packed4=True)
             assert ftile % 2 == 0 and ftile == 2 * cols_tile
             assert ((ftile // 2) * b_pad) % 128 == 0  # nibble-plane halves
+
+
+@pytest.mark.parametrize("f", [28, 63, 137, 700, 2000])
+def test_flat_histogram_column_chunks_are_balanced(f):
+    """The launches of one histogram hand at most ``f + nchunks - 1``
+    columns (balanced chunks), one step holds at most ``_STEP_ELEMS``
+    one-hot elements, and the benchmark's shapes keep their tiles: Higgs'
+    28 columns one launch at the row block of 256 the fused wave packs by,
+    MS-LTR's 137 ONE launch of 137 (it was 3 x 63 = 189 handed)."""
+    from lightgbm_tpu.ops.pallas_histogram import _STEP_ELEMS, kernel_layout
+
+    for dtype in ("f32", "bf16", "int8"):
+        for num_bins in (63, 255):
+            blk, ftile, _, b_pad = kernel_layout(f, num_bins, dtype)
+            nchunks = -(-f // ftile)
+            assert nchunks * ftile <= f + nchunks - 1
+            assert blk * ftile * b_pad <= _STEP_ELEMS
+    assert kernel_layout(28, 255, "f32")[:2] == (256, 28)
+    assert kernel_layout(137, 255, "f32")[:2] == (128, 137)

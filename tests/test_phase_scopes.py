@@ -154,10 +154,11 @@ def test_kernel_launch_sites_end_in_the_rows_they_are_handed(path):
     assert len(launches) > 2                     # one launch per bucket
 
 
-@pytest.mark.parametrize("features", [28, 137])
-def test_a_wide_histogram_says_the_columns_one_launch_holds(features):
-    """At 137 features one leaf's histogram is several kernel launches over
-    the same rows (the layout's column tile), all under ONE scope path:
+@pytest.mark.parametrize("features,expect", [(28, 1), (137, 1), (700, 3)])
+def test_a_wide_histogram_says_the_columns_one_launch_holds(features, expect):
+    """At 700 features one leaf's histogram is several kernel launches over
+    the same rows (the layout's column tile: 3 x 234; MS-LTR's 137 columns
+    are ONE launch since PR 28), all under ONE scope path:
     ``cols<C>`` is the columns ONE launch holds, so rows x cols summed over
     the launches covers the histogram's cells once, and ``rows<R>`` stays
     the last segment (``hist_rows_useful`` reads it there)."""
@@ -174,8 +175,9 @@ def test_a_wide_histogram_says_the_columns_one_launch_holds(features):
     cols_seg, rows_seg = scope.split("/")[-2:]
     assert rows_seg == f"rows{rows}" and cols_seg.startswith("cols")
     cols = int(cols_seg[4:])
-    assert launches == (1 if features == 28 else 3)
+    assert launches == expect
     assert cols * (launches - 1) < features <= cols * launches
+    assert cols * launches <= features + launches - 1     # balanced chunks
 
 
 def test_fused_wave_gather_is_handed_the_rows_the_wave_has():

@@ -1,6 +1,8 @@
 """The three Pallas kernels must lower — and compile — for a TPU from this
 CPU-only box, with ``interpret=False``, at the Higgs headline shape
-(28 features, ``max_bin=255``, 255 leaves, ``leaf_batch=16``).
+(28 features, ``max_bin=255``, 255 leaves, ``leaf_batch=16``), and the flat
+histogram kernel at the benchmark's wide tile (MS-LTR's 137 columns in one
+launch: the unfused cell's kernel).
 
 Interpret mode runs kernel bodies through plain XLA, so a primitive Pallas
 TPU cannot lower (``cumsum`` in the split scan, ``dynamic_slice`` on a
@@ -28,7 +30,7 @@ import tpu_aot  # noqa: E402
 def _cases():
     # a CPU sharding only carries shapes here: stage 1 never compiles
     sharding = jax.sharding.SingleDeviceSharding(jax.devices("cpu")[0])
-    return tpu_aot.higgs_kernel_cases(sharding)
+    return tpu_aot.kernel_cases(sharding)
 
 
 @pytest.mark.parametrize("case", _cases(), ids=lambda c: c[0])

@@ -455,9 +455,12 @@ def test_selection_parity_onehot_vs_argmax(rng):
 def test_wave_layout_legal_and_budgeted():
     """Hermetic kernel_layout-style pin for the fused kernel's VMEM plan:
     every BlockSpec-relevant dimension Mosaic-legal (128-multiple lane
-    dims, nibble-pair-even feature tiles), histogram block + scan scratch
-    under budget wherever the layout claims to fit, and the shapes that
-    must (bench Higgs) / must not (Epsilon-wide) fuse."""
+    dims, nibble-pair-even feature tiles), the working set — three
+    resident histograms + scan scratch + streamed blocks; the one-hot is
+    never stored since PR 28 — under budget wherever the layout claims to
+    fit, and the shapes that must (bench Higgs) / must not (MS-LTR,
+    Epsilon-wide) fuse: the widths admitted are the ones admitted before
+    the one-hot left the working set."""
     from lightgbm_tpu.ops.pallas_wave import (WAVE_VMEM_BUDGET,
                                               wave_layout)
 
@@ -468,18 +471,30 @@ def test_wave_layout_legal_and_budgeted():
                 assert lay["b_pad"] % 128 == 0 and lay["b_pad"] >= nb
                 assert (lay["ftile"] * lay["b_pad"]) % 128 == 0
                 assert lay["rows_block"] % 128 == 0
+                assert lay["total_bytes"] == (
+                    lay["hist_block_bytes"] + lay["scan_scratch_bytes"]
+                    + lay["stream_bytes"])
                 if lay["fits"]:
                     assert lay["single_chunk"]
                     assert lay["total_bytes"] <= WAVE_VMEM_BUDGET
-                    assert (lay["hist_block_bytes"]
-                            + lay["scan_scratch_bytes"]) <= WAVE_VMEM_BUDGET
         lay4 = wave_layout(13, 16, dtype, packed4=True)
         assert lay4["ftile"] % 2 == 0
     # the bench Higgs shape fuses (fp32 AND the quantized int8 wire) ...
     assert wave_layout(28, 256, "f32")["fits"]
     assert wave_layout(28, 256, "int8")["fits"]
-    # ... Epsilon-wide does not (keeps the unfused + pool + tiled scan)
+    assert wave_layout(28, 255, "f32")["rows_block"] == 256
+    # ... Epsilon-wide does not (keeps the unfused + pool + tiled scan),
+    # and neither does MS-LTR's 137 columns, though kernel_layout now hands
+    # them to one launch and the bytes would fit: the admitted widths end
+    # where they ended before PR 28 (63 columns at 255 bins f32)
     assert not wave_layout(2000, 256, "f32")["fits"]
+    wide = wave_layout(137, 255, "f32")
+    assert wide["ftile"] == 137 and wide["total_bytes"] <= WAVE_VMEM_BUDGET
+    assert not wide["single_chunk"] and not wide["fits"]
+    assert wave_layout(63, 255, "f32")["fits"]
+    assert not wave_layout(64, 255, "f32")["fits"]
+    assert wave_layout(240, 255, "int8")["fits"]
+    assert not wave_layout(241, 255, "int8")["fits"]
 
 
 def test_capability_predicate_and_knob():

@@ -8,7 +8,7 @@ CPU-only box.  That catches the class of error ``lower(lowering_platforms=
 it does not execute: whether the compiled kernel computes the right answer
 is ``chip_smoke.py``'s job, on the chip.
 
-    python tools/tpu_aot.py            # the three kernels, Higgs shape
+    python tools/tpu_aot.py   # the three kernels, Higgs shape + MS-LTR's tile
 
 prints one ``[OK]``/``[FAIL]`` line per kernel (with the compiler's message)
 and exits non-zero when any failed, or 3 when libtpu offers no topology.
@@ -49,10 +49,12 @@ def compile_for_tpu(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
-def higgs_kernel_cases(sharding):
+def kernel_cases(sharding):
     """(name, fn, args) for the three kernels at the Higgs headline shape:
     28 features, max_bin=255, 255 leaves, leaf_batch=16 — the shapes
-    ``chip_smoke.py`` runs."""
+    ``chip_smoke.py`` runs — and ``histogram_flat`` at the wide tile of the
+    benchmark's unfused cell (MS-LTR's 137 columns, which ``kernel_layout``
+    hands to ONE launch)."""
     import jax
     import jax.numpy as jnp
 
@@ -73,6 +75,11 @@ def higgs_kernel_cases(sharding):
             f"histogram_flat {dtype} B={b}",
             functools.partial(histogram_flat, num_bins=b, dtype=dtype),
             (sds((n, f), jnp.uint8), sds((n, 3), vd))))
+    wide = 137
+    cases.append((
+        f"histogram_flat f32 B={b} F={wide}",
+        functools.partial(histogram_flat, num_bins=b, dtype="f32"),
+        (sds((n, wide), jnp.uint8), sds((n, 3), jnp.float32))))
     cases.append((
         "histogram_flat f32 packed4 B=15",
         functools.partial(histogram_flat, num_bins=15, dtype="f32",
@@ -125,7 +132,7 @@ def main() -> int:
         print(f"tpu_aot: no compile-only TPU topology here: {e!r}"[:300])
         return 3
     failed = 0
-    for name, fn, args in higgs_kernel_cases(sharding):
+    for name, fn, args in kernel_cases(sharding):
         try:
             compile_for_tpu(fn, *args)
             print(f"[OK] {name}", flush=True)
