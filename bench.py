@@ -321,7 +321,7 @@ def run_wide_rung(rows, iters, platform, jax, features=None,
         "row_iters_per_sec": round(rows * iters / elapsed, 1),
         "histogram_pool_mb": pool_mb,
         "pool_slots": int(slots),
-        "pool_engaged": bool(g.grow.pool_capable and slots < num_leaves),
+        "pool_engaged": bool(g.plan.pool and slots < num_leaves),
         "leaf_hist_mb_unpooled": round(
             num_leaves * features * bins * 3 * 4 / 2**20, 1),
         "leaf_hist_mb_pooled": round(

@@ -81,7 +81,6 @@ import bench  # noqa: E402
 import lightgbm_tpu as lgb  # noqa: E402
 from lightgbm_tpu import native, serve  # noqa: E402
 from lightgbm_tpu.metrics import _auc  # noqa: E402
-from lightgbm_tpu.ops.histogram import resolve_impl  # noqa: E402
 from lightgbm_tpu.ops.pallas_common import interpret_mode  # noqa: E402
 from lightgbm_tpu.utils.jax_cache import (cache_entry_count,  # noqa: E402
                                           enable_compile_cache)
@@ -205,19 +204,19 @@ def agree(a, b, what: str, Xs, ys, *, gain_tol: float, auc_tol: float) -> dict:
 
 def assert_default_path(bst, what: str) -> dict:
     """What ran, not what was asked."""
-    g = bst._gbdt
-    impl = resolve_impl(g.grower_cfg.histogram_impl)
+    plan = bst._gbdt.plan
     leaves = int(trees_of(bst)[0].num_leaves)
-    say(f"{what}.histogram_impl", impl)
-    say(f"{what}.wave_fused_active", g.wave_fused_active)
+    say(f"{what}.plan", str(plan))
     say(f"{what}.first_tree_leaves", leaves)
-    check(impl == "pallas", f"{what}: histogram impl resolved to {impl!r}")
-    check(g.wave_fused_active is True, f"{what}: fused wave kernel inactive")
+    check(plan.hist_impl == "pallas",
+          f"{what}: histogram impl resolved to {plan.hist_impl!r}")
+    check(plan.body == "wave" and plan.fused,
+          f"{what}: fused wave kernel inactive: {plan.why.get('fused')}")
     check(leaves > LEAVES // 2,
           f"{what}: first tree has {leaves} of {LEAVES} leaves")
-    check(bool(np.isfinite(np.asarray(g.scores)).all()),
+    check(bool(np.isfinite(np.asarray(bst._gbdt.scores)).all()),
           f"{what}: non-finite training scores")
-    return {"histogram_impl": impl, "wave_fused_active": True,
+    return {"histogram_impl": plan.hist_impl, "wave_fused_active": True,
             "first_tree_leaves": leaves, "trees": len(trees_of(bst))}
 
 
@@ -287,7 +286,7 @@ def main() -> int:
     facts["fp32"] = assert_default_path(bst, "fp32")
     ref, TIMINGS["fp32_plain_train"], _ = timed(
         lambda: train(dict(params, **plain), ds, FP32_ROUNDS))
-    check(not ref._gbdt.wave_fused_active, "plain path ran the fused kernel")
+    check(not ref._gbdt.plan.fused, "plain path ran the fused kernel")
     p_def = bst.predict(Xs, raw_score=True)
     gap = np.abs(p_def - ref.predict(Xs, raw_score=True))
     say("fp32.vs_plain.raw_score_gap",
@@ -312,8 +311,8 @@ def main() -> int:
     q_unf, TIMINGS["quant_pallas_unfused_train"], _ = timed(
         lambda: train(dict(qparams, tpu_histogram_impl=default_impl,
                            tpu_wave_kernel="unfused"), ds, QUANT_ROUNDS))
-    check(resolve_impl(q_unf._gbdt.grower_cfg.histogram_impl) == "pallas"
-          and not q_unf._gbdt.wave_fused_active,
+    check(q_unf._gbdt.plan.hist_impl == "pallas"
+          and not q_unf._gbdt.plan.fused,
           "unfused pallas run did not run the unfused pallas path")
     q_plain, TIMINGS["quant_plain_train"], _ = timed(
         lambda: train(dict(qparams, **plain), ds, QUANT_ROUNDS))
@@ -379,11 +378,13 @@ def main() -> int:
         g = mbst._gbdt
         homes = {s.device for s in g.bins_dev.addressable_shards}
         say("multichip.bins_shard_devices", len(homes))
-        say("multichip.rs_active", g.grow.rs_active)
+        say("multichip.plan", str(g.plan))
         check(len(homes) == DEVICE["count"], "bins_dev is not sharded over "
               f"all {DEVICE['count']} devices: {sorted(map(str, homes))}")
-        check(g.grow.rs_active, "reduce-scatter inactive on the data mesh")
-        check(resolve_impl(g.grower_cfg.histogram_impl) == "pallas",
+        check(g.plan.reduce == "scatter",
+              "reduce-scatter inactive on the data mesh: "
+              f"{g.plan.why.get('scatter')}")
+        check(g.plan.hist_impl == "pallas",
               "multichip run did not use the Pallas histogram")
         # sharded vs serial from the same int8 gradients: the identity
         # tests/test_parallel.py pins (same splits, leaf values rtol 1e-4),

@@ -180,8 +180,7 @@ _PARAMS: List[Tuple[str, Any, Any, Tuple[str, ...], Optional[Tuple[Any, Any]]]] 
     ("gpu_use_dp", bool, False, (), None),
     ("num_gpu", int, 1, (), (1, None)),
     # TPU-specific knobs (no reference analog).
-    ("tpu_histogram_impl", str, "auto", (), None),  # auto|pallas|flat_bf16|onehot|segment
-    ("tpu_rows_block", int, 16384, (), (256, None)),
+    ("tpu_histogram_impl", str, "auto", (), None),  # auto|pallas|onehot|segment
     # auto 4-bit bin packing when all features fit 16 bins (reference
     # DenseBin IS_4BIT); set false to force byte-per-bin storage
     ("tpu_4bit_bins", bool, True, (), None),

@@ -14,6 +14,7 @@ the reference keeps SHAP/categorical logic host-side).
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -253,14 +254,11 @@ class GBDT:
                 "device_type or set it to cpu to train on the CPU backend")
         from ..parallel.mesh import DATA_AXIS, FEATURE_AXIS
         # Data-only meshes use the sharded permutation layout (shard_map:
-        # per-shard pallas histograms + one psum per wave).  Feature-only
-        # meshes route to the feature-sharded perm layout when the config
-        # allows (grower.fp_capable_for) — per-shard kernels, so the
-        # default histogram impl stays; only the GSPMD mask fallback needs
-        # the compiler-partitionable einsum impls.
+        # per-shard pallas histograms + one reduction per wave); what a
+        # feature-only or hybrid mesh runs is the growth plan's to say
+        # (self.plan, below).
         data_only_mesh = (self.mesh is not None
                           and int(self.mesh.shape[FEATURE_AXIS]) == 1)
-        hist_impl = cfg.tpu_histogram_impl
         voting = cfg.tree_learner == "voting" and data_only_mesh
         # EFB (reference FindGroups/FeatureGroup): histogram/partition run
         # on the bundled column matrix; split scans see reconstructed
@@ -302,11 +300,7 @@ class GBDT:
         # Every learner-composition downgrade/rejection goes through the
         # declarative capability matrix (models/capabilities.py) — ONE
         # enumerable table instead of scattered warn-and-fallback branches.
-        from .capabilities import Composition, resolve
-        if cfg.tpu_wave_kernel not in ("auto", "fused", "unfused"):
-            raise ValueError(
-                f"tpu_wave_kernel={cfg.tpu_wave_kernel!r}: expected auto, "
-                "fused or unfused")
+        from .capabilities import Composition, plan_growth, resolve
         comp, _ = resolve(Composition(
             voting=voting,
             leaf_batch=leaf_batch,
@@ -318,10 +312,6 @@ class GBDT:
             warn=Log.warning)
         voting, leaf_batch = comp.voting, comp.leaf_batch
         wave_kernel = comp.wave_kernel
-        if cfg.tpu_hist_comm not in ("auto", "allreduce", "reduce_scatter"):
-            raise ValueError(
-                f"tpu_hist_comm={cfg.tpu_hist_comm!r}: expected auto, "
-                "allreduce or reduce_scatter")
         if cfg.tpu_device_goss not in ("auto", "on", "off"):
             raise ValueError(
                 f"tpu_device_goss={cfg.tpu_device_goss!r}: expected auto, "
@@ -371,8 +361,7 @@ class GBDT:
             hist_bins=(self.bundles.max_group_bins
                        if self.bundles is not None else 0),
             split=_split_config(cfg, train),
-            histogram_impl=hist_impl,
-            rows_block=cfg.tpu_rows_block,
+            histogram_impl=cfg.tpu_histogram_impl,
             gather_rows=self.mesh is None or data_only_mesh,
             leaf_batch=leaf_batch,
             forced_splits=forced,
@@ -393,47 +382,37 @@ class GBDT:
             histogram_pool_size=cfg.histogram_pool_size,
             wave_kernel=wave_kernel,
             health_signal=self._health_active,
+            # 4-bit bin packing (reference DenseBin IS_4BIT
+            # auto-selection): with every feature at <= 16 bins, store
+            # nibble pairs — the resident bin matrix and per-leaf gathers
+            # halve.  Asked for here; the plan refuses it under EFB and
+            # the feature-parallel layout.
+            packed4=cfg.tpu_4bit_bins and train.binned.max_num_bins <= 16,
         )
-        from .grower import fp_capable_for, pool_active_for, rs_active_for
-        if (cfg.tpu_hist_comm == "reduce_scatter"
-                and not rs_active_for(self.grower_cfg, self.mesh,
-                                      DATA_AXIS)):
-            Log.warning(
-                "tpu_hist_comm=reduce_scatter needs a data-parallel mesh "
-                "and a composition without voting, "
-                "intermediate/advanced monotone constraints, forced "
-                "splits or (non-EFB) feature_contri; keeping the "
-                "full-histogram allreduce")
-        if (cfg.histogram_pool_size >= 0
-                and not pool_active_for(self.grower_cfg, self.mesh,
-                                        DATA_AXIS)):
-            Log.warning(
-                "histogram_pool_size is ignored for this composition: the "
-                "GSPMD mask layout, voting-parallel and the intermediate/"
-                "advanced monotone refresh need every leaf histogram "
-                "resident; keeping the full (num_leaves, ...) carry")
-        if (self.mesh is not None and not data_only_mesh
-                and hist_impl == "auto"
-                and not fp_capable_for(self.grower_cfg, self.mesh,
-                                       DATA_AXIS)):
-            # GSPMD mask fallback: the pallas kernel is per-device-only;
-            # use the compiler-partitionable einsum impls
-            import dataclasses as _dc
-            hist_impl = ("onehot" if jax.default_backend() == "tpu"
-                         else "segment")
-            self.grower_cfg = _dc.replace(self.grower_cfg,
-                                          histogram_impl=hist_impl)
-        # 4-bit bin packing (reference DenseBin IS_4BIT auto-selection):
-        # with every feature at <= 16 bins, store nibble pairs — the
-        # resident bin matrix and per-leaf gathers halve.  Excluded from
-        # EFB (bundle bins exceed 4 bits) and the feature-parallel layout
-        # (nibble pairs must not straddle feature shards).
-        if (cfg.tpu_4bit_bins and self.bundles is None
-                and train.binned.max_num_bins <= 16
-                and not fp_capable_for(self.grower_cfg, self.mesh,
-                                       DATA_AXIS)):
-            import dataclasses as _dc
-            self.grower_cfg = _dc.replace(self.grower_cfg, packed4=True)
+        # What this configuration runs on this mesh at this shape — body,
+        # layout, kernel, reduction, pool — decided in ONE place
+        # (capabilities.plan_growth) and read everywhere else, the grower
+        # included.  Invalid tpu_histogram_impl / tpu_wave_kernel /
+        # tpu_hist_comm values raise here.
+        self.plan = plan_growth(self.grower_cfg, self.mesh, DATA_AXIS,
+                                rows=train.num_data,
+                                features=train.num_features)
+        Log.debug(f"growth plan: {self.plan}")
+        # the config states the form the bins travel in: the plan's
+        self.grower_cfg = dataclasses.replace(self.grower_cfg,
+                                              packed4=self.plan.packed4)
+        for asked, key, said in (
+                (cfg.tpu_hist_comm == "reduce_scatter", "scatter",
+                 "tpu_hist_comm=reduce_scatter cannot engage ({}); keeping "
+                 "the full-histogram allreduce"),
+                (cfg.histogram_pool_size >= 0, "pool",
+                 "histogram_pool_size is ignored for this composition "
+                 "({}); keeping the full (num_leaves, ...) carry"),
+                (wave_kernel == "fused", "fused",
+                 "tpu_wave_kernel=fused cannot engage ({}); keeping the "
+                 "unfused path")):
+            if asked and key in self.plan.why:
+                Log.warning(said.format(self.plan.why[key]))
         self._quant_key = (jax.random.PRNGKey(cfg.seed)
                            if cfg.use_quantized_grad else None)
         # PRNG for per-node randomness (extra_trees thresholds / bynode
@@ -448,27 +427,6 @@ class GBDT:
                 cfg.extra_seed * 92821 + cfg.feature_fraction_seed)
         self.grow = make_grower(self.grower_cfg, mesh=self.mesh,
                                 data_axis=DATA_AXIS)
-        # Fused wave kernel (tpu_wave_kernel, ops/pallas_wave.py): the
-        # composition gate lives on the grower; AND the shape gates here —
-        # the shared VMEM-fit predicate plus the perm-layout row floor
-        # (_grow_impl routes n <= _MIN_BUCKET to the mask layout, where no
-        # wave runs at all) — so reporting (bench blobs, the fused-wave
-        # census) states exactly what _grow_wave traces.
-        self.wave_fused_active = False
-        if getattr(self.grow, "wave_fused", False):
-            from ..ops.pallas_wave import wave_fits_for
-            from .grower import _MIN_BUCKET
-            self.wave_fused_active = (
-                train.num_data > _MIN_BUCKET
-                and wave_fits_for(self.grower_cfg, train.num_features))
-        if wave_kernel == "fused" and not self.wave_fused_active:
-            Log.warning(
-                "tpu_wave_kernel=fused cannot engage for this composition/"
-                "shape (device mesh, voting, EFB bundling, monotone "
-                "constraints, sorted-categorical scans, CEGB, "
-                "feature_contri, a feature space too wide for one VMEM "
-                "block, or too few rows for the wave layout); keeping the "
-                "unfused path")
         if self.bundles is not None:
             self.bins_dev = train.bundled_bins_device()
             self._fg_dev = jnp.asarray(self.bundles.feat_group, jnp.int32)
@@ -495,7 +453,7 @@ class GBDT:
                 if pad:
                     self.bins_dev = jnp.pad(self.bins_dev,
                                             ((0, pad), (0, 0)))
-            elif getattr(self.grow, "fp_capable", False):
+            elif self.plan.layout == "feature":
                 # Feature-sharded perm layout: pad feature columns so the
                 # (data, feature) placement shards evenly; the grower pads
                 # its per-feature metadata to match (grower._grow_fp).
@@ -789,6 +747,12 @@ class GBDT:
                         self.valid_scores[i].at[:, k].add(pred)
                 else:
                     self.valid_scores[i] = self.valid_scores[i] + pred
+
+    @property
+    def wave_fused_active(self) -> bool:
+        """Read-only alias of ``self.plan.fused`` (the fused wave kernel
+        runs), kept for the tools and tests that read it."""
+        return self.plan.fused
 
     @property
     def fused_path_active(self) -> bool:

@@ -8,7 +8,9 @@ sequence with only scalars returning to host).
 
 TPU re-design: the whole per-tree growth loop is ONE compiled XLA program —
 a ``lax.while_loop`` with static trip bound ``num_leaves - 1`` over static-shape
-state.  Two interchangeable data layouts:
+state.  ONE permutation-layout body, ``_grow_wave`` (a wave of one is the
+sequential leaf-wise case), in three layouts, and the mask body beside it;
+which of them a configuration runs is ``capabilities.plan_growth``'s to say:
 
 - **Permutation layout** (default, single device): a row-index permutation kept
   grouped by leaf (the reference's ``DataPartition``/``CUDADataPartition``), so
@@ -36,7 +38,13 @@ state.  Two interchangeable data layouts:
 
   Either way every split decision is replicated across shards and per-tree
   cost stays O(N·depth / shards).
-- **Mask layout** (feature-axis meshes / tiny data): rows carry a
+- **Feature-sharded permutation layout** (feature-only meshes, a wave of
+  one): rows replicated, feature columns sharded; each shard histograms
+  and scans its own columns, the winner syncs as one SplitInfo payload and
+  the owner of the split column broadcasts the go-left vector
+  (``_grow_fp``).
+- **Mask layout** (hybrid meshes, compositions the feature-sharded layout
+  refuses, tiny data): rows carry a
   ``row_leaf`` assignment vector and leaf membership is a predicate folded
   into the histogram contraction.  Slower (full-N pass per split) but works
   under arbitrary GSPMD shardings: reductions cross the mesh via
@@ -58,8 +66,8 @@ slots, and a miss (an evicted histogram needed again: splitting an old
 leaf, forced splits) recomputes from the leaf's contiguous perm segment in
 creation-time row order and re-reduces across shards like the resident
 path.  Under ``hist_comm=reduce_scatter`` a slot holds only the owned
-``ceil(G/K)`` feature slice, so the savings multiply.  See
-``pool_active_for`` for the compositions that keep full residency.
+``ceil(G/K)`` feature slice, so the savings multiply.  The compositions
+that keep full residency: ``capabilities.plan_growth`` (``why["pool"]``).
 """
 
 from __future__ import annotations
@@ -75,9 +83,10 @@ from ..ops.histogram import histogram_from_vals, unpack_bins4
 from ..ops.split import (BestSplit, SplitConfig, best_split, leaf_gain,
                          leaf_output, smoothed_output, sync_best_split)
 from ..telemetry.spans import kernel_rows, phase
+from .capabilities import PERM_MIN_ROWS as _MIN_BUCKET
+from .capabilities import plan_growth
 
 _NEG_INF = -jnp.inf
-_MIN_BUCKET = 2048
 
 
 @dataclasses.dataclass(frozen=True)
@@ -167,8 +176,9 @@ class GrowerConfig:
     # when every feature has <= 16 bins the (N, F) matrix is stored as
     # (N, ceil(F/2)) uint8 nibble pairs — the resident bin matrix and the
     # per-leaf row gathers halve, and the histogram kernels unpack in
-    # VMEM/registers.  Set by GBDT when eligible (no EFB bundling, no
-    # feature-parallel layout).
+    # VMEM/registers.  States the form the caller's bins are in; GBDT
+    # sets it from its growth plan (no EFB bundling, no feature-parallel
+    # layout).
     packed4: bool = False
     # Cross-shard histogram reduction for the data-parallel sharded-perm
     # paths (reference data_parallel_tree_learner.cpp:284).  "allreduce":
@@ -178,8 +188,8 @@ class GrowerConfig:
     # winner syncs via the one-hot SplitInfo payload broadcast
     # (SyncUpGlobalBestSplit) — ~2x less comm per wave, shards-x less
     # scan FLOPs.  "auto" = reduce_scatter whenever the composition
-    # allows (see rs_active_for); voting mode and the mask layout keep
-    # their own reductions in every mode.
+    # allows (the growth plan's ``reduce``); voting mode and the mask
+    # layout keep their own reductions in every mode.
     hist_comm: str = "auto"
     # Bounded histogram pool (reference HistogramPool,
     # serial_tree_learner.h: LRU slots + recompute-on-miss), reference MB
@@ -189,10 +199,10 @@ class GrowerConfig:
     # multiply) behind an int32 leaf->slot indirection.  -1 = unbounded =
     # the full (L, G, B, 3) carry.  Auto-clamped to [2*leaf_batch + 1, L]
     # so the wave frontier (W parents pinned for sibling subtraction + W
-    # freshly built smaller siblings) always fits.  Engages on the
-    # perm/wave/sharded-perm layouts (see pool_active_for); the mask
-    # layout, voting and the intermediate/advanced monotone refresh keep
-    # full residency.
+    # freshly built smaller siblings) always fits.  Engages on the wave
+    # body, single-device or data-sharded (the growth plan's ``pool``);
+    # the mask layout, voting and the intermediate/advanced monotone
+    # refresh keep full residency.
     histogram_pool_size: float = -1.0
     # Fused wave kernel (ops/pallas_wave.py): ONE pallas_call per wave
     # builds the smaller-sibling histograms, derives the larger siblings
@@ -202,7 +212,7 @@ class GrowerConfig:
     # where the capability checks pass AND the flat pallas kernel is the
     # live histogram impl (TPU backends); "fused" forces the kernel
     # (interpret-mode on CPU — how tier-1 exercises the kernel body);
-    # "unfused" keeps the per-leaf path.  See wave_fused_for.
+    # "unfused" keeps the per-leaf path.  The growth plan's ``fused``.
     wave_kernel: str = "auto"
     # Training-health sentinel signals (resilience/health.py): True wires
     # the quantized int16-wire overflow guard's escalation into a
@@ -315,204 +325,6 @@ def _store_best(state: _GrowState, leaf: jnp.ndarray, bs: BestSplit,
     )
 
 
-def fp_capable_for(cfg: GrowerConfig, mesh, data_axis: str) -> bool:
-    """Static predicate: does this config route a feature-only mesh to the
-    feature-sharded perm layout (vs the GSPMD mask fallback)?  Shared by
-    make_grower's dispatch and GBDT's bins pre-padding / impl selection so
-    they cannot disagree."""
-    if mesh is None or len(mesh.axis_names) < 2:
-        return False
-    others = [a for a in mesh.axis_names if a != data_axis]
-    if len(others) != 1 or int(mesh.shape[others[0]]) <= 1:
-        return False
-    n_forced = len(cfg.forced_splits or ())
-    # feature_contri is a static full-F tuple truncated to the scan width —
-    # a per-shard feature slice would apply shard 0's multipliers
-    # everywhere, so those configs keep the (full-F) mask fallback.
-    return (int(mesh.shape[data_axis]) == 1 and cfg.leaf_batch == 1
-            and not cfg.voting and not cfg.split.extra_trees
-            and cfg.feature_fraction_bynode >= 1.0
-            and not cfg.interaction_groups and not cfg.split.use_cegb
-            and not n_forced and not cfg.bundled
-            and not cfg.split.feature_contri
-            and not ((cfg.mono_intermediate or cfg.mono_advanced)
-                     and cfg.split.has_monotone))
-
-
-def rs_active_for(cfg: GrowerConfig, mesh, data_axis: str) -> bool:
-    """Static predicate: does this config route the data-sharded perm/wave
-    paths to the feature-sliced histogram reduce-scatter (vs the replicated
-    full-histogram allreduce)?  Shared by make_grower's dispatch, GBDT's
-    knob resolution and the HLO-cost/census tooling so they cannot
-    disagree.
-
-    Excluded compositions (these keep the allreduce):
-    - voting: it reduces only vote winners' slices, never full histograms;
-    - intermediate/advanced monotone: the per-step refresh rescans EVERY
-      leaf from its resident histogram and the advanced bound tensors live
-      in full feature space — both need the replicated leaf_hist;
-    - forced splits: _apply_forced derives child stats from the full
-      histogram row of an arbitrary (forced) feature;
-    - feature_contri without EFB: the multipliers are a STATIC full-F
-      tuple baked into the scan, which truncates to the local width — a
-      slice-local scan would apply shard 0's block to every shard's owned
-      features.  (The EFB slice keeps the full-F scan under an ownership
-      mask, so it composes.)
-    """
-    if cfg.hist_comm not in ("auto", "reduce_scatter"):
-        return False
-    if mesh is None or int(mesh.shape[data_axis]) <= 1:
-        return False
-    if not cfg.gather_rows:
-        return False
-    if cfg.voting:
-        return False
-    if cfg.forced_splits:
-        return False
-    if cfg.split.feature_contri and not cfg.bundled:
-        return False
-    if (cfg.mono_intermediate or cfg.mono_advanced) and cfg.split.has_monotone:
-        return False
-    return True
-
-
-def pool_active_for(cfg: GrowerConfig, mesh=None,
-                    data_axis: str = "data") -> bool:
-    """Static predicate: may this config bound the leaf-histogram carry
-    with the P-slot pool (``histogram_pool_size`` >= 0, reference
-    ``HistogramPool`` semantics) instead of full (L, G, B, 3) residency?
-    Shared by make_grower's layouts, GBDT's knob resolution and tests so
-    they cannot disagree.
-
-    Excluded compositions (these keep full residency):
-    - the GSPMD mask layout (``gather_rows=False``): leaves have no
-      contiguous row segment to recompute an evicted histogram from;
-    - voting: the wave body and root scan read resident LOCAL parent
-      histograms that are never globally reduced;
-    - intermediate/advanced monotone: the per-step refresh rescans EVERY
-      leaf from its resident histogram — a bounded pool would recompute
-      L-P histograms per step.
-
-    Note the actual slot count is shape-dependent (``hist_cols``): a pool
-    large enough to hold all L leaves degenerates to the unpooled carry
-    even when this predicate is True."""
-    if cfg.histogram_pool_size < 0:
-        return False
-    if not cfg.gather_rows:
-        return False
-    if cfg.voting:
-        return False
-    if (cfg.mono_intermediate or cfg.mono_advanced) and cfg.split.has_monotone:
-        return False
-    return True
-
-
-def wave_fused_for(cfg: GrowerConfig, mesh=None,
-                   data_axis: str = "data") -> bool:
-    """Static predicate: may this composition route wave growth through
-    the fused histogram->subtract->scan Pallas kernel
-    (``ops/pallas_wave.py``, ``tpu_wave_kernel``)?  Shared by
-    make_grower's dispatch, GBDT's knob resolution and the census/bench
-    tooling so they cannot disagree.  The final answer is this AND the
-    shape-dependent ``pallas_wave.wave_layout_fits`` (checked at trace
-    time in _grow_wave, and by GBDT for reporting).
-
-    Excluded compositions (these keep the unfused wave):
-    - any device mesh / the GSPMD mask layout: the cross-shard histogram
-      reduce (psum / reduce-scatter) lands MID-fusion, between build and
-      scan;
-    - voting: it scans compact vote-winner slices, not full histograms;
-    - EFB bundling: the scan runs in EXPANDED original-feature space
-      (bundle-offset gathers are not Mosaic-expressible);
-    - monotone constraints (any mode): the scan needs per-child output
-      bounds / the per-step refresh;
-    - forced splits: _apply_forced overwrites stored splits mid-growth;
-    - extra_trees / feature_fraction_bynode / interaction constraints:
-      per-NODE feature masks and thresholds (the kernel takes one static
-      wave-level mask);
-    - CEGB: per-child gain-penalty columns;
-    - feature_contri: static full-F multipliers stay host-resolved;
-    - sorted categoricals: the many-vs-many scan argsorts (one-hot
-      categoricals compose fine).
-
-    Under "auto" the kernel additionally engages only where the flat
-    pallas histogram is the live impl (TPU) — on CPU backends the
-    interpret-mode kernel is a test vehicle, not a win, so auto keeps the
-    unfused path and only an explicit ``tpu_wave_kernel=fused`` forces
-    it."""
-    if cfg.wave_kernel not in ("auto", "fused", "unfused"):
-        raise ValueError(
-            f"wave_kernel={cfg.wave_kernel!r}: expected auto, fused or "
-            "unfused")
-    if cfg.wave_kernel == "unfused":
-        return False
-    if mesh is not None:
-        return False
-    if not cfg.gather_rows:
-        return False
-    if cfg.voting or cfg.bundled:
-        return False
-    if cfg.forced_splits:
-        return False
-    if cfg.split.has_monotone:
-        return False
-    if cfg.split.extra_trees or cfg.feature_fraction_bynode < 1.0:
-        return False
-    if cfg.interaction_groups:
-        return False
-    if cfg.split.use_cegb or cfg.split.feature_contri:
-        return False
-    if cfg.split.has_categorical and cfg.split.use_sorted_categorical:
-        return False
-    if cfg.wave_kernel == "fused":
-        return True
-    from ..ops.histogram import resolve_impl
-    return resolve_impl(cfg.histogram_impl) in ("pallas", "flat")
-
-
-def stream_unsupported_reason(cfg: GrowerConfig, mesh=None) -> Optional[str]:
-    """Why this composition cannot run the out-of-core streaming grower
-    (``lightgbm_tpu/stream/``, docs/STREAMING.md); None = stream-capable.
-    Shared by ``make_grower``'s stream kit, the stream trainer's
-    validation and the tests so they cannot disagree.
-
-    The streaming grower is a host-driven twin of the mask layout: every
-    per-split pass over the bins matrix (partition update + the smaller
-    sibling's histogram) is row-separable, so it runs chunk-by-chunk
-    under a byte budget.  Compositions whose growth step needs
-    NON-row-separable state are excluded:
-
-    - a device mesh: residency is a single-device host->device pipeline
-      (multi-host streaming composes with pre-partitioned shards instead);
-    - voting: local-histogram voting has no global per-leaf histogram to
-      chunk-accumulate into;
-    - EFB bundling: bundle-space decode tables are per-shard-build state
-      the store does not carry (dense streaming shapes don't bundle);
-    - forced splits: ``_apply_forced`` reads arbitrary leaves' resident
-      histograms outside the chunk sweep;
-    - intermediate/advanced monotone: the per-step refresh rescans every
-      leaf, not just the split one;
-    - CEGB / interaction constraints: per-path feature state is updated
-      by ``_children_updates`` variants the kit does not thread.
-    """
-    if mesh is not None:
-        return "device mesh (stream residency is single-device)"
-    if cfg.voting:
-        return "voting-parallel keeps local histograms"
-    if cfg.bundled:
-        return "EFB bundling"
-    if cfg.forced_splits:
-        return "forced splits"
-    if (cfg.mono_intermediate or cfg.mono_advanced) \
-            and cfg.split.has_monotone:
-        return "intermediate/advanced monotone refresh"
-    if cfg.split.use_cegb:
-        return "CEGB penalties"
-    if cfg.interaction_groups:
-        return "interaction constraints"
-    return None
-
-
 def _split_buckets(n: int) -> list:
     """Static slice sizes covering leaf row counts 1..n."""
     sizes = []
@@ -558,9 +370,14 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
     With ``mesh`` (and ``cfg.gather_rows``), the permutation/wave layouts run
     per-shard inside ``shard_map`` over ``data_axis`` with one histogram
     reduction per wave — a feature-sliced ``psum_scatter`` or a full
-    ``psum``, per ``cfg.hist_comm`` (see module docstring)."""
+    ``psum``, per ``cfg.hist_comm`` (see module docstring).
+
+    Which body, layout, kernel and reduction run is read off the growth
+    plan (``capabilities.plan_growth``): its static half here, completed
+    in ``_grow_impl`` for the shapes the program is traced at."""
 
     L, B = cfg.num_leaves, cfg.num_bins
+    static = plan_growth(cfg, mesh, data_axis, rows=None, features=None)
     HB = cfg.hist_bins or cfg.num_bins   # histogram-storage bin axis
     forced = cfg.forced_splits or ()
     n_forced = min(len(forced), max(L - 1, 0))
@@ -682,7 +499,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             # Slice-local scan (see _best_for): node inputs derive
             # replicated, then project onto the owned feature window.  The
             # advanced-monotone bound tensors never reach this path
-            # (rs_active_for excludes the refresh modes).
+            # (the plan never scatters under the refresh modes).
             assert advk is None
             fmaskk, randk, penaltyk = rs["project"](fmaskk, randk, penaltyk)
             nbpf, nan_bins, is_cat, monotone = rs["meta_s"]
@@ -753,35 +570,15 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         raise ValueError(
             "forced splits require leaf_batch=1 and are not supported with "
             "voting-parallel (the wave scheduler would reorder them)")
-    # Feature-parallel capability: a feature-only mesh routes to the
-    # feature-sharded perm layout when every enabled knob supports local
-    # per-shard scans; anything else falls back to the GSPMD mask layout.
     fp_axis_name = None
     fp_shards = 1
-    if mesh is not None and len(mesh.axis_names) > 1:
-        others = [a for a in mesh.axis_names if a != data_axis]
-        if len(others) == 1:
-            fp_axis_name = others[0]
-            fp_shards = int(mesh.shape[fp_axis_name])
+    if static.layout == "feature":
+        fp_axis_name = next(a for a in mesh.axis_names if a != data_axis)
+        fp_shards = int(mesh.shape[fp_axis_name])
 
     adv = cfg.mono_advanced and cfg.split.has_monotone
     inter = (cfg.mono_intermediate or adv) and cfg.split.has_monotone
-    fp_capable = fp_capable_for(cfg, mesh, data_axis)
-    if cfg.hist_comm not in ("auto", "allreduce", "reduce_scatter"):
-        raise ValueError(
-            f"hist_comm={cfg.hist_comm!r}: expected auto, allreduce or "
-            "reduce_scatter")
-    rs_on = rs_active_for(cfg, mesh, data_axis)
     rs_shards = 1 if mesh is None else int(mesh.shape[data_axis])
-    # ---- bounded histogram pool (reference HistogramPool,
-    # serial_tree_learner.h: cache_size slots, LRU eviction, recompute on a
-    # cache miss).  P slots replace the full (L, ...) leaf_hist carry; the
-    # leaf->slot indirection lives in the growth state.
-    pool_capable = pool_active_for(cfg, mesh, data_axis)
-    # ---- fused wave kernel (ops/pallas_wave.py, tpu_wave_kernel): the
-    # composition-level gate; the shape-level wave_layout_fits check runs
-    # at trace time inside _grow_wave.
-    wave_fused_req = wave_fused_for(cfg, mesh, data_axis)
     _W_FRONTIER = min(cfg.leaf_batch, max(L - 1, 1))
 
     def _pool_slots(hist_cols: int) -> int:
@@ -789,8 +586,10 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         slots under the reference's MB semantics, clamped so one wave
         always fits (W parent slots stay pinned for sibling subtraction
         while up to 2W child slots materialize) and to L (>= L slots ==
-        today's unpooled carry, returned as exactly L)."""
-        if not pool_capable:
+        today's unpooled carry, returned as exactly L).  (Reference
+        HistogramPool, serial_tree_learner.h: cache_size slots, LRU
+        eviction, recompute on a cache miss.)"""
+        if not static.pool:
             return L
         slot_bytes = hist_cols * HB * 3 * 4
         p = int(float(cfg.histogram_pool_size) * (1 << 20)
@@ -800,7 +599,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
 
     def _pool_ops(P):
         """Slot machinery for a P-slot pool: LRU claim/evict and ownership
-        bookkeeping, shared by the perm (W=1) and wave (W>1) bodies."""
+        bookkeeping."""
         IMAX = jnp.iinfo(jnp.int32).max
 
         @phase("grow/update")
@@ -860,7 +659,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         return claim, assign
 
     def _pool_setup(pool_cols, axis, rs):
-        """Per-layout pool context shared by _grow_perm and _grow_wave:
+        """Per-layout pool context of _grow_wave:
         slot count, activity flag, claim/assign ops, and the reduce every
         recomputed (miss) histogram must ride so its value matches the
         resident path's."""
@@ -895,9 +694,9 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             "monotone_constraints_method=advanced does not compose with "
             "forced splits (the refresh-gathered child bounds would not "
             "match a force-overwritten split); use intermediate")
-    if cfg.packed4 and (cfg.bundled or fp_capable):
-        raise ValueError("packed4 bins do not compose with EFB bundling or "
-                         "the feature-parallel layout (caller gates this)")
+    if cfg.packed4 and not static.packed4:
+        raise ValueError("packed4 bins: " + static.why["packed4"]
+                         + " (the caller packs by its plan)")
     @phase("grow/scan")
     def _vote_best_batch(hist_loc, pgk, phk, pck, poutk, scale3, meta,
                          feature_mask, boundsk, depthk, axis,
@@ -1120,12 +919,10 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
     @phase("grow/update")
     def _children_updates(st, leaf, new_leaf, hist_left, hist_right,
                           gl, hl, cl, gr, hr, cr, meta, feature_mask,
-                          cegb=None, groups_mat=None, scale3=None,
-                          sync=None, fp_mono=None, rs=None, slots2=None):
+                          cegb=None, groups_mat=None, scale3=None):
         """Store child stats + their best splits (both children batched into
-        single 2-row scatters to minimize kernel count in the hot loop).
-        ``slots2`` redirects the two histogram writes into pool slots
-        (bounded pool active); default is the unpooled slot == leaf id."""
+        single 2-row scatters to minimize kernel count in the hot loop):
+        one executed split of the mask body and of the stream kit."""
         depth = st.leaf_depth[leaf] + 1
         node = st.num_leaves - 1
         pair = jnp.stack([leaf, new_leaf])
@@ -1177,8 +974,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 # monotone feature caps both children at the child-output
                 # midpoint; outputs are always clipped to the leaf's
                 # inherited bounds.
-                mono_t = (fp_mono(st.best_feature[leaf]) if fp_mono
-                          is not None else meta[3][st.best_feature[leaf]])
+                mono_t = meta[3][st.best_feature[leaf]]
                 is_num = ~st.best_is_cat[leaf]
                 mid = (out_l + out_r) / 2.0
                 lo_l = jnp.where((mono_t < 0) & is_num,
@@ -1218,11 +1014,10 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         h2 = jnp.stack([hl, hr])
         c2 = jnp.stack([cl, cr])
         hist2s = _expand_hist_batch(_scale_hist(hist2, scale3), meta,
-                                    g2, h2, c2, rs)    # scaled (split scan)
+                                    g2, h2, c2)        # scaled (split scan)
         st = st._replace(
             num_leaves=st.num_leaves + 1,
-            leaf_hist=st.leaf_hist.at[
-                pair if slots2 is None else slots2].set(hist2),
+            leaf_hist=st.leaf_hist.at[pair].set(hist2),
             leaf_sum_grad=st.leaf_sum_grad.at[pair].set(g2),
             leaf_sum_hess=st.leaf_sum_hess.at[pair].set(h2),
             leaf_count=st.leaf_count.at[pair].set(c2),
@@ -1236,12 +1031,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             else depth < cfg.max_depth
         bs2 = _best_for_pair(hist2s, g2, h2, c2, meta, feature_mask,
                              penalty2, jnp.stack([out_l, out_r]), node_key,
-                             path2, groups_mat, bounds2, depth2, rs=rs)
-        if sync is not None:
-            # feature-parallel / reduce-scatter: local scans covered only
-            # owned features; globalize both children's winners before
-            # storing
-            bs2 = sync(bs2)
+                             path2, groups_mat, bounds2, depth2)
         gain2 = jnp.where(depth_ok, bs2.gain, _NEG_INF)
         return st._replace(
             best_gain=st.best_gain.at[pair].set(gain2),
@@ -1674,7 +1464,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
 
     def _part_branch_for(bins_pad, nan_bins, S, meta=None):
         """Partition one leaf's contiguous perm slice of static size S
-        (cheap S-ops; no histogram).  Shared by the perm and wave layouts.
+        (cheap S-ops; no histogram).
         Under EFB the split feature's column is decoded from its bundle."""
         @phase("grow/partition")
         def branch(perm, start, cnt, feat, sbin, dleft, scat, cmask):
@@ -1747,7 +1537,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         """RAW histogram of a contiguous perm range of static size S (the
         smaller sibling — the larger one comes from parent-hist subtraction,
         the reference's FeatureHistogram::Subtract).  Padded slots hit the
-        phantom zero row.  Shared by the perm and wave layouts."""
+        phantom zero row."""
         @phase("grow/hist")
         def branch(perm, start, cnt):
             seg = jax.lax.dynamic_slice(perm, (start,), (S,))
@@ -1755,7 +1545,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             seg = jnp.where(valid, seg, n)
             return histogram_from_vals(
                 bins_pad[seg], vals_pad[seg], num_bins=HB,
-                impl=cfg.histogram_impl,
+                impl=static.hist_impl,
                 rows_block=min(cfg.rows_block, S),
                 packed4=cfg.packed4, features=nf)
         return branch
@@ -1872,7 +1662,8 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
 
     @phase("grow/setup")
     def _perm_setup(bins, vals, scale3, meta, feature_mask, cegb, key,
-                    groups_mat=None, axis=None, rs=None, pool_slots=None):
+                    groups_mat=None, axis=None, rs=None, pool_slots=None,
+                    voting=False):
         """Shared permutation-layout prologue: padded arrays, buckets, root
         histogram/state/best-split.  ``axis`` = shard_map axis name for the
         cross-shard histogram reduction (None = single device); ``rs`` = the
@@ -1889,10 +1680,9 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         perm0 = jnp.concatenate([jnp.arange(n, dtype=jnp.int32),
                                  jnp.full(max_bucket, n, jnp.int32)])
         root_hist = histogram_from_vals(
-            bins, vals, num_bins=HB, impl=cfg.histogram_impl,
+            bins, vals, num_bins=HB, impl=static.hist_impl,
             packed4=cfg.packed4, features=meta[0].shape[0],
             rows_block=cfg.rows_block)
-        voting = cfg.voting and axis is not None
         if axis is not None and not voting:
             # The reference's histogram reduce
             # (data_parallel_tree_learner.cpp:284) — integer tensors under
@@ -1967,220 +1757,17 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                              side="right") - 1, 0, L - 1)].astype(jnp.int32)
         return jnp.zeros(n, jnp.int32).at[state.perm[:n]].set(pos_leaf)
 
-    # ------------------------------------------------------------------ perm path
-    def _grow_perm(bins, vals, scale3, feature_mask, meta, cegb=None,
-                   key=None, axis=None, faxis=None, fp_shards=1):
-        """Permutation-layout growth (single device, or per-shard under
-        ``shard_map`` when ``axis`` names the mesh data axis, or
-        feature-sharded when ``faxis`` names the feature axis: rows
-        replicated, each shard histograms/scans only its own feature
-        columns — the reference FeatureParallelTreeLearner layout)."""
-        n = bins.shape[0]
-        f = meta[0].shape[0]
-        nan_bins = meta[1]
-        groups_mat = _groups_matrix(f) if use_groups else None
-        foffset = (jax.lax.axis_index(faxis) * f if faxis is not None
-                   else None)
-        fp_sync = (None if faxis is None else phase("grow/reduce")(
-            lambda bs: _fp_sync_best(bs, foffset, faxis, fp_shards)))
-        fp_mono = None
-        if faxis is not None and cfg.split.has_monotone:
-            def fp_mono(feat_g):
-                # constraint type of a GLOBAL feature: owner shard
-                # broadcasts it (the local meta holds only owned features)
-                lf = feat_g - foffset
-                owns = (lf >= 0) & (lf < f)
-                m = jnp.where(owns, meta[3][jnp.clip(lf, 0, f - 1)], 0)
-                return _psum(m, faxis)
-        rs = None
-        hist_cols = f if cfg.packed4 else bins.shape[1]
-        if axis is not None and rs_on:
-            rs = _make_rs(axis, hist_cols, meta)
-        sync = fp_sync if fp_sync is not None else (
-            rs["sync"] if rs is not None else None)
-        P, pool_on, pool_claim, pool_assign, _reduce_hist = _pool_setup(
-            rs["go"] if rs is not None else hist_cols, axis, rs)
-        (state, bins_pad, vals_pad, buckets, buckets_arr,
-         max_bucket) = _perm_setup(bins, vals, scale3, meta, feature_mask,
-                                   cegb, key, groups_mat, axis, rs, P)
-        if fp_sync is not None:
-            # _perm_setup stored the LOCAL root best; globalize it
-            # (reference SyncUpGlobalBestSplit after the root scan).
-            zero = jnp.zeros((), jnp.float32)
-            bs0 = BestSplit(
-                gain=state.best_gain[0], feature=state.best_feature[0],
-                bin=state.best_bin[0],
-                default_left=state.best_default_left[0],
-                is_cat=state.best_is_cat[0],
-                cat_mask=state.best_cat_mask[0],
-                sum_grad_left=state.best_gl[0],
-                sum_hess_left=state.best_hl[0],
-                count_left=state.best_cl[0],
-                sum_grad_right=zero, sum_hess_right=zero, count_right=zero)
-            state = _store_best(state, jnp.asarray(0), fp_sync(bs0),
-                                jnp.asarray(True))
-
-        part_branches = ([_part_branch_for_gl(S) for S in buckets]
-                         if faxis is not None else
-                         [_part_branch_for(bins_pad, nan_bins, S, meta)
-                          for S in buckets])
-        hist_branches = [_hist_branch_for(bins_pad, vals_pad, n, S,
-                                          meta[0].shape[0])
-                         for S in buckets]
-
-        def _bucket_of(cnt):
-            return jnp.clip(jnp.searchsorted(buckets_arr, cnt, side="left"),
-                            0, len(buckets) - 1).astype(jnp.int32)
-
-        def _pool_hist_of(st, l):
-            """Pool lookup with recompute-on-miss (reference
-            HistogramPool::Get returning false -> the learner reconstructs
-            the leaf's histogram from its rows): an evicted leaf's
-            histogram is rebuilt from its contiguous perm segment — whose
-            row order is untouched since the leaf was created, so a leaf
-            originally histogrammed directly recomputes bit-identically —
-            and re-reduced across shards exactly like the resident path."""
-            sl = st.leaf_slot[l]
-
-            def rec(_):
-                h = jax.lax.switch(
-                    _bucket_of(st.leaf_rows[l]), hist_branches, st.perm,
-                    st.leaf_start[l], st.leaf_rows[l])
-                return _reduce_hist(h)
-
-            return jax.lax.cond(
-                sl < 0, rec,
-                lambda _: st.leaf_hist[jnp.clip(sl, 0, P - 1)], None)
-
-        def body(st: _GrowState) -> _GrowState:
-            with phase("grow/select"):
-                use_f = jnp.asarray(False)
-                si = jnp.asarray(0)
-                if n_forced:
-                    st, use_f, si = _apply_forced(
-                        st, scale3, meta,
-                        hist_of=_pool_hist_of if pool_on else None)
-                    leaf = jnp.where(
-                        use_f, st.forced_leaf[si],
-                        jnp.argmax(st.best_gain)).astype(jnp.int32)
-                else:
-                    leaf = jnp.argmax(st.best_gain).astype(jnp.int32)
-                node = st.num_leaves - 1
-                new_leaf = st.num_leaves
-                start = st.leaf_start[leaf]
-                cnt = st.leaf_rows[leaf]
-                pg, ph, pc = (st.leaf_sum_grad[leaf], st.leaf_sum_hess[leaf],
-                              st.leaf_count[leaf])
-                gl, hl, cl = (st.best_gl[leaf], st.best_hl[leaf],
-                              st.best_cl[leaf])
-                gr, hr, cr = pg - gl, ph - hl, pc - cl
-            if pool_on:
-                # Parent histogram BEFORE the partition reorders the
-                # segment: resident slot, or recompute-on-miss from the
-                # leaf's rows in their creation-time order.
-                with phase("grow/hist"):
-                    sp = st.leaf_slot[leaf]
-                    hist_parent = _pool_hist_of(st, leaf)
-
-            with phase("grow/partition"):
-                if faxis is not None:
-                    glv = _fp_go_left(
-                        bins_pad, nan_bins, st.best_feature[leaf],
-                        st.best_bin[leaf], st.best_default_left[leaf],
-                        st.best_is_cat[leaf], st.best_cat_mask[leaf],
-                        foffset, f, faxis)
-                    perm, nl_phys = jax.lax.switch(
-                        _bucket_of(cnt), part_branches, st.perm, start, cnt,
-                        glv)
-                else:
-                    perm, nl_phys = jax.lax.switch(
-                        _bucket_of(cnt), part_branches, st.perm, start, cnt,
-                        st.best_feature[leaf], st.best_bin[leaf],
-                        st.best_default_left[leaf], st.best_is_cat[leaf],
-                        st.best_cat_mask[leaf])
-            with phase("grow/select"):
-                # Histogram ONLY the physically smaller child's contiguous
-                # range (its own, usually much smaller, bucket) — the
-                # expensive op scales with the smaller sibling, exactly like
-                # the reference's serial learner; the sibling comes from
-                # parent-hist subtraction.  Under a mesh the small/large
-                # choice must be GLOBAL so every shard histograms the same
-                # side.
-                if axis is None:
-                    small_left = nl_phys <= cnt - nl_phys
-                else:
-                    nl_g = _psum(nl_phys, axis)
-                    cnt_g = _psum(cnt, axis)
-                    small_left = nl_g <= cnt_g - nl_g
-                hs_start = jnp.where(small_left, start, start + nl_phys)
-                hs_cnt = jnp.where(small_left, nl_phys, cnt - nl_phys)
-            with phase("grow/hist"):
-                hist_small = jax.lax.switch(
-                    _bucket_of(hs_cnt), hist_branches, perm, hs_start, hs_cnt)
-            if axis is not None:
-                # The reference's per-step histogram reduce: full psum
-                # (replicated scan) or feature-sliced reduce-scatter
-                # (slice-local scan + SplitInfo payload sync).
-                hist_small = (rs["scatter"](hist_small) if rs is not None
-                              else _psum(hist_small, axis))
-
-            with phase("grow/subtract"):
-                if not pool_on:
-                    hist_parent = st.leaf_hist[leaf]
-                hist_big = hist_parent - hist_small
-                hist_left = jnp.where(small_left, hist_small, hist_big)
-                hist_right = jnp.where(small_left, hist_big, hist_small)
-
-            slots2 = None
-            if pool_on:
-                # Claim a slot for the smaller child; the larger child
-                # lands in the parent's slot (or a second claim on a miss).
-                st, ss1, sb1 = pool_claim(st, sp[None],
-                                          jnp.ones(1, bool), (sp < 0)[None])
-                s_small, s_big = ss1[0], sb1[0]
-                slots2 = jnp.stack([jnp.where(small_left, s_small, s_big),
-                                    jnp.where(small_left, s_big, s_small)])
-                st = pool_assign(st, jnp.stack([leaf, new_leaf]), slots2)
-
-            with phase("grow/update"):
-                tree = _update_tree(st, leaf, new_leaf, node, pg, ph, pc)
-                st = st._replace(
-                    perm=perm,
-                    tree=tree,
-                    leaf_start=st.leaf_start.at[new_leaf].set(start + nl_phys),
-                    leaf_rows=st.leaf_rows.at[leaf].set(nl_phys)
-                                          .at[new_leaf].set(cnt - nl_phys),
-                )
-            st = _children_updates(st, leaf, new_leaf, hist_left,
-                                    hist_right, gl, hl, cl, gr, hr, cr,
-                                    meta, feature_mask, cegb, groups_mat,
-                                    scale3, sync=sync, fp_mono=fp_mono,
-                                    rs=rs, slots2=slots2)
-            if n_forced:
-                st = _record_forced_children(st, use_f, si, leaf, new_leaf)
-            if inter:
-                # Safe with forced splits: this overwrites best_* for ALL
-                # leaves, but _apply_forced re-pins the pending forced
-                # directive at the START of the next step, so a forced
-                # split is never lost (test_forced_splits_survive_
-                # intermediate_monotone).
-                st = _inter_refresh(st, scale3, meta, feature_mask, cegb,
-                                    groups_mat)
-            return st
-
-        def cond(st: _GrowState):
-            more = jnp.max(st.best_gain) > _NEG_INF
-            if n_forced:
-                more = more | (st.num_leaves - 1 < n_forced)
-            return (st.num_leaves < L) & more
-
-        state = jax.lax.while_loop(cond, body, state)
-        return _finish(state), _row_leaf_from_perm(state, n, max_bucket)
-
     # ------------------------------------------------------------------ wave path
-    def _grow_wave(bins, vals, scale3, feature_mask, meta, cegb=None,
-                   key=None, axis=None):
+    def _grow_wave(bins, vals, scale3, feature_mask, meta, plan, cegb=None,
+                   key=None, axis=None, faxis=None):
         """Wave growth (permutation layout): split the top-W leaves per step.
+        THE permutation-layout body: single device, per shard under
+        ``shard_map`` when ``axis`` names the mesh data axis, or
+        feature-sharded when ``faxis`` names the feature axis (rows
+        replicated, each shard histograms and scans only its own columns —
+        the reference FeatureParallelTreeLearner layout).  A wave of one
+        (``leaf_batch=1``) is the reference's sequential leaf-wise order,
+        and the only wave forced splits and ``faxis`` run in.
 
         Per wave: partition each chosen leaf's contiguous segment, histogram
         each SMALLER sibling's contiguous range, get the larger siblings by
@@ -2198,21 +1785,57 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         n, gcols = bins.shape
         f = meta[0].shape[0]
         W = min(cfg.leaf_batch, max(L - 1, 1))
-        voting = cfg.voting and axis is not None
+        assert W == 1 or not (n_forced or faxis), (W, n_forced, faxis)
+        voting = plan.reduce == "vote"
         nan_bins = meta[1]
         groups_mat = _groups_matrix(f) if use_groups else None
         rs = None
         hist_cols = f if cfg.packed4 else gcols
-        if axis is not None and rs_on:
+        if plan.reduce == "scatter":
             rs = _make_rs(axis, hist_cols, meta)
+        # slice-local scans (owned feature block / own feature columns)
+        # globalize their winners as one SplitInfo payload
+        sync = rs["sync"] if rs is not None else None
+        fp_mono = None
+        if faxis is not None:
+            foffset = jax.lax.axis_index(faxis) * f
+            sync = phase("grow/reduce")(
+                lambda bs: _fp_sync_best(bs, foffset, faxis, fp_shards))
+
+            def fp_mono(feat_g):
+                # constraint type of GLOBAL features: the owner shard
+                # broadcasts it (the local meta holds only owned features)
+                lf = feat_g - foffset
+                owns = (lf >= 0) & (lf < f)
+                m = jnp.where(owns, meta[3][jnp.clip(lf, 0, f - 1)], 0)
+                return _psum(m, faxis)
         P, pool_on, pool_claim, pool_assign, _reduce_hist = _pool_setup(
             rs["go"] if rs is not None else hist_cols, axis, rs)
         (state, bins_pad, vals_pad, buckets, buckets_arr,
          max_bucket) = _perm_setup(bins, vals, scale3, meta, feature_mask,
-                                   cegb, key, groups_mat, axis, rs, P)
+                                   cegb, key, groups_mat, axis, rs, P,
+                                   voting)
+        if faxis is not None:
+            # _perm_setup stored the LOCAL root best; globalize it
+            # (reference SyncUpGlobalBestSplit after the root scan).
+            zero = jnp.zeros((), jnp.float32)
+            bs0 = BestSplit(
+                gain=state.best_gain[0], feature=state.best_feature[0],
+                bin=state.best_bin[0],
+                default_left=state.best_default_left[0],
+                is_cat=state.best_is_cat[0],
+                cat_mask=state.best_cat_mask[0],
+                sum_grad_left=state.best_gl[0],
+                sum_hess_left=state.best_hl[0],
+                count_left=state.best_cl[0],
+                sum_grad_right=zero, sum_hess_right=zero, count_right=zero)
+            state = _store_best(state, jnp.asarray(0), sync(bs0),
+                                jnp.asarray(True))
 
-        part_branches = [_part_branch_for(bins_pad, nan_bins, S, meta)
-                         for S in buckets]
+        part_branches = ([_part_branch_for_gl(S) for S in buckets]
+                         if faxis is not None else
+                         [_part_branch_for(bins_pad, nan_bins, S, meta)
+                          for S in buckets])
         hist_branches = [_hist_branch_for(bins_pad, vals_pad, n, S,
                                           meta[0].shape[0])
                          for S in buckets]
@@ -2221,10 +1844,26 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             return jnp.clip(jnp.searchsorted(sizes, cnt, side="left"),
                             0, sizes.shape[0] - 1).astype(jnp.int32)
 
-        # ---- fused wave kernel (ops/pallas_wave.py): composition gate
-        # resolved in make_grower (wave_fused_req), shape gate here —
-        # trace-time statics, so degrade costs nothing.
-        use_fused = wave_fused_req and axis is None and not voting
+        def _pool_hist(st, sl, miss, start, cnt):
+            """Pool lookup with recompute-on-miss (reference
+            HistogramPool::Get returning false -> the learner reconstructs
+            the leaf's histogram from its rows): an evicted leaf's
+            histogram is rebuilt from its contiguous perm segment — whose
+            row order is untouched since the leaf was created, so a leaf
+            originally histogrammed directly recomputes bit-identically —
+            and re-reduced across shards exactly like the resident path."""
+            def rec(_):
+                h = jax.lax.switch(_bucket_of(cnt), hist_branches, st.perm,
+                                   start, cnt)
+                return _reduce_hist(h)
+
+            return jax.lax.cond(
+                miss, rec,
+                lambda _: st.leaf_hist[jnp.clip(sl, 0, P - 1)], None)
+
+        # ---- fused wave kernel (ops/pallas_wave.py): the plan's word, a
+        # trace-time static
+        use_fused = plan.fused
         if use_fused:
             from ..ops.pallas_common import C_PAD, interpret_mode
             from ..ops.pallas_wave import (fused_wave_call, hist_from_flat,
@@ -2235,8 +1874,6 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             wave_dtype = wave_dtype_for(cfg)
             _lay = wave_layout(f, HB, wave_dtype, cfg.rows_block,
                                cfg.packed4)
-            use_fused = _lay["fits"]
-        if use_fused:
             _w_order, _w_inv = plane_order(f, cfg.packed4)
             wave_meta_w = wave_meta(meta[0], meta[1], meta[2], feature_mask,
                                     features=f, num_bins=HB,
@@ -2318,11 +1955,24 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 return child[:, 0], child[:, 1], bs
 
         def body(st: _GrowState) -> _GrowState:
+            if n_forced:
+                st, use_f, si = _apply_forced(
+                    st, scale3, meta,
+                    hist_of=(lambda s, l: _pool_hist(
+                        s, s.leaf_slot[l], s.leaf_slot[l] < 0,
+                        s.leaf_start[l], s.leaf_rows[l]))
+                    if pool_on else None)
             with phase("grow/select"):
                 budget = L - st.num_leaves
                 top_g, top_l = jax.lax.top_k(st.best_gain, W)
                 slot = jnp.arange(W, dtype=jnp.int32)
                 active = (top_g > _NEG_INF) & (slot < budget)
+                if n_forced:
+                    # a pending forced split IS this wave of one
+                    top_l = jnp.where(use_f, st.forced_leaf[si],
+                                      top_l).astype(jnp.int32)
+                    top_g = st.best_gain[top_l]
+                    active = active | use_f
                 if inter:
                     # Conflict-free wave (per-wave bound recomputation): two
                     # leaves ORDERED by a monotone relation must not split in
@@ -2370,17 +2020,8 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                     missW = active & (spW < 0)
 
                     def parent_one(j, ph):
-                        def rec(_):
-                            h = jax.lax.switch(
-                                _bucket_of(cnts[j]), hist_branches, st.perm,
-                                starts[j], cnts[j])
-                            return _reduce_hist(h)
-
-                        h = jax.lax.cond(
-                            missW[j], rec,
-                            lambda _: st.leaf_hist[jnp.clip(spW[j], 0, P - 1)],
-                            None)
-                        return ph.at[j].set(h)
+                        return ph.at[j].set(_pool_hist(
+                            st, spW[j], missW[j], starts[j], cnts[j]))
 
                     parent_hist = jax.lax.fori_loop(
                         0, W, parent_one,
@@ -2388,8 +2029,18 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
 
             def part_one(j, carry):
                 perm, nls = carry
+                if faxis is not None:
+                    # the split column lives on one shard: its owner
+                    # broadcasts the go-left vector
+                    glv = _fp_go_left(
+                        bins_pad, nan_bins, feats[j], sbins[j], dlefts[j],
+                        scats[j], cmasks[j], foffset, f, faxis)
 
                 def do(p):
+                    if faxis is not None:
+                        return jax.lax.switch(
+                            _bucket_of(cnts[j]), part_branches, p,
+                            starts[j], cnts[j], glv)
                     return jax.lax.switch(
                         _bucket_of(cnts[j]), part_branches, p, starts[j],
                         cnts[j], feats[j], sbins[j], dlefts[j], scats[j],
@@ -2436,7 +2087,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 # histogram build + sibling subtract + split scan while
                 # the (C_PAD, F*B) accumulators stay VMEM-resident.  The
                 # monotone/voting/CEGB branches below are statically off
-                # on this path (wave_fused_for).
+                # on this path (the plan refuses to fuse them).
                 hist_left, hist_right, fused_bs = _fused_wave(
                     perm, small_start, small_cnt, small_left, parent_hist,
                     jnp.stack([gl, gr], 1), jnp.stack([hl, hr], 1),
@@ -2516,7 +2167,8 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                     plo, phi = st.leaf_lo[top_l], st.leaf_hi[top_l]
                     out_l = jnp.clip(out_l, plo, phi)
                     out_r = jnp.clip(out_r, plo, phi)
-                    mono_t = meta[3][feats]
+                    mono_t = (meta[3][feats] if fp_mono is None
+                              else fp_mono(feats))
                     is_num = ~scats
                     mid = (out_l + out_r) / 2.0
                     lo_l = jnp.where((mono_t < 0) & is_num,
@@ -2637,11 +2289,17 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                                     cr, child_path)
                         penalty2 = cat2(pen_l, pen_r)
 
+            if n_forced:
+                st = _record_forced_children(st, use_f, si, top_l[0],
+                                             newleaf_j[0])
             if inter:
-                # Per-wave bound + best-split refresh over ALL leaves — the
-                # wave analog of the sequential per-split refresh.  The 2W
-                # children's searches are part of the full rescan, so the
-                # dedicated children pass below is skipped.
+                # Per-wave bound + best-split refresh over ALL leaves.  The
+                # 2W children's searches are part of the full rescan, so
+                # the dedicated children pass below is skipped.  Safe with
+                # forced splits: this overwrites best_* for ALL leaves, but
+                # _apply_forced re-pins the pending forced directive at
+                # the START of the next step, so a forced split is never
+                # lost (test_forced_splits_survive_intermediate_monotone).
                 return _inter_refresh(st, scale3, meta, feature_mask, cegb,
                                       groups_mat)
 
@@ -2671,10 +2329,10 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                                      penalty2, cat2(out_l, out_r), node_key,
                                      path2, groups_mat, bounds2,
                                      cat2(depth, depth), rs=rs)
-                if rs is not None:
+                if sync is not None:
                     # All 2W slice-local winners globalize in one vmapped
                     # payload broadcast (SyncUpGlobalBestSplit).
-                    bs = rs["sync"](bs)
+                    bs = sync(bs)
             with phase("grow/update"):
                 if cfg.max_depth <= 0:
                     depth_ok = jnp.ones(2 * W, bool)
@@ -2701,26 +2359,24 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 )
 
         def cond(st: _GrowState):
-            return (st.num_leaves < L) & (jnp.max(st.best_gain) > _NEG_INF)
+            room = st.num_leaves < L
+            more = jnp.max(st.best_gain) > _NEG_INF
+            if n_forced:
+                more = more | (st.num_leaves - 1 < n_forced)
+            return room & more
 
         state = jax.lax.while_loop(cond, body, state)
         return _finish(state), _row_leaf_from_perm(state, n, max_bucket)
 
     # ------------------------------------------------------------------ mask path
-    def _grow_mask(bins, vals, scale3, feature_mask, meta, cegb=None,
+    def _grow_mask(bins, vals, scale3, feature_mask, meta, plan, cegb=None,
                    key=None):
-        """Mask-layout growth (sharding-friendly; full-N pass per split)."""
+        """Mask-layout growth (sharding-friendly; full-N pass per split).
+        Under a mesh it runs on GSPMD-sharded operands OUTSIDE shard_map,
+        which is why the plan names a partitionable histogram there."""
         n, gcols = bins.shape
         f = meta[0].shape[0]
         groups_mat = _groups_matrix(f) if use_groups else None
-        # Under a mesh this path runs on GSPMD-sharded operands OUTSIDE
-        # shard_map; the pallas kernel is per-device-only, so route 'auto'
-        # to the partitionable einsum/scatter impls.
-        mask_impl = cfg.histogram_impl
-        if mesh is not None and mask_impl in ("auto", "pallas", "flat",
-                                              "flat_bf16"):
-            mask_impl = ("onehot" if jax.default_backend() == "tpu"
-                         else "segment")
 
         @phase("grow/hist")
         def hist_for(mask):
@@ -2730,12 +2386,12 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             masked = jnp.where(mask[:, None], vals, jnp.zeros_like(vals))
             return histogram_from_vals(
                 bins, masked, num_bins=HB,
-                impl=mask_impl, rows_block=cfg.rows_block)
+                impl=plan.hist_impl, rows_block=cfg.rows_block)
 
         with phase("grow/setup"):
             nan_bins = meta[1]
             root_hist = histogram_from_vals(
-                bins, vals, num_bins=HB, impl=mask_impl,
+                bins, vals, num_bins=HB, impl=plan.hist_impl,
                 rows_block=cfg.rows_block)
             root_tot = jnp.sum(_scale_hist(root_hist[0:1], scale3)[0], axis=0)
             root_g, root_h, root_c = root_tot[0], root_tot[1], root_tot[2]
@@ -2827,7 +2483,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         return _finish(state), row_leaf
 
     # ----------------------------------------------------- feature-parallel path
-    def _grow_fp(bins, vals, scale3, feature_mask, meta, split_key):
+    def _grow_fp(bins, vals, scale3, feature_mask, meta, plan, split_key):
         """Feature-parallel perm layout (reference
         ``FeatureParallelTreeLearner``, feature_parallel_tree_learner.cpp):
         rows replicated, feature columns sharded.  Each shard histograms and
@@ -2875,9 +2531,9 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 i += 1
             if have_key:
                 sk = extra[i]
-            return _grow_perm(bins_l, vals_r, s3, fm_l,
-                              (nb_l, na_l, ic_l, mo_l), None, sk,
-                              axis=None, faxis=fp_axis_name, fp_shards=S)
+            return _grow_wave(bins_l, vals_r, s3, fm_l,
+                              (nb_l, na_l, ic_l, mo_l), plan, None, sk,
+                              faxis=fp_axis_name)
 
         return jax.shard_map(
             body, mesh=mesh,
@@ -2889,9 +2545,9 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                              *extras)
 
     # -------------------------------------------------------------- sharded path
-    def _grow_sharded(bins, vals, scale3, feature_mask, meta, cegb,
+    def _grow_sharded(bins, vals, scale3, feature_mask, meta, plan, cegb,
                       split_key):
-        """Run the permutation/wave grower per-shard under ``shard_map``:
+        """Run the wave grower per-shard under ``shard_map``:
         local partitions + local histograms, ONE cross-shard histogram
         reduction per wave (the reference's histogram reduce,
         ``data_parallel_tree_learner.cpp:284``) — a feature-sliced
@@ -2902,8 +2558,6 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         while_loop stays in lockstep."""
         from jax.sharding import PartitionSpec as P
 
-        grow_fn = (_grow_wave if (cfg.leaf_batch > 1 or cfg.voting)
-                   else _grow_perm)
         have_scale = scale3 is not None
         have_cegb = cegb is not None
         have_key = split_key is not None
@@ -2933,7 +2587,8 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 i += 2
             if have_key:
                 sk = extra[i]
-            return grow_fn(bins, vals, s3, fmask, m, cg, sk, axis=data_axis)
+            return _grow_wave(bins, vals, s3, fmask, m, plan, cg, sk,
+                              axis=data_axis)
 
         return jax.shard_map(
             body, mesh=mesh,
@@ -3020,35 +2675,33 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             if bins.shape[0] != vals.shape[0]:
                 vals = jnp.pad(
                     vals, ((0, bins.shape[0] - vals.shape[0]), (0, 0)))
-        use_sharded = (mesh is not None and cfg.gather_rows
-                       and bins.shape[0] // dshards > _MIN_BUCKET)
-        if fp_capable and bins.shape[1] != meta[0].shape[0] \
-                and bins.shape[0] <= _MIN_BUCKET:
-            # caller pre-padded feature columns for the fp layout but the
-            # row count routes to the mask fallback, which must see the
-            # metadata's width (pad columns are all-zero)
-            bins = bins[:, : meta[0].shape[0]]
-        if fp_capable and bins.shape[0] > _MIN_BUCKET:
+        # The ONE routing chain: the plan, completed for the shapes this
+        # program is traced at (the row floor and the kernel's width gate).
+        nfeat = meta[0].shape[0]
+        plan = plan_growth(cfg, mesh, data_axis, rows=bins.shape[0],
+                           features=nfeat)
+        if plan.layout == "feature":
             tree, row_leaf = _grow_fp(bins, vals, scale3, feature_mask,
-                                      meta, split_key)
-        elif use_sharded:
+                                      meta, plan, split_key)
+        elif plan.layout == "data":
             tree, row_leaf = _grow_sharded(bins, vals, scale3, feature_mask,
-                                           meta, cegb, split_key)
-        elif (mesh is None and cfg.gather_rows
-                and bins.shape[0] > _MIN_BUCKET):
-            # The fused wave kernel lives in _grow_wave; a fused-capable
-            # config routes through it even at leaf_batch=1 (a wave of 1).
-            grow_fn = (_grow_wave if (cfg.leaf_batch > 1 or wave_fused_req)
-                       else _grow_perm)
-            tree, row_leaf = grow_fn(bins, vals, scale3, feature_mask,
-                                     meta, cegb, split_key)
+                                           meta, plan, cegb, split_key)
+        elif plan.body == "wave":
+            tree, row_leaf = _grow_wave(bins, vals, scale3, feature_mask,
+                                        meta, plan, cegb, split_key)
         else:
             if cfg.packed4:
-                # the mask fallback (tiny row counts / no-gather) indexes
+                # the mask body (tiny row counts / no-gather) indexes
                 # full columns; unpack once — small data, small cost
-                bins = unpack_bins4(bins, meta[0].shape[0])
+                bins = unpack_bins4(bins, nfeat)
+            elif static.layout == "feature":
+                # the caller may have padded feature columns for the
+                # feature-sharded layout, which the row count then
+                # refused: the mask body must see the metadata's width
+                # (pad columns are all-zero)
+                bins = bins[:, :nfeat]
             tree, row_leaf = _grow_mask(bins, vals, scale3, feature_mask,
-                                        meta, cegb, split_key)
+                                        meta, plan, cegb, split_key)
         with phase("grow/finish"):
             row_leaf = row_leaf[:n]
             if cfg.quantized and cfg.quant_renew_leaf:
@@ -3078,11 +2731,11 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
     # for fp32 whenever the sums are exactly representable (the same
     # caveat as the histogram pool and fused wave kernel carry).
     def _make_stream_kit(num_features: int):
-        reason = stream_unsupported_reason(cfg, mesh)
-        if reason is not None:
-            raise ValueError(f"streaming growth unsupported: {reason}")
+        if static.stream_reason is not None:
+            raise ValueError("streaming growth unsupported: "
+                             + static.stream_reason)
         f = int(num_features)
-        hist_kw = dict(num_bins=HB, impl=cfg.histogram_impl,
+        hist_kw = dict(num_bins=HB, impl=static.hist_impl,
                        rows_block=cfg.rows_block, packed4=cfg.packed4,
                        features=f if cfg.packed4 else 0)
 
@@ -3238,15 +2891,10 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
     from ..telemetry import instrument
     grow = instrument(jax.jit(_grow_impl, donate_argnums=()), "grower/grow",
                       track_memory=True)
-    # static dispatch facts, inspectable by tests/tools
-    grow.fp_capable = fp_capable
-    grow.rs_active = rs_on
-    grow.pool_capable = pool_capable
+    # the static half of the growth plan (shape gates open), inspectable
+    # by tests/tools; GBDT.plan is the one completed for its data
+    grow.plan = static
     grow.pool_slots = _pool_slots
-    # Composition-level fused-wave gate (tpu_wave_kernel); the full answer
-    # ANDs the shape-level pallas_wave.wave_layout_fits (GBDT reports it
-    # as wave_fused_active, the same predicate _grow_wave traces with).
-    grow.wave_fused = wave_fused_req
     # Scan-able handle: the iteration-packed path traces grow INSIDE a
     # lax.scan body that is already under jit; the raw function skips the
     # redundant inner-jit trace (semantics identical — nested jit inlines).
@@ -3254,5 +2902,4 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
     # Streaming grow kit factory (lightgbm_tpu/stream/): chunked twin of
     # the mask-layout body, sharing its state/update/scan functions.
     grow.stream_kit = _make_stream_kit
-    grow.stream_reason = stream_unsupported_reason(cfg, mesh)
     return grow

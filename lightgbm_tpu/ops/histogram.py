@@ -169,15 +169,13 @@ def histogram_from_vals(
     ones; the pallas kernel reduces per-chunk then adds the seed (integer
     quantized histograms stay exact either way)."""
     impl = resolve_impl(impl)
-    if impl in ("pallas", "flat", "flat_bf16"):
+    if impl == "pallas":
         from .pallas_common import interpret_mode
         from .pallas_histogram import histogram_flat, kernel_layout
-        if jnp.issubdtype(vals.dtype, jnp.integer):
-            # Quantized histograms: s8 x s8 -> s32 on the MXU's double-rate
-            # int8 path (reference Int32HistogramSumReducer, bin.h:48-81).
-            dtype = "int8"
-        else:
-            dtype = "bf16" if impl == "flat_bf16" else "f32"
+        # Quantized histograms: s8 x s8 -> s32 on the MXU's double-rate
+        # int8 path (reference Int32HistogramSumReducer, bin.h:48-81).
+        dtype = ("int8" if jnp.issubdtype(vals.dtype, jnp.integer)
+                 else "f32")
         # the launch's last scope segments: the feature columns and the
         # rows ONE kernel launch is handed — a histogram wider than the
         # layout's column tile is several launches under this one path

@@ -159,28 +159,11 @@ def wave_layout(features: int, num_bins: int, dtype: str,
     }
 
 
-def wave_layout_fits(features: int, num_bins: int, dtype: str,
-                     rows_block: int = 0, packed4: bool = False) -> bool:
-    return wave_layout(features, num_bins, dtype, rows_block, packed4)["fits"]
-
-
 def wave_dtype_for(cfg) -> str:
-    """The fused kernel's one-hot dtype for a GrowerConfig-like ``cfg`` —
-    the ONE resolution shared by the grower's trace-time gate and GBDT's
-    ``wave_fused_active`` reporting, so the two cannot drift apart."""
-    if cfg.quantized:
-        return "int8"
-    return "bf16" if cfg.histogram_impl == "flat_bf16" else "f32"
-
-
-def wave_fits_for(cfg, features: int) -> bool:
-    """Shape gate for a GrowerConfig-like ``cfg`` at ``features`` columns
-    (duck-typed: quantized / histogram_impl / hist_bins / num_bins /
-    rows_block / packed4) — exactly what ``_grow_wave`` evaluates at trace
-    time."""
-    return wave_layout_fits(features, cfg.hist_bins or cfg.num_bins,
-                            wave_dtype_for(cfg), cfg.rows_block,
-                            cfg.packed4)
+    """The fused kernel's operand dtype for a GrowerConfig-like ``cfg`` —
+    the ONE resolution shared by the growth plan's width gate and the
+    kernel's launch site."""
+    return "int8" if cfg.quantized else "f32"
 
 
 def wave_meta(num_bins_per_feature, nan_bins, is_categorical, feature_mask,
@@ -421,7 +404,7 @@ def fused_wave_call(
         raise ValueError(
             f"fused wave needs the single-chunk layout: got {ct} bin "
             f"columns / parent width {parent_flat.shape[-1]} vs layout "
-            f"({cols_tile}, {ftile * b_pad}); check wave_layout_fits")
+            f"({cols_tile}, {ftile * b_pad}); check wave_layout")
     if t % blk or blk_slot.shape != (t // blk,):
         raise ValueError(
             f"fused wave needs whole row blocks and one slot per block: "

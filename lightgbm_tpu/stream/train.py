@@ -14,7 +14,7 @@ chunk's accumulator (``histogram_from_vals(init=...)``) so the add
 sequence replays the in-core one — streamed trees are bitwise-identical
 to in-core trees (pinned across fp32/quantized/packed4 x iter-pack x
 GOSS in tests/test_stream.py; on TPU's blockwise pallas histogram the
-fp32 guarantee needs chunk rows aligned to ``tpu_rows_block``, while
+fp32 guarantee needs chunk rows aligned to the kernel's row block, while
 quantized integer histograms are unconditionally exact).
 
 Gradient-based residency (``tpu_stream_residency=goss``, the
@@ -146,7 +146,7 @@ class StreamDataset:
 def stream_degrade_reason(gbdt) -> Optional[str]:
     """Why this booster cannot train streamed (None = capable) — the
     stream twin of ``iter_pack_degrade_reason``, one enumerable list."""
-    reason = getattr(gbdt.grow, "stream_reason", "no stream kit")
+    reason = gbdt.plan.stream_reason
     if reason is not None:
         return reason
     if gbdt.cfg.boosting != "gbdt":

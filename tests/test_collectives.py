@@ -140,7 +140,7 @@ def test_comm_backend_reaches_grower_reduce_scatter(mesh):
                           split=_split_config(cfg), leaf_batch=2,
                           hist_comm="reduce_scatter")
     grow = G.make_grower(gcfg, mesh=mesh, data_axis=DATA_AXIS)
-    assert grow.rs_active
+    assert grow.plan.reduce == "scatter"
     tree_ref, rl_ref = grow(*args)
 
     calls = []

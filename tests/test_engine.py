@@ -372,7 +372,7 @@ def test_mosaic_compile_failure_raises_and_names_optouts(monkeypatch):
     monkeypatch.setattr(pallas_histogram, "histogram_flat", boom)
     X, y = make_regression(n_samples=600, n_features=6, noise=0.1,
                            random_state=3)
-    for impl in ("pallas", "flat"):
+    for impl in ("pallas",):
         with pytest.raises(RuntimeError) as ei:
             lgb.train({"objective": "regression", "verbosity": -1,
                        "num_leaves": 15, "tpu_histogram_impl": impl},

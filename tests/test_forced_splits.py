@@ -11,20 +11,39 @@ from sklearn.datasets import make_classification
 import lightgbm_tpu as lgb
 
 
-def test_forced_root_and_nested_child(tmp_path):
-    X, y = make_classification(n_samples=2000, n_features=8, n_informative=4,
-                               random_state=0)
+@pytest.mark.parametrize("n_samples,extra,body", [
+    # 2 000 rows: the mask body
+    pytest.param(2000, {}, "mask", id="mask"),
+    # above the perm layouts' floor: the wave of one; a 3-slot histogram
+    # pool evicts the root's left child before its own forced split comes
+    # up, so _apply_forced reads it through the wave's pooled parent
+    # lookup (recompute on a miss)
+    pytest.param(6000, {"histogram_pool_size": 0.001}, "wave",
+                 id="wave-of-one-pooled"),
+])
+def test_forced_root_and_nested_child(tmp_path, n_samples, extra, body):
+    X, y = make_classification(n_samples=n_samples, n_features=8,
+                               n_informative=4, random_state=0)
     spec = {
         "feature": 5, "threshold": 0.25,
-        "left": {"feature": 3, "threshold": -0.5},
+        "left": {"feature": 3, "threshold": -0.5,
+                 "left": {"feature": 1, "threshold": 0.0}},
+        "right": {"feature": 0, "threshold": 0.1},
     }
     path = tmp_path / "forced.json"
     path.write_text(json.dumps(spec))
-    bst = lgb.train({"objective": "binary", "num_leaves": 15,
-                     "min_data_in_leaf": 5, "verbosity": -1,
-                     "forcedsplits_filename": str(path)},
+    bst = lgb.train(dict({"objective": "binary", "num_leaves": 15,
+                          "min_data_in_leaf": 5, "verbosity": -1,
+                          "forcedsplits_filename": str(path)}, **extra),
                     lgb.Dataset(X, label=y), 4)
+    plan = bst._gbdt.plan
+    assert plan.body == body and plan.pool is bool(extra), str(plan)
+    if extra:
+        assert bst._gbdt.grow.pool_slots(8) == 3
     for tree in bst._gbdt.models[0]:
+        # BFS order: root, its left, its right, the left's left
+        assert list(tree.split_feature[:4]) == [5, 3, 0, 1]
+        assert tree.left_child[1] == 3
         # node 0 = forced root; node 1 = forced split of its LEFT child
         assert tree.split_feature[0] == 5
         assert tree.split_feature[1] == 3

@@ -79,7 +79,7 @@ def test_pool_bitwise_serial_and_wave(grow_args, leaf_batch, slots):
     g0 = G.make_grower(base)
     g1 = G.make_grower(dataclasses.replace(
         base, histogram_pool_size=slots * slot_mb))
-    assert not g0.pool_capable and g1.pool_capable
+    assert not g0.plan.pool and g1.plan.pool
     assert g1.pool_slots(12) < base.num_leaves
     t0, rl0 = g0(*args)
     t1, rl1 = g1(*args)
@@ -102,7 +102,8 @@ def test_pool_bitwise_sharded_reduce_scatter(grow_args, quantized):
     g1 = G.make_grower(
         dataclasses.replace(base, histogram_pool_size=10 * slot_mb),
         mesh=mesh, data_axis=DATA_AXIS)
-    assert g0.rs_active and g1.rs_active and g1.pool_capable
+    assert (g0.plan.reduce == g1.plan.reduce == "scatter"
+            and g1.plan.pool)
     t0, rl0 = g0(*args)
     t1, rl1 = g1(*args)
     _assert_same_tree(t0, t1, rl0, rl1)
@@ -129,7 +130,7 @@ def test_pool_bitwise_booster_packed4_and_efb_quantized():
     b1 = lgb.train(dict(p4, histogram_pool_size=0.005),
                    lgb.Dataset(X, label=y), 3)
     assert b0._gbdt.grower_cfg.packed4
-    assert b1._gbdt.grow.pool_capable
+    assert b1._gbdt.plan.pool
     np.testing.assert_array_equal(b0.predict(X, raw_score=True),
                                   b1.predict(X, raw_score=True))
     # EFB
@@ -139,7 +140,7 @@ def test_pool_bitwise_booster_packed4_and_efb_quantized():
                    lgb.Dataset(Xe, label=ye), 3)
     e1 = lgb.train(dict(base, enable_bundle=True, histogram_pool_size=0.02),
                    lgb.Dataset(Xe, label=ye), 3)
-    assert e0._gbdt.bundles is not None and e1._gbdt.grow.pool_capable
+    assert e0._gbdt.bundles is not None and e1._gbdt.plan.pool
     np.testing.assert_array_equal(e0.predict(Xe, raw_score=True),
                                   e1.predict(Xe, raw_score=True))
 
@@ -162,7 +163,7 @@ def test_pool_forced_splits_recompute_on_miss():
         f0 = lgb.train(p, lgb.Dataset(X, label=y), 3)
         f1 = lgb.train(dict(p, histogram_pool_size=0.004),
                        lgb.Dataset(X, label=y), 3)
-        assert f1._gbdt.grow.pool_capable
+        assert f1._gbdt.plan.pool
         np.testing.assert_array_equal(f0.predict(X, raw_score=True),
                                       f1.predict(X, raw_score=True))
     finally:
@@ -173,7 +174,11 @@ def test_pool_slots_clamp_and_predicate():
     """MB -> slot arithmetic and the composition predicate: the frontier
     floor (2W+1) and the L cap clamp the user knob; -1 and the excluded
     compositions (mask layout, voting, monotone refresh) keep the full
-    carry; pool_active_for is the ONE shared gate."""
+    carry; the growth plan's ``pool`` is the ONE shared gate."""
+    from lightgbm_tpu.models.capabilities import plan_growth
+
+    def pool(c):
+        return plan_growth(c, None, rows=None, features=None).pool
     split = G.SplitConfig()
     base = G.GrowerConfig(num_leaves=255, num_bins=256, split=split,
                           leaf_batch=16, histogram_pool_size=1.0)
@@ -185,15 +190,14 @@ def test_pool_slots_clamp_and_predicate():
     assert big.pool_slots(28) == 255          # cap at L == unpooled carry
     off = G.make_grower(dataclasses.replace(base,
                                             histogram_pool_size=-1.0))
-    assert not off.pool_capable
+    assert not off.plan.pool
     # excluded compositions keep full residency
-    assert not G.pool_active_for(dataclasses.replace(
-        base, gather_rows=False))
-    assert not G.pool_active_for(dataclasses.replace(base, voting=True))
-    assert not G.pool_active_for(dataclasses.replace(
+    assert not pool(dataclasses.replace(base, gather_rows=False))
+    assert not pool(dataclasses.replace(base, voting=True))
+    assert not pool(dataclasses.replace(
         base, mono_intermediate=True,
         split=dataclasses.replace(split, has_monotone=True)))
-    assert G.pool_active_for(base)
+    assert pool(base)
 
 
 def test_pool_knob_warns_only_when_inert(capsys):

@@ -166,7 +166,7 @@ def test_growth_carry_bytes_bounded_wide_pool():
     grow = G.make_grower(gcfg)
     P = grow.pool_slots(FW)
     unpooled_bytes = LW * FW * B * 3 * 4
-    assert grow.pool_capable
+    assert grow.plan.pool
     assert P * FW * B * 3 * 4 <= unpooled_bytes // 4, (P, LW)
     rng = np.random.RandomState(0)
     bins = jnp.asarray(rng.randint(0, B, (NW, FW)).astype(np.uint8))
@@ -312,7 +312,7 @@ def wave_pair():
         gcfg = G.GrowerConfig(num_leaves=LW, num_bins=BW, split=scfg,
                               leaf_batch=WW, wave_kernel=mode)
         grow = G.make_grower(gcfg)
-        assert grow.wave_fused == (mode == "fused")
+        assert grow.plan.fused == (mode == "fused")
         return grow.lower(*args).compile().as_text()
 
     return {"fused": compile_txt("fused"), "unfused": compile_txt("unfused")}

@@ -35,15 +35,34 @@ def test_mesh_construction():
     assert mesh_for_tree_learner("feature").devices.shape == (1, 8)
 
 
-@pytest.mark.parametrize("tree_learner", ["data", "feature"])
-def test_sharded_training_matches_serial(tree_learner):
-    X, y = _data()
-    params = {"objective": "binary", "num_leaves": 15, "min_data_in_leaf": 5,
-              "metric": "auc", "verbosity": -1}
+@pytest.mark.parametrize("tree_learner,n,extra,layout", [
+    pytest.param("data", 2000, {}, "gspmd", id="data"),
+    pytest.param("feature", 2000, {}, "gspmd", id="feature"),
+    # above the perm layouts' floor a feature-only mesh runs the
+    # feature-sharded wave of one; with a monotone constraint the owner
+    # shard broadcasts the split feature's constraint (fp_mono in the wave)
+    pytest.param("feature", 6000,
+                 {"monotone_constraints": [(j in (4, 13)) - (j in (6, 12))
+                                           for j in range(16)]}, "feature",
+                 id="feature-wave-monotone"),
+])
+def test_sharded_training_matches_serial(tree_learner, n, extra, layout):
+    X, y = _data(n=n)
+    params = dict({"objective": "binary", "num_leaves": 15,
+                   "min_data_in_leaf": 5, "metric": "auc", "verbosity": -1},
+                  **extra)
     serial = lgb.train(dict(params, tree_learner="serial"),
                        lgb.Dataset(X, label=y), 10)
     sharded = lgb.train(dict(params, tree_learner=tree_learner),
                         lgb.Dataset(X, label=y), 10)
+    assert sharded._gbdt.plan.layout == layout, str(sharded._gbdt.plan)
+    if extra:
+        # the first tree makes the serial wave of one's choices, constraint
+        # and all (later trees may part at a near-tie: f32 sum order)
+        t_s, t_f = serial._gbdt.models[0][0], sharded._gbdt.models[0][0]
+        np.testing.assert_array_equal(t_s.split_feature, t_f.split_feature)
+        np.testing.assert_array_equal(t_s.split_bin, t_f.split_bin)
+        assert set(t_f.split_feature) & {4, 6, 12, 13}  # constrained splits
     ps = serial.predict(X, raw_score=True)
     pp = sharded.predict(X, raw_score=True)
     # Same algorithm, same data — differences only from f32 reduction order.
@@ -223,7 +242,7 @@ def test_feature_parallel_perm_exact_parity():
             meta["nan_bins"], meta["is_categorical"], meta["monotone"])
     tree_s, rl_s = G.make_grower(gcfg)(*args)
     grow_f = G.make_grower(gcfg, mesh=make_mesh(1, 8), data_axis=DATA_AXIS)
-    assert grow_f.fp_capable           # routed to the perm layout, not mask
+    assert grow_f.plan.layout == "feature"   # the perm layout, not mask
     tree_f, rl_f = grow_f(*args)
     assert int(tree_s.num_leaves) == int(tree_f.num_leaves) == 255
     np.testing.assert_array_equal(np.asarray(tree_s.split_feature),
@@ -254,7 +273,7 @@ def test_feature_parallel_composition_fallback():
     base = dict(num_leaves=15, num_bins=64, split=_split_config(cfg))
     mesh = make_mesh(1, 8)
     assert G.make_grower(G.GrowerConfig(**base), mesh=mesh,
-                         data_axis=DATA_AXIS).fp_capable
+                         data_axis=DATA_AXIS).plan.layout == "feature"
     sp = base["split"]
     for bad in (dict(interaction_groups=((0, 1), (2, 3))),
                 dict(bundled=True, hist_bins=64),
@@ -267,12 +286,12 @@ def test_feature_parallel_composition_fallback():
                      split=dataclasses.replace(sp, has_monotone=True))):
         g = G.make_grower(G.GrowerConfig(**dict(base, **bad)), mesh=mesh,
                           data_axis=DATA_AXIS)
-        assert not g.fp_capable, bad
+        assert g.plan.layout != "feature" and "feature" in g.plan.why, bad
     # basic monotone stays ON the fp path
     g = G.make_grower(G.GrowerConfig(**dict(
         base, split=dataclasses.replace(sp, has_monotone=True))),
         mesh=mesh, data_axis=DATA_AXIS)
-    assert g.fp_capable
+    assert g.plan.layout == "feature"
 
 
 def test_sharded_training_metric_parity():
@@ -425,7 +444,7 @@ def test_hist_comm_reduce_scatter_matches_allreduce(quantized):
     g_rs = G.make_grower(
         dataclasses.replace(base, hist_comm="reduce_scatter"),
         mesh=mesh, data_axis=DATA_AXIS)
-    assert g_rs.rs_active and not g_ar.rs_active
+    assert (g_rs.plan.reduce, g_ar.plan.reduce) == ("scatter", "psum")
     t_ar, rl_ar = g_ar(*args)
     t_rs, rl_rs = g_rs(*args)
     assert int(t_ar.num_leaves) == int(t_rs.num_leaves) == 31
@@ -453,7 +472,8 @@ def test_hist_comm_reduce_scatter_matches_allreduce_efb():
     b_rs = lgb.train(dict(base, tpu_hist_comm="reduce_scatter"),
                      lgb.Dataset(X, label=y), 3)
     assert b_ar._gbdt.bundles is not None
-    assert b_rs._gbdt.grow.rs_active and not b_ar._gbdt.grow.rs_active
+    assert (b_rs._gbdt.plan.reduce, b_ar._gbdt.plan.reduce) \
+        == ("scatter", "psum")
     # identical model files up to the serialized knob value itself
     strip = lambda s: "\n".join(ln for ln in s.splitlines()
                                 if not ln.startswith("[tpu_hist_comm:"))
@@ -479,7 +499,7 @@ def test_hist_comm_fallbacks_warn():
                 hist_comm="reduce_scatter")
     mesh = make_mesh(8, 1)
     assert G.make_grower(G.GrowerConfig(**base), mesh=mesh,
-                         data_axis=DATA_AXIS).rs_active
+                         data_axis=DATA_AXIS).plan.reduce == "scatter"
     for bad in (dict(voting=True),
                 dict(forced_splits=((0, 1, -1, -1),)),
                 dict(mono_intermediate=True,
@@ -489,18 +509,20 @@ def test_hist_comm_fallbacks_warn():
                     sp, feature_contri=(0.5,) * 8))):
         g = G.make_grower(G.GrowerConfig(**dict(base, **bad)), mesh=mesh,
                           data_axis=DATA_AXIS)
-        assert not g.rs_active, bad
+        assert g.plan.reduce in ("psum", "vote"), bad
+        assert "scatter" in g.plan.why, bad
     # ... but the EFB slice scans full-F under an ownership mask, so
     # feature_contri composes there (predicate only: building a bundled
     # grower needs bundle metadata)
-    assert G.rs_active_for(
+    from lightgbm_tpu.models.capabilities import plan_growth
+    assert plan_growth(
         G.GrowerConfig(**dict(base, bundled=True,
                               split=dataclasses.replace(
                                   sp, feature_contri=(0.5,) * 8))),
-        mesh, DATA_AXIS)
+        mesh, DATA_AXIS, rows=None, features=None).reduce == "scatter"
     # feature-only meshes never reduce-scatter (rows are replicated there)
-    assert not G.make_grower(G.GrowerConfig(**base), mesh=make_mesh(1, 8),
-                             data_axis=DATA_AXIS).rs_active
+    assert G.make_grower(G.GrowerConfig(**base), mesh=make_mesh(1, 8),
+                         data_axis=DATA_AXIS).plan.reduce == "none"
     with pytest.raises(ValueError, match="hist_comm"):
         G.make_grower(G.GrowerConfig(**dict(base, hist_comm="bogus")),
                       mesh=mesh, data_axis=DATA_AXIS)

@@ -77,7 +77,7 @@ def _pair(base, args, **kw):
     gu = G.make_grower(dataclasses.replace(base, wave_kernel="unfused",
                                            **kw))
     gf = G.make_grower(dataclasses.replace(base, wave_kernel="fused", **kw))
-    assert not gu.wave_fused and gf.wave_fused
+    assert not gu.plan.fused and gf.plan.fused
     return gu(*args), gf(*args)
 
 
@@ -115,7 +115,7 @@ def test_fused_bitwise_pooled(grown, quantized):
     gf = G.make_grower(dataclasses.replace(
         base, wave_kernel="fused", leaf_batch=4,
         histogram_pool_size=10.5 * slot_mb))
-    assert gf.pool_capable and gf.pool_slots(f) < base.num_leaves
+    assert gf.plan.pool and gf.pool_slots(f) < base.num_leaves
     _assert_same_tree(t0, t1, rl0, rl1)
 
 
@@ -498,23 +498,26 @@ def test_wave_layout_legal_and_budgeted():
 
 
 def test_capability_predicate_and_knob():
-    """wave_fused_for: the composition gate (shared with GBDT and the
-    census) — excluded axes degrade, explicit fused forces on CPU, auto
-    engages only where the flat pallas impl is live."""
+    """The growth plan's ``fused`` at open shape gates: the composition
+    gate — excluded axes degrade, explicit fused forces on CPU, auto
+    engages only where the pallas histogram is live."""
+    from lightgbm_tpu.models.capabilities import plan_growth
     from lightgbm_tpu.ops.split import SplitConfig
+
+    def fused(c):
+        return plan_growth(c, None, rows=None, features=None).fused
 
     plain = SplitConfig(has_nan=True, has_categorical=False,
                         use_sorted_categorical=False, has_monotone=False)
     base = G.GrowerConfig(num_leaves=15, num_bins=64, split=plain,
                           leaf_batch=4)
     rep = dataclasses.replace
-    assert G.wave_fused_for(rep(base, wave_kernel="fused"))
+    assert fused(rep(base, wave_kernel="fused"))
     # auto on a CPU backend (resolve_impl -> segment): stays unfused
-    assert not G.wave_fused_for(rep(base, wave_kernel="auto"))
-    # ... but auto with the flat pallas impl engages
-    assert G.wave_fused_for(rep(base, wave_kernel="auto",
-                                histogram_impl="flat"))
-    assert not G.wave_fused_for(rep(base, wave_kernel="unfused"))
+    assert not fused(rep(base, wave_kernel="auto"))
+    # ... but auto with the pallas impl engages
+    assert fused(rep(base, wave_kernel="auto", histogram_impl="pallas"))
+    assert not fused(rep(base, wave_kernel="unfused"))
     for bad in (
         rep(base, wave_kernel="fused", voting=True),
         rep(base, wave_kernel="fused", bundled=True),
@@ -534,9 +537,9 @@ def test_capability_predicate_and_knob():
             split=rep(plain, has_categorical=True,
                       use_sorted_categorical=True)),
     ):
-        assert not G.wave_fused_for(bad), bad
+        assert not fused(bad), bad
     with pytest.raises(ValueError, match="wave_kernel"):
-        G.wave_fused_for(rep(base, wave_kernel="bogus"))
+        fused(rep(base, wave_kernel="bogus"))
     with pytest.raises(ValueError, match="tpu_wave_kernel"):
         lgb.train({"objective": "binary", "tpu_wave_kernel": "bogus",
                    "verbosity": -1},

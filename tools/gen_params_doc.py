@@ -32,10 +32,9 @@ _DESCRIPTIONS = {
         "EXPLICIT tpu|gpu|cuda that resolves to the cpu backend is an "
         "error"),
     "tpu_histogram_impl": (
-        "histogram kernel: auto|pallas|flat_bf16|onehot|segment (auto = "
+        "histogram kernel: auto|pallas|onehot|segment (auto = "
         "pallas on TPU, segment elsewhere; a kernel that fails to compile "
         "raises — onehot is the explicit XLA opt-out, never a fallback)"),
-    "tpu_rows_block": "rows per histogram-kernel block",
     "tpu_4bit_bins": (
         "auto 4-bit bin packing when every feature fits 16 bins "
         "(reference DenseBin IS_4BIT): resident bin matrix and per-leaf "
@@ -57,7 +56,7 @@ _DESCRIPTIONS = {
         "docs/PERF.md round 9).  auto = fused only where the "
         "capability checks pass (no mesh/voting/EFB/monotone/"
         "sorted-categorical/CEGB/per-node randomness, feature space fits "
-        "one VMEM block) AND the flat pallas histogram is the live impl "
+        "one VMEM block) AND the pallas histogram is the live impl "
         "(TPU); fused = force the kernel (interpret mode on CPU — the "
         "tier-1 coverage vehicle, slow); unfused = always the per-leaf "
         "path"),

@@ -206,8 +206,8 @@ def fused_wave_census(rows=4096, features=12, num_leaves=15, leaf_batch=4):
     ``fori_loop`` over the bucket switch), the fused kernel issues ONE
     ``pallas_call`` per wave with leaf batches pipelined through the grid.
     ``hist_dispatches_per_wave`` is derived from the grower's own declared
-    dispatch structure (``grow.wave_fused`` + the VMEM shape gate — the
-    SAME predicates the trace is built from, so the census cannot disagree
+    dispatch structure (the growth plan, ``GBDT.plan.fused`` — the
+    SAME plan the trace is built from, so the census cannot disagree
     with the program), and each blob carries the measured program
     dispatches/iter so the fused kernel is witnessed not to add launches.
     On CPU the fused grower runs the kernel body in interpret mode — the
