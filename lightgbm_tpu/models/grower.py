@@ -14,10 +14,12 @@ which of them a configuration runs is ``capabilities.plan_growth``'s to say:
 
 - **Permutation layout** (default, single device): a row-index permutation kept
   grouped by leaf (the reference's ``DataPartition``/``CUDADataPartition``), so
-  every per-split op — partition, histogram gather, scatter-back — touches ONLY
-  the splitting leaf's rows via ``dynamic_slice`` with a static power-of-two
-  bucket chosen by a ``lax.switch`` on the leaf's row count.  Per-tree work is
-  O(N · avg_depth) like the reference, not O(N · num_leaves).
+  every per-split op touches ONLY the splitting leaves' rows: the partition
+  is ONE ragged pass per wave over the W segments packed back to back
+  (``_partition_wave``), the unfused histogram gather a ``dynamic_slice``
+  with a static power-of-two bucket chosen by a ``lax.switch`` on the leaf's
+  row count.  Per-tree work is O(N · avg_depth) like the reference, not
+  O(N · num_leaves).
 - **Sharded permutation layout** (data-axis meshes): the SAME permutation
   machinery runs per-shard inside ``shard_map`` — each shard keeps a local
   row permutation grouped by leaf and histograms only its local slice of the
@@ -361,6 +363,152 @@ def _wave_row_ladder(lo: int, hi: int, blk: int) -> list:
         if not sizes or t < sizes[-1]:
             sizes.append(t)
         k += 1
+
+
+def _ladder_step(sizes, x):
+    """Index of the first of the ascending static ``sizes`` that holds
+    ``x`` (the last one if none does): the ``lax.switch`` index of a
+    bucket or of a total-row ladder."""
+    return jnp.clip(jnp.searchsorted(sizes, x, side="left"),
+                    0, sizes.shape[0] - 1).astype(jnp.int32)
+
+
+def _partition_block(n: int) -> int:
+    """Rows per block of the partition pass's packing: a power of two near
+    ``n / 1024`` within 256..2048.  The pass has no kernel tile to respect:
+    slicing a block's rows out of ``perm`` costs the same 2-3 us whatever
+    it holds, and every split leaf pads its last block — on a v5e 2 048
+    and 8 192 rows read alike on a wave of most of 1.5 M rows and 8 192
+    reads 1.6x worse on a wave of 45 k (PERF.md, Findings PR 30)."""
+    return min(2048, max(256, 1 << (n // 1024).bit_length()))
+
+
+def _prefix_count(bits):
+    """Inclusive prefix count of a ``(T,)`` bool vector, ``T`` a multiple of
+    128, through the MXU: rows of 128 times an upper-triangular matrix of
+    ones count inside each row (0/1 is exact in bf16 and a row's count in
+    the f32 accumulator), and a cumsum over the ``T / 128`` row totals
+    offsets them.  On a v5e it runs like ``jnp.cumsum`` and compiles in a
+    fraction of its ``reduce-window``'s time (PERF.md, Findings PR 30)."""
+    rows = bits.reshape(-1, 128).astype(jnp.bfloat16)
+    tri = (jnp.arange(128)[:, None] <= jnp.arange(128)[None, :]).astype(
+        jnp.bfloat16)
+    inner = jnp.dot(rows, tri,
+                    preferred_element_type=jnp.float32).astype(jnp.int32)
+    total = inner[:, -1]
+    return (inner + (jnp.cumsum(total) - total)[:, None]).reshape(-1)
+
+
+def _partition_wave(perm, starts, cnts, go_left, n):
+    """THE partition of the permutation layout: a stable two-way partition
+    of the W disjoint ``perm`` segments ``[starts[j], starts[j] + cnts[j])``
+    in ONE ragged pass — one read of the go-left bit per row, one prefix
+    sum, one scatter.
+
+    ``go_left`` holds the wave's W splits by row id, one bit a split
+    (``_go_left_bits``): split ``j`` of row ``r`` is bit ``j % 32`` of word
+    ``[(j // 32) * (n + 1) + r]``.  The segments are packed back to back in
+    whole row blocks (``ops/pallas_wave.wave_block_slots``; an empty or
+    inactive slot holds no block) and padded only to the next step of a
+    TOTAL-row ladder (``_wave_row_ladder``), whose branch carries the rows
+    it is handed in its scope path (``rows<R>``).  ONE inclusive prefix
+    count of the bits over the packed rows, less its value at each slot's
+    first row, is a row's rank among its slot's left rows; a right row's
+    rank is its place in the slot less the left rows before it.  ONE
+    scatter writes every real row straight to ``starts[slot] + rank`` (the
+    destinations are a permutation of the segments: ``unique_indices``);
+    the padding rows go out of range and are dropped, so every other
+    position of ``perm`` is untouched.  Returns ``(perm, nl)``, ``nl[j]``
+    the rows slot ``j`` sent left (0 for an empty slot)."""
+    from ..ops.pallas_wave import wave_block_slots
+
+    W = starts.shape[0]
+    blk = _partition_block(n)
+    totals = _wave_row_ladder(blk, (n // blk + W) * blk, blk)
+    size = perm.shape[0]
+    nb = (cnts.astype(jnp.int32) + (blk - 1)) // blk
+    off = jnp.cumsum(nb) - nb
+    ti = _ladder_step(jnp.asarray(totals, jnp.int32), jnp.sum(nb) * blk)
+
+    def branch_for(T):
+        def br(perm):
+            with kernel_rows(T, 1):
+                slot, k = wave_block_slots(off, T // blk)
+                row0 = k * blk
+                seg = jax.vmap(lambda s0: jax.lax.dynamic_slice(
+                    perm, (s0,), (blk,)))(starts[slot] + row0)
+                place = row0[:, None] + jnp.arange(blk, dtype=jnp.int32)
+                valid = place < cnts[slot][:, None]             # (T/blk, blk)
+                word = go_left.at[((slot >> 5) * (n + 1))[:, None] + seg].get(
+                    mode="promise_in_bounds")
+                gl = (((word >> (slot & 31)[:, None]) & 1) > 0) & valid
+                incl = _prefix_count(gl.reshape(T))
+                excl = jnp.concatenate([incl - gl.reshape(T), incl[-1:]])
+                base = excl[jnp.minimum(off * blk, T)]          # (W,)
+                nl = jnp.concatenate([base[1:], incl[-1:]]) - base
+                lrank = excl[:T].reshape(gl.shape) - base[slot][:, None]
+                rank = jnp.where(gl, lrank,
+                                 nl[slot][:, None] + place - lrank)
+                # padding rows: out of range, and still no two alike
+                dest = jnp.where(
+                    valid, starts[slot][:, None] + rank,
+                    size + jnp.arange(T, dtype=jnp.int32).reshape(gl.shape))
+                return perm.at[dest.reshape(T)].set(
+                    seg.reshape(T), mode="drop", unique_indices=True), nl
+        return br
+
+    return jax.lax.switch(ti, [branch_for(T) for T in totals], perm)
+
+
+def _pack_bits(bits):
+    """``(W, M)`` bools -> ``(ceil(W / 32) * M,)`` int32: row ``j`` is bit
+    ``j % 32`` of the words ``[(j // 32) * M : (j // 32 + 1) * M]``."""
+    w, m = bits.shape
+    pad = jnp.pad(bits, ((0, -w % 32), (0, 0))).reshape(-1, 32, m)
+    return jnp.sum(pad.astype(jnp.int32)
+                   << jnp.arange(32, dtype=jnp.int32)[None, :, None],
+                   axis=1).reshape(-1)
+
+
+def _decode_col(cfg, raw, feat, meta):
+    """Bundle-space bin -> original-feature bin for row partitioning."""
+    if not cfg.bundled:
+        return raw
+    nbpf, fo = meta[0], meta[5]
+    off = fo[feat]
+    nb = nbpf[feat]
+    return jnp.where(
+        off < 0, raw,
+        jnp.where((raw >= off) & (raw < off + nb - 1), raw - off + 1, 0))
+
+
+def _go_left_bits(cfg, bins_fm, meta, feats, sbins, dlefts, scats, cmasks):
+    """The wave's W splits applied to EVERY row, by row id: the ``go_left``
+    table of ``_partition_wave``.  Dense and sequential — split ``j`` reads
+    its column as ONE row of the feature-major bins ``bins_fm`` (``(G, N +
+    1)``, made once a tree), W x N bytes a wave — so that the pass's one
+    random read a row is a 1-D gather of a 32-bit word, the cheapest
+    gather a v5e has (7 ns a row against 13-22 for one byte out of the
+    ``(N + 1, G)`` matrix; PERF.md, Findings PR 30).  Under EFB the column
+    is decoded from its bundle, under packed4 from its nibble; a
+    categorical split looks its bin up in the mask's 32-bit words."""
+    col = lambda a: a[:, None]
+    gcols = meta[4][feats] if cfg.bundled else feats
+    raw = bins_fm[gcols // 2 if cfg.packed4 else gcols].astype(jnp.int32)
+    if cfg.packed4:
+        raw = jnp.where(col(gcols) % 2 == 0, raw & 15, (raw >> 4) & 15)
+    bins = _decode_col(cfg, raw, col(feats), meta)              # (W, N + 1)
+    go_left = bins <= col(sbins)
+    if cfg.split.has_categorical:
+        words = _pack_bits(cmasks.T).reshape(-1, cmasks.shape[0])
+        word = jnp.zeros_like(bins)
+        for k in range(words.shape[0]):
+            word = jnp.where((bins >> 5) == k, col(words[k]), word)
+        go_left = jnp.where(col(scats), ((word >> (bins & 31)) & 1) > 0,
+                            go_left)
+    go_left = jnp.where((bins == col(meta[1][feats])) & ~col(scats),
+                        col(dlefts), go_left)
+    return _pack_bits(go_left)
 
 
 def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
@@ -1435,54 +1583,6 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         gl = jnp.where(owns, gl, False)
         return _psum(gl.astype(jnp.float32), faxis) > 0.5
 
-    def _partition_scatter(perm, start, seg, valid, go_left, S):
-        """Stable two-way partition of a contiguous perm slice given its
-        go-left predicate — the single copy of the slice/cumsum/scatter
-        kernel shared by every partition-branch flavor."""
-        go_left = go_left & valid
-        go_right = valid & ~go_left
-        nl_phys = jnp.sum(go_left.astype(jnp.int32))
-        lpos = jnp.cumsum(go_left.astype(jnp.int32)) - go_left
-        rpos = nl_phys + jnp.cumsum(go_right.astype(jnp.int32)) - go_right
-        pos = jnp.where(go_left, lpos,
-                        jnp.where(go_right, rpos,
-                                  jnp.arange(S, dtype=jnp.int32)))
-        new_seg = jnp.zeros(S, jnp.int32).at[pos].set(seg)
-        return (jax.lax.dynamic_update_slice(perm, new_seg, (start,)),
-                nl_phys)
-
-    def _part_branch_for_gl(S):
-        """Partition branch over a precomputed row-id-indexed go-left
-        vector (feature-parallel path: the split column lives on one
-        shard; see _fp_go_left)."""
-        @phase("grow/partition")
-        def branch(perm, start, cnt, glv):
-            seg = jax.lax.dynamic_slice(perm, (start,), (S,))
-            valid = jnp.arange(S, dtype=jnp.int32) < cnt
-            return _partition_scatter(perm, start, seg, valid, glv[seg], S)
-        return branch
-
-    def _part_branch_for(bins_pad, nan_bins, S, meta=None):
-        """Partition one leaf's contiguous perm slice of static size S
-        (cheap S-ops; no histogram).
-        Under EFB the split feature's column is decoded from its bundle."""
-        @phase("grow/partition")
-        def branch(perm, start, cnt, feat, sbin, dleft, scat, cmask):
-            seg = jax.lax.dynamic_slice(perm, (start,), (S,))
-            valid = jnp.arange(S, dtype=jnp.int32) < cnt
-            gcol = meta[4][feat] if cfg.bundled else feat
-            if cfg.packed4:
-                byte = bins_pad[seg, gcol // 2].astype(jnp.int32)
-                raw = jnp.where(gcol % 2 == 0, byte & 15, (byte >> 4) & 15)
-            else:
-                raw = bins_pad[seg, gcol].astype(jnp.int32)
-            col = _decode_col(raw, feat, meta)
-            is_nan = col == nan_bins[feat]
-            go_left = jnp.where(scat, cmask[col], col <= sbin)
-            go_left = jnp.where(is_nan & ~scat, dleft, go_left)
-            return _partition_scatter(perm, start, seg, valid, go_left, S)
-        return branch
-
     def _expand_hist(bh, meta, tg, th, tc, rs=None):
         """(G, B, 3) bundle histogram -> (F, B, 3) per-original-feature view
         (reference: per-feature offsets into group histograms,
@@ -1521,17 +1621,6 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         return jax.vmap(lambda b, g, h, c: _expand_hist(b, meta, g, h, c,
                                                         rs))(
             bhk, gk, hk, ck)
-
-    def _decode_col(raw, feat, meta):
-        """Bundle-space bin -> original-feature bin for row partitioning."""
-        if not cfg.bundled:
-            return raw
-        nbpf, fo = meta[0], meta[5]
-        off = fo[feat]
-        nb = nbpf[feat]
-        return jnp.where(
-            off < 0, raw,
-            jnp.where((raw >= off) & (raw < off + nb - 1), raw - off + 1, 0))
 
     def _hist_branch_for(bins_pad, vals_pad, n, S, nf=0):
         """RAW histogram of a contiguous perm range of static size S (the
@@ -1769,7 +1858,8 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         (``leaf_batch=1``) is the reference's sequential leaf-wise order,
         and the only wave forced splits and ``faxis`` run in.
 
-        Per wave: partition each chosen leaf's contiguous segment, histogram
+        Per wave: partition the chosen leaves' contiguous segments in one
+        ragged pass (``_partition_wave``), histogram
         each SMALLER sibling's contiguous range, get the larger siblings by
         subtraction, and search all 2W children's splits.  Unfused that is
         W per-leaf ``histogram_flat`` calls at each leaf's own bucket, an
@@ -1832,17 +1922,17 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             state = _store_best(state, jnp.asarray(0), sync(bs0),
                                 jnp.asarray(True))
 
-        part_branches = ([_part_branch_for_gl(S) for S in buckets]
-                         if faxis is not None else
-                         [_part_branch_for(bins_pad, nan_bins, S, meta)
-                          for S in buckets])
+        if faxis is None:
+            # a split's column is one contiguous row of the feature-major
+            # bins (a dense transpose, once a tree): _go_left_bits
+            with phase("grow/setup"):
+                bins_fm = bins_pad.T
         hist_branches = [_hist_branch_for(bins_pad, vals_pad, n, S,
                                           meta[0].shape[0])
                          for S in buckets]
 
         def _bucket_of(cnt, sizes=buckets_arr):
-            return jnp.clip(jnp.searchsorted(sizes, cnt, side="left"),
-                            0, sizes.shape[0] - 1).astype(jnp.int32)
+            return _ladder_step(sizes, cnt)
 
         def _pool_hist(st, sl, miss, start, cnt):
             """Pool lookup with recompute-on-miss (reference
@@ -2027,33 +2117,19 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                         0, W, parent_one,
                         jnp.zeros((W,) + st.leaf_hist.shape[1:], raw_dtype))
 
-            def part_one(j, carry):
-                perm, nls = carry
+            with phase("grow/partition"):
                 if faxis is not None:
                     # the split column lives on one shard: its owner
-                    # broadcasts the go-left vector
+                    # broadcasts the go-left vector, read by row id
                     glv = _fp_go_left(
-                        bins_pad, nan_bins, feats[j], sbins[j], dlefts[j],
-                        scats[j], cmasks[j], foffset, f, faxis)
-
-                def do(p):
-                    if faxis is not None:
-                        return jax.lax.switch(
-                            _bucket_of(cnts[j]), part_branches, p,
-                            starts[j], cnts[j], glv)
-                    return jax.lax.switch(
-                        _bucket_of(cnts[j]), part_branches, p, starts[j],
-                        cnts[j], feats[j], sbins[j], dlefts[j], scats[j],
-                        cmasks[j])
-
-                perm, nl = jax.lax.cond(
-                    active[j], do, lambda p: (p, jnp.asarray(0, jnp.int32)),
-                    perm)
-                return perm, nls.at[j].set(nl)
-
-            with phase("grow/partition"):
-                perm, nl_phys = jax.lax.fori_loop(
-                    0, W, part_one, (st.perm, jnp.zeros(W, jnp.int32)))
+                        bins_pad, nan_bins, feats[0], sbins[0], dlefts[0],
+                        scats[0], cmasks[0], foffset, f, faxis)
+                    go_left = glv.astype(jnp.int32)
+                else:
+                    go_left = _go_left_bits(cfg, bins_fm, meta, feats, sbins,
+                                            dlefts, scats, cmasks)
+                perm, nl_phys = _partition_wave(st.perm, starts, cnts,
+                                                go_left, n)
 
             with phase("grow/select"):
                 if axis is None:
@@ -2431,8 +2507,8 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             with phase("grow/partition"):
                 gcol = meta[4][feat] if cfg.bundled else feat
                 col = _decode_col(
-                    jnp.take(bins, gcol, axis=1).astype(jnp.int32), feat,
-                    meta)
+                    cfg, jnp.take(bins, gcol, axis=1).astype(jnp.int32),
+                    feat, meta)
                 is_nan = col == nan_bins[feat]
                 go_left = jnp.where(scat, cmask[col], col <= sbin)
                 go_left = jnp.where(is_nan & ~scat, dleft, go_left)

@@ -357,6 +357,34 @@ def test_fused_wave_gathers_no_more_than_a_wave_holds(wave_pair):
     assert not re.search(rf"u8\[{WW},\d+,{FW}\]", wave_pair["fused"])
 
 
+@pytest.mark.parametrize("mode", ["fused", "unfused"])
+def test_partition_is_one_ragged_pass_per_wave(wave_pair, mode):
+    """ISSUE-30 structural pin: the compiled program partitions a wave in
+    ONE pass over its W segments packed back to back — one scatter per
+    step of the total-row ladder, straight into ``perm`` — and nothing
+    under ``grow/partition`` is shaped by a power-of-two bucket any more
+    (the old form: per split leaf a ``switch`` over buckets of 2 048 and
+    4 096 rows here, each with its own gather, two cumsums, a scatter into
+    ``zeros(S)`` and a ``dynamic-update-slice`` back into ``perm``)."""
+    blk = G._partition_block(NW)
+    ladder = G._wave_row_ladder(blk, (NW // blk + WW) * blk, blk)
+    buckets = set(G._split_buckets(NW))
+    assert not buckets & set(ladder)            # the pin can tell them apart
+    part = [ln for ln in wave_pair[mode].splitlines()
+            if "grow/partition" in ln]
+    assert part
+    handed = {int(r) for ln in part for r in re.findall(r"/rows(\d+)", ln)}
+    assert handed == set(ladder), sorted(handed)
+    scatters = [ln for ln in part if re.search(r" scatter\(", ln)]
+    assert len(scatters) == len(ladder)
+    assert all(f"s32[{2 * NW}]" in ln and "unique_indices=true" in ln
+               for ln in scatters)               # into perm itself
+    assert not any("dynamic-update-slice(" in ln for ln in part)
+    dims = {int(d) for ln in part for _, ds in _parse_shapes(ln)
+            for d in ds.split(",") if d}
+    assert not dims & buckets, sorted(dims & buckets)
+
+
 def test_program_flops_bounded(hlo):
     """XLA's own FLOP count for the bench-shaped program (while bodies
     counted once) must stay near the one-hot contraction's analytic cost.

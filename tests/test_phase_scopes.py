@@ -213,6 +213,36 @@ def test_fused_wave_gather_is_handed_the_rows_the_wave_has():
     assert largest == cap, (largest, cap)
 
 
+@pytest.mark.parametrize("path", ["fused", "unfused", "sharded"])
+def test_partition_pass_sits_under_grow_partition_with_its_rows(path):
+    """The ONE partition pass: every step of its total-row ladder is a
+    branch whose scope path ends in ``cols1/rows<R>`` — one column read a
+    row, ``R`` rows handed — under ``grow/partition``, and holds the
+    pass's three operations; the write into ``perm`` happens nowhere
+    else.  (``benchmark/scopes.rows_fed`` sums ``rows<R>`` over KERNEL
+    events only, so the histogram readings do not see these.)"""
+    import lightgbm_tpu.models.grower as G
+
+    fn, args = _program(path)
+    n = N // 2 if path == "sharded" else N      # rows a shard
+    w = PARAMS["tpu_leaf_batch"]
+    blk = G._partition_block(n)
+    ladder = G._wave_row_ladder(blk, (n // blk + w) * blk, blk)
+    held = {}
+    for eqn, scope in _walk(jax.make_jaxpr(fn)(*args).jaxpr):
+        prim = eqn.primitive.name
+        m = re.search(r"grow/partition/(?:.*/)?cols1/rows(\d+)(?:/|$)", scope)
+        if m:
+            held.setdefault(int(m.group(1)), set()).add(prim)
+        if prim == "scatter" and eqn.outvars[0].aval.shape == (2 * n,):
+            assert m, scope                     # perm is written here alone
+        if prim == "dynamic_update_slice":
+            assert "grow/partition" not in scope, scope
+    assert sorted(held) == ladder, (sorted(held), ladder)
+    for prims in held.values():
+        assert {"gather", "dot_general", "scatter"} <= prims, prims
+
+
 class _NoScope(contextlib.ContextDecorator):
     def __enter__(self):
         return self

@@ -12,6 +12,14 @@ is ``chip_smoke.py``'s job, on the chip.
 
 prints one ``[OK]``/``[FAIL]`` line per kernel (with the compiler's message)
 and exits non-zero when any failed, or 3 when libtpu offers no topology.
+
+    python tools/tpu_aot.py --grower higgs|msltr [--phase grow/partition]
+
+compiles the whole GROWER at a benchmark cell's shape (1.5 M x 28 on the
+fused wave, 2.27 M x 137 on the unfused; 255 leaves, ``leaf_batch=16``)
+and prints the compile's seconds, the temporaries it plans and the
+operations it holds under one phase scope — a count and a plan, never a
+speed.
 """
 
 from __future__ import annotations
@@ -125,12 +133,92 @@ def kernel_cases(sharding):
     return cases
 
 
+GROWER_SHAPES = {"higgs": (1_500_000, 28, "fused"),
+                 "msltr": (2_270_000, 137, "unfused")}
+
+
+def grower_case(cell: str, sharding):
+    """``(fn, args)``: the jitted grower's body at a benchmark cell's
+    shape, planned as a TPU would plan it (the Pallas kernels compiled,
+    not interpreted: ``interpret_mode`` asks the live backend, which is
+    the CPU here, so this tool answers for it)."""
+    import jax
+    import jax.numpy as jnp
+
+    import lightgbm_tpu.models.grower as G
+    import lightgbm_tpu.ops.pallas_common as pc
+    from lightgbm_tpu.ops.split import SplitConfig
+
+    pc.interpret_mode = lambda: False
+    n, f, wave = GROWER_SHAPES[cell]
+    scfg = SplitConfig(min_data_in_leaf=0, min_sum_hessian_in_leaf=100.0,
+                       has_nan=False, has_categorical=False,
+                       use_sorted_categorical=False, has_monotone=False)
+    grow = G.make_grower(G.GrowerConfig(
+        num_leaves=255, num_bins=255, split=scfg, leaf_batch=16,
+        histogram_impl="pallas", wave_kernel=wave))
+    assert grow.plan.fused == (wave == "fused"), str(grow.plan)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    i32, f32 = jnp.int32, jnp.float32
+    return grow.raw, (sds((n, f), jnp.uint8), sds((n,), f32), sds((n,), f32),
+                      sds((n,), f32), sds((f,), jnp.bool_), sds((f,), i32),
+                      sds((f,), i32), sds((f,), jnp.bool_), sds((f,), i32))
+
+
+def phase_census(hlo_text: str, phase: str) -> dict:
+    """Operations of a compiled module whose innermost phase scope is
+    ``phase`` (a fusion counts once; the instructions inside it do not),
+    by opcode, and the distinct ``rows<R>`` their paths carry."""
+    import collections
+    import re
+
+    ops, rows, comp = collections.Counter(), set(), ""
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = re.search(r'op_name="([^"]*)"', line)
+        op = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = .*? ([a-z\-]+)\(", line)
+        if ("fused_computation" in comp or "sub_computation" in comp
+                or not m or not op or phase not in m.group(1)
+                or re.search(r"(grow|boost)/[a-z_]+",
+                             m.group(1).split(phase)[-1])
+                or op.group(1) in ("get-tuple-element", "bitcast", "tuple",
+                                   "constant", "parameter")):
+            continue
+        ops[op.group(1)] += 1
+        rows.update(int(r) for r in re.findall(r"/rows(\d+)", m.group(1)))
+    return {"operations": sum(ops.values()), "by_opcode": dict(ops),
+            "rows": sorted(rows)}
+
+
 def main() -> int:
+    import argparse
+    import time
+
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--grower", choices=sorted(GROWER_SHAPES))
+    ap.add_argument("--phase", default="grow/partition")
+    opts = ap.parse_args()
     try:
         sharding = tpu_sharding()
     except Exception as e:  # noqa: BLE001 — no libtpu / no topology support
         print(f"tpu_aot: no compile-only TPU topology here: {e!r}"[:300])
         return 3
+    if opts.grower:
+        fn, args = grower_case(opts.grower, sharding)
+        t0 = time.time()
+        compiled = compile_for_tpu(fn, *args)
+        census = phase_census(compiled.as_text(), opts.phase)
+        print(f"[OK] grower {opts.grower}: compiled in "
+              f"{time.time() - t0:.1f} s, temporaries "
+              f"{compiled.memory_analysis().temp_size_in_bytes} bytes; "
+              f"under {opts.phase}: {census}", flush=True)
+        return 0
     failed = 0
     for name, fn, args in kernel_cases(sharding):
         try:
