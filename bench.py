@@ -294,7 +294,9 @@ def run_wide_rung(rows, iters, platform, jax, features=None,
     """Dense-wide (Epsilon-like) rung: the (L, F, B, 3) leaf-histogram
     carry that motivates the bounded pool (~1.5 GB f32 unpooled at
     F=2000/B=256/L=255).  Trains with histogram_pool_size set so the blob
-    also witnesses the pooled carry; returns the detail blob."""
+    also witnesses the pooled carry; returns the detail blob.  The chip
+    figure for this shape, unpooled, lives in the root PERF.md under the
+    benchmark cell ``epsilon.train`` (PR 31); this rung is the CPU one."""
     features = features or WIDE_FEATURES
     # CPU rehearsal: XLA-on-host cannot afford B=256 x F=2000 histograms —
     # shrink depth/bins, keep the WIDTH (the shape under test).

@@ -28,6 +28,8 @@ dense arm, which is what LightGBM itself uses once sparse columns are bundled.
 from __future__ import annotations
 
 import dataclasses
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -39,6 +41,10 @@ MISSING_ZERO = 1
 MISSING_NAN = 2
 
 _KZERO_LO, _KZERO_HI = -1e-35, 1e-35  # reference uses kZeroThreshold = 1e-35
+# Sampled values (columns x sampled rows) from which bin_dataset finds the
+# columns' boundaries on several threads: below it a pool costs more than
+# the sorts it spreads (4 M values are 0.4 s in one thread).
+_PARALLEL_FIND_BIN_VALUES = 1 << 22
 
 
 @dataclasses.dataclass
@@ -350,10 +356,10 @@ def bin_dataset(
                 f"for {f} features (reference requires an exact match)")
         if any(int(v) <= 1 for v in max_bin_by_feature):
             raise ValueError("max_bin_by_feature values must be > 1")
-    mappers: List[BinMapper] = []
     s = sample.shape[0]
-    all_nan_cols: List[int] = []
-    for j in range(f):
+
+    def one_feature(j: int):
+        """Column ``j``'s ``(mapper, all its sampled values are NaN)``."""
         mb = max_bin
         if max_bin_by_feature is not None:
             mb = int(max_bin_by_feature[j])
@@ -364,17 +370,27 @@ def bin_dataset(
             col[: len(nz)] = nz       # find_bin is order-invariant
         else:
             col = sample[:, j]
-        if (j not in cat_set and s
-                and bool(np.isnan(np.asarray(col, np.float64)).all())):
-            all_nan_cols.append(j)
-        mappers.append(
-            find_bin(
-                col, mb, min_data_in_bin,
-                is_categorical=(j in cat_set),
-                use_missing=use_missing, zero_as_missing=zero_as_missing,
-                forced_upper_bounds=(forced_bins or {}).get(j),
-            )
-        )
+        all_nan = (j not in cat_set and s > 0
+                   and bool(np.isnan(np.asarray(col, np.float64)).all()))
+        return find_bin(
+            col, mb, min_data_in_bin,
+            is_categorical=(j in cat_set),
+            use_missing=use_missing, zero_as_missing=zero_as_missing,
+            forced_upper_bounds=(forced_bins or {}).get(j)), all_nan
+
+    # A column's boundaries depend on that column alone, and nearly all of
+    # the work is the native sort (ctypes releases the interpreter lock):
+    # wide samples go column by column over a few threads, as the
+    # reference's loader does under OpenMP (2000 columns x 200 000 sampled
+    # rows: 43 s in one thread).
+    workers = min(os.cpu_count() or 1, 16, f)
+    if workers > 1 and f * s >= _PARALLEL_FIND_BIN_VALUES:
+        with ThreadPoolExecutor(workers) as pool:
+            found = list(pool.map(one_feature, range(f)))
+    else:
+        found = [one_feature(j) for j in range(f)]
+    mappers: List[BinMapper] = [m for m, _ in found]
+    all_nan_cols: List[int] = [j for j, (_, nan) in enumerate(found) if nan]
     # Ingestion health (docs/ROBUSTNESS.md; reference DatasetLoader
     # feature_pre_filter warnings): a column that is entirely NaN in the
     # binning sample, or binned trivially (constant), can never split —
@@ -670,23 +686,32 @@ def build_bundles(binned: "BinnedData", *, max_conflict_rate: float = 0.0,
     order = [int(j) for j in np.argsort(nz_cnt) if eligible[j]]
     bundles: List[List[int]] = []
     bundle_nz: List[np.ndarray] = []
+    bundle_cnt: List[int] = []
     bundle_bins: List[int] = []
     for j in order:
         extra = int(nbpf[j]) - 1
+        cnt_j = int(nz_cnt[j])
         placed = False
         for bi in range(len(bundles)):
             if bundle_bins[bi] + extra > max_bundle_bins:
+                continue
+            # two sets of a + b members among s rows share at least
+            # a + b - s: dense columns (2000 of them are 2 M pairs, 115 s
+            # of row-wise ANDs) are refused without looking at the rows
+            if bundle_cnt[bi] + cnt_j - s > budget:
                 continue
             conflict = int(np.count_nonzero(bundle_nz[bi] & nz[:, j]))
             if conflict <= budget:
                 bundles[bi].append(j)
                 bundle_nz[bi] |= nz[:, j]
+                bundle_cnt[bi] += cnt_j - conflict
                 bundle_bins[bi] += extra
                 placed = True
                 break
         if not placed:
             bundles.append([j])
             bundle_nz.append(nz[:, j].copy())
+            bundle_cnt.append(cnt_j)
             bundle_bins.append(1 + extra)
 
     # The greedy pass enforced the budget on a sample only; re-check each

@@ -82,8 +82,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.histogram import histogram_from_vals, unpack_bins4
-from ..ops.split import (BestSplit, SplitConfig, best_split, leaf_gain,
-                         leaf_output, smoothed_output, sync_best_split)
+from ..ops.split import (BestSplit, SplitConfig, _resolve_tile, best_split,
+                         leaf_gain, leaf_output, smoothed_output,
+                         sync_best_split)
+from ..telemetry.registry import registry
 from ..telemetry.spans import kernel_rows, phase
 from .capabilities import PERM_MIN_ROWS as _MIN_BUCKET
 from .capabilities import plan_growth
@@ -978,6 +980,8 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         )
         P = L if pool_slots is None else pool_slots
         pooled = P < L
+        registry().gauge("grow.leaf_hist_bytes").set(
+            P * gcols * HB * 3 * jnp.dtype(root_hist.dtype).itemsize)
         return _GrowState(
             num_leaves=jnp.asarray(1, jnp.int32),
             perm=jnp.zeros(0, jnp.int32),  # set by caller when used
@@ -2756,6 +2760,20 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         nfeat = meta[0].shape[0]
         plan = plan_growth(cfg, mesh, data_axis, rows=bins.shape[0],
                            features=nfeat)
+        # What the traced width selects, for the registry (set once a
+        # compile, as rank.queries is): the column chunks one histogram
+        # takes and the columns ONE launch holds (kernel_rows' chunks<K>
+        # and cols<C>), and the split scan's block width (0 = untiled).
+        hcols = ftile = nfeat if cfg.packed4 else bins.shape[1]
+        if plan.hist_impl == "pallas":
+            from ..ops.pallas_histogram import kernel_layout
+            ftile = kernel_layout(hcols, HB,
+                                  "int8" if cfg.quantized else "f32",
+                                  cfg.rows_block, cfg.packed4)[1]
+        registry().gauge("hist.col_chunks").set(-(-hcols // ftile))
+        registry().gauge("hist.cols_tile").set(ftile)
+        registry().gauge("scan.tile").set(
+            _resolve_tile(cfg.split.scan_tile, nfeat))
         if plan.layout == "feature":
             tree, row_leaf = _grow_fp(bins, vals, scale3, feature_mask,
                                       meta, plan, split_key)

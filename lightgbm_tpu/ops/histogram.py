@@ -179,9 +179,9 @@ def histogram_from_vals(
         # the launch's last scope segments: the feature columns and the
         # rows ONE kernel launch is handed — a histogram wider than the
         # layout's column tile is several launches under this one path
-        ftile = kernel_layout(features if packed4 else bins.shape[1],
-                              num_bins, dtype, rows_block, packed4)[1]
-        with kernel_rows(bins.shape[0], ftile):
+        f = features if packed4 else bins.shape[1]
+        ftile = kernel_layout(f, num_bins, dtype, rows_block, packed4)[1]
+        with kernel_rows(bins.shape[0], ftile, -(-f // ftile)):
             out = histogram_flat(bins, vals, num_bins=num_bins,
                                  rows_block=rows_block, dtype=dtype,
                                  packed4=packed4, features=features,

@@ -18,6 +18,7 @@ no data-dependent control flow, one reduction.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -634,6 +635,7 @@ def select_payload(t: _ScanTables, is_categorical, cfg: SplitConfig, *,
     return bgain, bf, bb, bdefault_left, bis_cat, GL, HL, CL, GR, HR, CR
 
 
+@functools.partial(jax.jit, static_argnames=("cfg", "with_feature_gains"))
 def _best_split_impl(
     hist: jnp.ndarray,            # (F, B, 3) leaf histogram
     parent_grad: jnp.ndarray,     # scalar ΣG over the leaf (includes NaN bin)
@@ -671,7 +673,16 @@ def _best_split_impl(
     where ``from_sorted`` flags a sorted-categorical winner — the cross-tile
     reducer needs it to reproduce the untiled "sorted wins only strictly"
     rule — and ``fg`` is the per-feature gain vector (None unless
-    ``with_feature_gains``)."""
+    ``with_feature_gains``).
+
+    Jitted, so that the untiled scan is ONE compiled expression wherever it
+    is called from, as a G-block under ``lax.map`` always is.  Op by op
+    (an eager call) every ``a * b + c`` rounds twice; inside a compiled
+    fusion the CPU backend's LLVM contracts it into one fused multiply-add
+    (``gain_given_output`` under ``path_smooth`` is such a sum), and the
+    per-feature gains of the two forms then sit 1 ulp apart.  Compiled
+    against compiled they are the same bits (tests/test_split_tile.py);
+    inside an enclosing jit this wrapper is inlined and changes nothing."""
     G, H, C = hist[..., 0], hist[..., 1], hist[..., 2]
     t = scan_tables(
         G, H, C, parent_grad, parent_hess, parent_count,

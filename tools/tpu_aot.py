@@ -13,10 +13,11 @@ is ``chip_smoke.py``'s job, on the chip.
 prints one ``[OK]``/``[FAIL]`` line per kernel (with the compiler's message)
 and exits non-zero when any failed, or 3 when libtpu offers no topology.
 
-    python tools/tpu_aot.py --grower higgs|msltr [--phase grow/partition]
+    python tools/tpu_aot.py --grower higgs|msltr|epsilon [--phase grow/hist]
 
 compiles the whole GROWER at a benchmark cell's shape (1.5 M x 28 on the
-fused wave, 2.27 M x 137 on the unfused; 255 leaves, ``leaf_batch=16``)
+fused wave, 2.27 M x 137 and 400 K x 2000 on the unfused; 255 leaves,
+``leaf_batch=16``)
 and prints the compile's seconds, the temporaries it plans and the
 operations it holds under one phase scope — a count and a plan, never a
 speed.
@@ -134,7 +135,8 @@ def kernel_cases(sharding):
 
 
 GROWER_SHAPES = {"higgs": (1_500_000, 28, "fused"),
-                 "msltr": (2_270_000, 137, "unfused")}
+                 "msltr": (2_270_000, 137, "unfused"),
+                 "epsilon": (400_000, 2000, "unfused")}
 
 
 def grower_case(cell: str, sharding):
