@@ -82,8 +82,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.histogram import histogram_from_vals, unpack_bins4
-from ..ops.split import (BestSplit, SplitConfig, _resolve_tile, best_split,
-                         leaf_gain, leaf_output, smoothed_output,
+from ..ops.split import (BestSplit, SplitConfig, _prefix_sum, _resolve_tile,
+                         best_split, leaf_gain, leaf_output, smoothed_output,
                          sync_best_split)
 from ..telemetry.registry import registry
 from ..telemetry.spans import kernel_rows, phase
@@ -385,20 +385,26 @@ def _partition_block(n: int) -> int:
     return min(2048, max(256, 1 << (n // 1024).bit_length()))
 
 
+def _offset_rows(inner):
+    """``(T / 128, 128)`` inclusive prefix sums inside each row -> the
+    ``(T,)`` inclusive prefix sum over all of them: a cumsum over the
+    ``T / 128`` row totals offsets the rows."""
+    total = inner[:, -1]
+    return (inner + (jnp.cumsum(total) - total)[:, None]).reshape(-1)
+
+
 def _prefix_count(bits):
     """Inclusive prefix count of a ``(T,)`` bool vector, ``T`` a multiple of
     128, through the MXU: rows of 128 times an upper-triangular matrix of
     ones count inside each row (0/1 is exact in bf16 and a row's count in
-    the f32 accumulator), and a cumsum over the ``T / 128`` row totals
-    offsets them.  On a v5e it runs like ``jnp.cumsum`` and compiles in a
+    the f32 accumulator), and ``_offset_rows`` offsets them.  On a v5e it
+    runs like ``jnp.cumsum`` and compiles in a
     fraction of its ``reduce-window``'s time (PERF.md, Findings PR 30)."""
     rows = bits.reshape(-1, 128).astype(jnp.bfloat16)
     tri = (jnp.arange(128)[:, None] <= jnp.arange(128)[None, :]).astype(
         jnp.bfloat16)
-    inner = jnp.dot(rows, tri,
-                    preferred_element_type=jnp.float32).astype(jnp.int32)
-    total = inner[:, -1]
-    return (inner + (jnp.cumsum(total) - total)[:, None]).reshape(-1)
+    return _offset_rows(jnp.dot(
+        rows, tri, preferred_element_type=jnp.float32).astype(jnp.int32))
 
 
 def _partition_wave(perm, starts, cnts, go_left, n):
@@ -460,6 +466,38 @@ def _partition_wave(perm, starts, cnts, go_left, n):
         return br
 
     return jax.lax.switch(ti, [branch_for(T) for T in totals], perm)
+
+
+def _row_leaf_map(leaf_start, leaf_rows, num_leaves, perm, n, sentinel):
+    """Row id -> leaf id from the final grouped permutation: position ``i``
+    of ``perm[:n]`` belongs to the live leaf whose ``[start, start + rows)``
+    holds ``i``, and that leaf id is piecewise constant over at most L
+    ranges.  So no position searches for its range: the CHANGE of leaf id
+    is scatter-added at each live start in ascending order (L numbers into
+    ``n`` zeros) and ONE inclusive int32 prefix sum over the positions
+    turns the changes into ids — shift-and-add inside rows of 128
+    (``ops/split._prefix_sum``), the rows offset by ``_offset_rows`` —
+    exact for every ``num_leaves``, with no per-row table read and
+    no loop (the per-position ``searchsorted`` it replaces read 62-66 ns a
+    row on a v5e, this 0.03; PERF.md, Findings PR 32).  A position before
+    the first start reads the first leaf and a gap the range before it, as
+    ``clip(searchsorted(..) - 1, 0, L - 1)`` did.  One scatter by
+    ``perm[:n]`` then puts the ids in row order.
+
+    Zero-row leaves (possible per shard under the sharded layout) share
+    their start with a sibling, and the slots past ``num_leaves`` hold
+    whatever the last tree left: both take ``sentinel``, a position no row
+    has, sort last and fall off the end (``mode="drop"``; one that lands in
+    the padding of the last row of 128 is sliced away)."""
+    L = leaf_start.shape[0]
+    starts = jnp.where((jnp.arange(L) < num_leaves) & (leaf_rows > 0),
+                       leaf_start, sentinel)
+    order = jnp.argsort(starts).astype(jnp.int32)
+    at = starts[order].at[0].set(0)     # before the first start: its leaf
+    change = jnp.zeros(-(-n // 128) * 128, jnp.int32).at[at].add(
+        jnp.diff(order, prepend=0), mode="drop")
+    pos_leaf = _offset_rows(_prefix_sum(change.reshape(-1, 128)))[:n]
+    return jnp.zeros(n, jnp.int32).at[perm[:n]].set(pos_leaf)
 
 
 def _pack_bits(bits):
@@ -1834,21 +1872,10 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
 
     @phase("grow/finish")
     def _row_leaf_from_perm(state, n, max_bucket):
-        """row -> leaf assignment from the final grouped permutation:
-        position i belongs to the leaf whose [start, start+rows) range
-        contains i."""
-        # Zero-row leaves (possible per-shard under the sharded layout) share
-        # their start with a sibling; exclude them so the searchsorted tie
-        # cannot claim the sibling's rows.
-        starts = jnp.where((jnp.arange(L) < state.num_leaves)
-                           & (state.leaf_rows > 0),
-                           state.leaf_start, n + max_bucket)
-        order = jnp.argsort(starts)
-        sorted_starts = starts[order]
-        pos_leaf = order[jnp.clip(
-            jnp.searchsorted(sorted_starts, jnp.arange(n, dtype=jnp.int32),
-                             side="right") - 1, 0, L - 1)].astype(jnp.int32)
-        return jnp.zeros(n, jnp.int32).at[state.perm[:n]].set(pos_leaf)
+        """row -> leaf assignment from the final grouped permutation
+        (``_row_leaf_map``); ``n + max_bucket`` is past ``perm``'s end."""
+        return _row_leaf_map(state.leaf_start, state.leaf_rows,
+                             state.num_leaves, state.perm, n, n + max_bucket)
 
     # ------------------------------------------------------------------ wave path
     def _grow_wave(bins, vals, scale3, feature_mask, meta, plan, cegb=None,

@@ -104,6 +104,52 @@ def test_wave_row_leaf_consistency():
     np.testing.assert_allclose(sc, pred, rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("path", ["fused", "unfused", "data_mesh_2"])
+def test_row_leaf_is_the_trees_walk(path):
+    """``row_leaf`` as ``grow`` returns it equals the leaf each training
+    row reaches by walking the returned tree on its bins — on the fused
+    wave, the unfused wave and per shard under a 2-shard data mesh (where
+    a leaf can hold no local rows)."""
+    import dataclasses
+    import jax.numpy as jnp
+    import lightgbm_tpu.models.grower as G
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.dataset import TrainData
+    from lightgbm_tpu.models.gbdt import _split_config
+    from lightgbm_tpu.models.tree import Tree
+    from lightgbm_tpu.parallel.mesh import DATA_AXIS, make_mesh
+
+    n, f = 2 * 2688, 8                  # not a multiple of 128 per shard
+    rng = np.random.RandomState(5)
+    X = rng.randn(n, f)
+    X[rng.rand(n) < 0.05, 3] = np.nan
+    X[: n // 2, 1] += 3.0               # leaves that live in ONE shard
+    y = (X[:, 0] + 0.7 * X[:, 1] * X[:, 2] + 0.3 * rng.randn(n) > 0)
+    cfg = Config({"objective": "binary", "num_leaves": 31,
+                  "min_data_in_leaf": 5, "verbosity": -1})
+    td = TrainData.build(X, y.astype(np.float64), cfg)
+    meta = td.feature_meta_device()
+    args = (jnp.asarray(td.binned.bins),
+            jnp.asarray((0.5 - y).astype(np.float32)),
+            jnp.full(n, 0.25, jnp.float32), jnp.ones(n, jnp.float32),
+            jnp.ones(f, bool), meta["num_bins_per_feature"],
+            meta["nan_bins"], meta["is_categorical"], meta["monotone"])
+    gcfg = G.GrowerConfig(num_leaves=31, num_bins=td.binned.max_num_bins,
+                          split=_split_config(cfg, td), leaf_batch=4)
+    if path == "data_mesh_2":
+        grow = G.make_grower(gcfg, mesh=make_mesh(2, 1), data_axis=DATA_AXIS)
+        assert grow.plan.layout == "data", str(grow.plan)
+    else:
+        grow = G.make_grower(dataclasses.replace(gcfg, wave_kernel=path))
+        assert grow.plan.fused is (path == "fused"), str(grow.plan)
+    assert grow.plan.body == "wave", str(grow.plan)
+    arrays, row_leaf = grow(*args)
+    assert int(arrays.num_leaves) == 31
+    walk = Tree.from_arrays(arrays).predict_leaf_bins(
+        td.binned.bins, np.asarray(td.binned.nan_bins))
+    np.testing.assert_array_equal(np.asarray(row_leaf), walk)
+
+
 @pytest.mark.parametrize("quantized", [False, True])
 def test_bench_config_auc_parity(quantized):
     """Pin bench-config quality against GENUINE LightGBM (VERDICT r3 weak #2:
