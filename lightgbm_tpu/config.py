@@ -223,11 +223,13 @@ _PARAMS: List[Tuple[str, Any, Any, Tuple[str, ...], Optional[Tuple[Any, Any]]]] 
     # the pack path (bagging/feature-fraction masks move to key-folded
     # device sampling there).
     ("tpu_iter_pack", int, 0, (), (0, 4096)),
-    # Device-resident GOSS (data_sample_strategy=goss): compute the
-    # sampling mask in-trace from the just-computed device gradients —
+    # Device-resident GOSS (data_sample_strategy=goss): select the
+    # sample in-trace from the just-computed device gradients —
     # exact lax.top_k top set (same stable descending tie-break as the
-    # host argsort), key-folded jax.random rest-sample with the exact
-    # (1-top_rate)/other_rate amplification.  The top set matches the
+    # host argsort), key-folded jax.random rest-sample with LightGBM's
+    # (N - top_k) / other_k amplification; on the single-device wave body
+    # the tree is then grown over the in-bag row ids alone
+    # (plan.sampling = subset; sampling.py).  The top set matches the
     # host sampler bit-for-bit under distinct scores; the random rest
     # sample is a DIFFERENT (seed-keyed device) stream than the host
     # np.random one — statistically equivalent, AUC-parity tested.

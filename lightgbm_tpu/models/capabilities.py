@@ -165,8 +165,8 @@ class GrowthPlan:
 
     ``why`` maps each thing the configuration asked for (by a parameter or
     by an ``auto`` default) and this plan refuses — ``"fused"``,
-    ``"scatter"``, ``"pool"``, ``"feature"``, ``"packed4"``, ``"stream"``
-    — to the ONE sentence that refused it."""
+    ``"scatter"``, ``"pool"``, ``"feature"``, ``"packed4"``, ``"stream"``,
+    ``"subset"`` — to the ONE sentence that refused it."""
 
     body: str                   # "mask" | "wave"
     layout: str                 # "single" | "data" | "feature" | "gspmd"
@@ -177,11 +177,17 @@ class GrowthPlan:
     pool: bool                  # bounded leaf-histogram pool
     stream_reason: Optional[str]  # None = the streamed trainer can run it
     why: Mapping[str, str]
+    # the form a row sample takes: "none" (no row sampling), "subset" (the
+    # tree is grown over the in-bag row ids alone) or "mask" (over every
+    # row, the out-of-bag ones weighted 0)
+    sampling: str = "none"
 
     def __str__(self):
         head = (f"body={self.body} layout={self.layout} fused={self.fused} "
                 f"hist_impl={self.hist_impl} packed4={self.packed4} "
                 f"reduce={self.reduce} pool={self.pool}")
+        if self.sampling != "none":
+            head += f" sampling={self.sampling}"
         return head + "".join(f"; no {k}: {v}" for k, v in self.why.items())
 
 
@@ -342,6 +348,22 @@ def plan_growth(cfg, mesh, data_axis: str = "data", *,
                                 f"up to {widest} at {bins} bins {dtype}")
     fused = cfg.wave_kernel != "unfused" and why["fused"] is None
 
+    # ---- row sampling: a sampled tree is grown over the in-bag rows alone
+    # where the selection hands the grower row ids of static length (the
+    # device GOSS selection) and the body is the single-device wave, whose
+    # perm holds row ids; every other composition weights all rows
+    sampler = cfg.sampling
+    if sampler != "none":
+        why["subset"] = _first(
+            (sampler == "goss_host", "the host GOSS sampler "
+             "(tpu_device_goss=off) hands over a row mask"),
+            (sampler != "goss_device", f"{sampler} hands over a row mask"),
+            (mesh is not None, "device mesh: each shard holds its own "
+             "share of the sample, of no static length"),
+            (body != "wave", mask_msg))
+    sampling = ("none" if sampler == "none"
+                else "mask" if why["subset"] else "subset")
+
     # ---- the streamed trainer (lightgbm_tpu/stream/): a host-driven twin
     # of the mask body; every per-split pass must be row-separable
     why["stream"] = _first(
@@ -358,4 +380,5 @@ def plan_growth(cfg, mesh, data_axis: str = "data", *,
     return GrowthPlan(
         body=body, layout=layout, fused=fused, hist_impl=hist_impl,
         packed4=packed4, reduce=reduce, pool=pool,
-        stream_reason=why.get("stream"), why=MappingProxyType(why))
+        stream_reason=why.get("stream"), why=MappingProxyType(why),
+        sampling=sampling)

@@ -25,7 +25,7 @@ import numpy as np
 from ..config import Config
 from ..dataset import TrainData
 from ..metrics import Metric
-from ..telemetry import phase, span, watch_compiles
+from ..telemetry import phase, registry, segment, span, watch_compiles
 from ..objectives import ObjectiveFunction, create_objective
 from ..sampling import FeatureSampler, SampleStrategy
 from ..ops.split import SplitConfig
@@ -354,6 +354,26 @@ class GBDT:
         self._trailing_health = None
         self._health_eval = None
         self._pack_health_pending: List = []
+        self.sample_strategy = SampleStrategy(
+            cfg, train.num_data, train.label, train.query_boundaries())
+        # Device-resident GOSS (tpu_device_goss): "on"/"auto" select the
+        # sample from the just-computed DEVICE gradients — in-trace inside
+        # the fused iteration when it applies, via a standalone device
+        # dispatch under "on" otherwise; "off" (and "auto" on
+        # non-fused-capable configs) replays the reference's host sampler
+        # (np argsort + np.random), pulling gradients to the host.  Which
+        # FORM the sample takes — in-bag row ids the tree is grown over, or
+        # a mask over all rows — is the growth plan's word (plan.sampling).
+        self._device_goss = cfg.tpu_device_goss
+        # (mask, in-bag row ids or None) of the last iteration's sample;
+        # None = it took every row (last_sample())
+        self._last_sample = None
+        if self.sample_strategy.is_goss:
+            top_k, other_k, amp = self.sample_strategy.goss_constants()
+            registry().gauge("sample.top_k").set(top_k)
+            registry().gauge("sample.other_k").set(other_k)
+            registry().gauge("sample.in_bag_rows").set(top_k + other_k)
+            registry().gauge("sample.amplify").set(amp)
         self.grower_cfg = GrowerConfig(
             num_leaves=cfg.num_leaves,
             max_depth=cfg.max_depth,
@@ -388,6 +408,7 @@ class GBDT:
             # halve.  Asked for here; the plan refuses it under EFB and
             # the feature-parallel layout.
             packed4=cfg.tpu_4bit_bins and train.binned.max_num_bins <= 16,
+            sampling=self._row_sampler(),
         )
         # What this configuration runs on this mesh at this shape — body,
         # layout, kernel, reduction, pool — decided in ONE place
@@ -463,15 +484,6 @@ class GBDT:
                     self.bins_dev = jnp.pad(self.bins_dev,
                                             ((0, 0), (0, padf)))
             self.bins_dev = shard_arrays(self.mesh, self.bins_dev)
-        self.sample_strategy = SampleStrategy(
-            cfg, train.num_data, train.label, train.query_boundaries())
-        # Device-resident GOSS (tpu_device_goss): "on"/"auto" compute the
-        # sampling mask from the just-computed DEVICE gradients — in-trace
-        # inside the fused iteration when it applies, via a standalone
-        # device dispatch under "on" otherwise; "off" (and "auto" on
-        # non-fused-capable configs) replays the reference's host sampler
-        # (np argsort + np.random), pulling gradients to the host.
-        self._device_goss = cfg.tpu_device_goss
 
         # CEGB (reference cost_effective_gradient_boosting.hpp): coupled
         # penalties apply on a feature's FIRST use in the model.  The
@@ -530,6 +542,24 @@ class GBDT:
                 phase("boost/gradients")(self.objective.get_gradients))
         self._build_iter_fns()
 
+    def _row_sampler(self) -> str:
+        """The row sampler this configuration runs, as ``GrowerConfig``
+        names it: the device GOSS selection is the one that hands the
+        grower in-bag row ids (an iteration given custom gradients still
+        samples on the host, as a mask)."""
+        cfg, strategy, obj = self.cfg, self.sample_strategy, self.objective
+        if cfg.boosting == "rf":
+            return "rf_bagging" if (strategy.is_goss
+                                    or strategy.is_bagging) else "none"
+        if strategy.is_goss:
+            fusable = (obj is not None and not obj.need_renew_tree_output
+                       and not obj.stochastic_gradients
+                       and not cfg.linear_tree)
+            device = (cfg.tpu_device_goss == "on"
+                      or (cfg.tpu_device_goss == "auto" and fusable))
+            return "goss_device" if device else "goss_host"
+        return "bagging" if strategy.is_bagging else "none"
+
     def _build_iter_fns(self) -> None:
         """Compile the per-iteration programs.  The fused program runs
         objective gradients -> tree growth -> shrinkage -> score update as ONE
@@ -543,7 +573,7 @@ class GBDT:
 
         def grow_apply(bins, scores_k, grad_k, hess_k, mask, fmask, shrink,
                        cegb_coupled=None, cegb_lazy=None, quant_key=None,
-                       split_key=None):
+                       split_key=None, sample_rows=None):
             # bins rides as an ARGUMENT (not a closure): multi-process jit
             # rejects closing over arrays spanning non-addressable devices
             arrays, row_leaf = grow(
@@ -551,7 +581,7 @@ class GBDT:
                 meta["num_bins_per_feature"], meta["nan_bins"],
                 meta["is_categorical"], meta["monotone"],
                 cegb_coupled, cegb_lazy, quant_key, split_key,
-                self._fg_dev, self._fo_dev)
+                self._fg_dev, self._fo_dev, sample_rows)
             with phase("boost/score_update"):
                 grew = arrays.num_leaves > 1
                 lv = jnp.where(grew, arrays.leaf_value * shrink, 0.0)
@@ -586,8 +616,26 @@ class GBDT:
         use_cegb = self._use_cegb
         track_used = use_cegb and bool(self._cegb_coupled_raw.any())
         n_rows = self.train_data.num_data
-        if goss_in_trace:
+        subset = self.plan.sampling == "subset"
+        if strategy.is_goss:
             goss_top_k, goss_other_k, goss_amp = strategy.goss_constants()
+
+            def goss_sample(grad, hess, key, it):
+                """This iteration's sample from its gradients: ``(mask,
+                rows)``, ``rows`` the in-bag ids where the plan grows over
+                the subset and None where it keeps the mask.  |g*h| summed
+                across classes, key folded by the absolute iteration — ONE
+                stream for the fused, the standalone and the packed
+                iteration."""
+                from ..sampling import goss_sample_device
+                with phase("boost/gradients"), segment("sample"):
+                    return goss_sample_device(
+                        grad.reshape(n_rows, -1).sum(axis=1),
+                        hess.reshape(n_rows, -1).sum(axis=1),
+                        jax.random.fold_in(key, it), goss_top_k,
+                        goss_other_k, goss_amp, with_rows=subset)
+
+            self._goss_sample = jax.jit(goss_sample)
         cegb_lazy = self._cegb_lazy_dev if use_cegb else None
         cegb_coupled_raw = self._cegb_coupled_dev if use_cegb else None
         health_active = self._health_active
@@ -596,18 +644,16 @@ class GBDT:
             def fused(bins, scores, mask, fmask, shrink, quant_key=None,
                       split_key=None, it=None, goss_key=None,
                       cegb_used=None):
-                from ..sampling import goss_mask_device
                 with phase("boost/gradients"):
                     grad, hess = obj.get_gradients(scores)
-                    if goss_in_trace:
-                        # Same score/key stream as the standalone device
-                        # mask (_iter_masks): |g*h| summed across classes,
-                        # key folded by the absolute iteration number.
-                        gs = grad.reshape(n_rows, -1).sum(axis=1)
-                        hs = hess.reshape(n_rows, -1).sum(axis=1)
-                        mask = goss_mask_device(
-                            gs, hs, jax.random.fold_in(goss_key, it),
-                            goss_top_k, goss_other_k, goss_amp)
+                rows = sample = None
+                if goss_in_trace and it is not None:
+                    # ``it`` is None in the iterations GOSS leaves
+                    # unsampled (the first int(1 / learning_rate)): the
+                    # caller's full mask stands and this is the plain
+                    # program
+                    mask, rows = sample = goss_sample(grad, hess, goss_key,
+                                                      it)
                 coupled = lazy = None
                 if use_cegb:
                     coupled = cegb_coupled_raw * (~cegb_used)
@@ -623,14 +669,14 @@ class GBDT:
                         ns_k, arrays, row_leaf = grow_apply(
                             bins, new_scores[:, k], grad[:, k], hess[:, k],
                             mask, fmask, shrink, coupled, lazy,
-                            quant_key=qk, split_key=sk)
+                            quant_key=qk, split_key=sk, sample_rows=rows)
                         new_scores = new_scores.at[:, k].set(ns_k)
                         outs.append((arrays, row_leaf))
                 else:
                     new_scores, arrays, row_leaf = grow_apply(
                         bins, scores, grad, hess, mask, fmask, shrink,
                         coupled, lazy, quant_key=quant_key,
-                        split_key=split_key)
+                        split_key=split_key, sample_rows=rows)
                     outs = [(arrays, row_leaf)]
                 hv = None
                 if health_active:
@@ -643,6 +689,7 @@ class GBDT:
                             grad, hess,
                             tuple(a.leaf_value for a, _rl in outs),
                             new_scores)
+                ret = [new_scores, outs]
                 if use_cegb:
                     new_used = cegb_used
                     if track_used:
@@ -650,12 +697,12 @@ class GBDT:
                             new_used = _mark_features_used_trace(
                                 new_used, arrays.split_feature,
                                 arrays.num_leaves)
-                    if health_active:
-                        return new_scores, outs, new_used, hv
-                    return new_scores, outs, new_used
+                    ret.append(new_used)
                 if health_active:
-                    return new_scores, outs, hv
-                return new_scores, outs
+                    ret.append(hv)
+                if sample is not None:
+                    ret.append(sample)     # LAST: what last_sample() reads
+                return tuple(ret)
             self._fused_core = fused      # scanned by the pack path
             # watch_compiles (telemetry/spans.py): launches already run
             # under the train/fused_iter span; the wrapper only notices
@@ -683,23 +730,23 @@ class GBDT:
     def _iter_masks(self, grad=None, hess=None):
         """Device row/feature masks for this iteration (cached when static).
         Returns ``(mask, fmask, grads)`` where ``grads`` is the (g, h) device
-        pair when it had to be computed anyway (GOSS), else None."""
+        pair when it had to be computed anyway (GOSS), else None; leaves
+        the sample — the mask and, from the device selection, the in-bag
+        row ids — in ``_last_sample``."""
         strategy = self.sample_strategy
         n = self.train_data.num_data
-        grads = None
-        if strategy.is_goss:
-            top_k, other_k, amp = strategy.goss_constants()
+        grads = rows = None
+        if strategy.is_goss and not strategy.goss_samples_at(self.iter_):
+            mask_dev = self._full_mask     # goss.hpp: not sampled yet
+        elif strategy.is_goss:
             if grad is None and self._device_goss == "on":
-                # Standalone device GOSS mask (reference goss.hpp:30-60):
+                # Standalone device GOSS sample (reference goss.hpp):
                 # gradients never leave HBM even though this config could
-                # not fuse the mask into the iteration dispatch.
-                from ..sampling import goss_mask_device
+                # not fuse the selection into the iteration dispatch.
                 g_dev, h_dev = self._grad_fn(self.scores)
                 grads = (g_dev, h_dev)
-                gs = g_dev.reshape(n, -1).sum(axis=1)
-                hs = h_dev.reshape(n, -1).sum(axis=1)
-                key = jax.random.fold_in(self._goss_key, self.iter_)
-                mask_dev = goss_mask_device(gs, hs, key, top_k, other_k, amp)
+                mask_dev, rows = self._goss_sample(
+                    g_dev, h_dev, self._goss_key, np.int32(self.iter_))
             elif grad is None:
                 # Host sampler (tpu_device_goss=off, or auto on a config
                 # whose objective already needs per-round host access):
@@ -722,7 +769,28 @@ class GBDT:
             mask_dev = self._bag_mask_dev
         else:
             mask_dev = self._full_mask
+        self._last_sample = (None if mask_dev is self._full_mask
+                             else (mask_dev, rows))
         return mask_dev, self._tree_fmask(), grads
+
+    def last_sample(self):
+        """The rows the last ``train_one_iter`` grew its trees on:
+        ``None`` when it took every row at weight 1, else ``(rows,
+        weights)`` as numpy arrays — the in-bag row ids ascending and each
+        one's multiplier of its gradient and hessian (1, or GOSS's
+        amplification for a drawn row).  What a comparison that recomputes
+        a sampled tree is handed (``benchmark/compare_sampled.py``)."""
+        if self._last_sample == "packed":
+            raise RuntimeError("the packed iterations keep no sample; "
+                               "run the one to be read with update()")
+        if self._last_sample is None:
+            return None
+        mask, rows = jax.device_get(self._last_sample)
+        mask = np.asarray(mask)
+        # a slot of the device selection no row filled holds N
+        rows = (np.flatnonzero(mask > 0) if rows is None
+                else rows[rows < mask.shape[0]])
+        return rows.astype(np.int64), mask[rows]
 
     def _tree_fmask(self) -> jnp.ndarray:
         """This iteration's feature mask — the ONE derivation shared by
@@ -785,14 +853,19 @@ class GBDT:
             # exact poison the health sentinel exists to catch
             self._poison_scores()
         used_fused = grad is None and self.fused_path_active
-        goss_in_fused = used_fused and self.sample_strategy.is_goss
+        sampled = self.sample_strategy.goss_samples_at(self.iter_)
+        if self.sample_strategy.is_goss and not sampled:
+            registry().counter("sample.unsampled_iters").inc()
+        goss_in_fused = used_fused and sampled
         if goss_in_fused:
             # The GOSS mask is derived IN-TRACE from the fused iteration's
             # own gradients — no standalone mask dispatch, no host pull.
             mask_dev, goss_grads = self._full_mask, None
             fmask = self._tree_fmask()
+            rows = None
         else:
             mask_dev, fmask, goss_grads = self._iter_masks(grad, hess)
+            rows = self._last_sample and self._last_sample[1]
         shrink = cfg.learning_rate if cfg.boosting != "rf" else 1.0
         qkey = (jax.random.fold_in(self._quant_key, self.iter_)
                 if self._quant_key is not None else None)
@@ -809,6 +882,9 @@ class GBDT:
             out = self._dispatch(
                 "_fused_iter", self.bins_dev, self.scores, mask_dev,
                 fmask, shrink, qkey, skey, it_arg, gkey, used0)
+            self._last_sample = None
+            if goss_in_fused:
+                *out, self._last_sample = out
             if self._health_active:
                 *out, self._health_pending = out
             if self._use_cegb:
@@ -838,7 +914,7 @@ class GBDT:
                       else jax.random.fold_in(skey, k))
                 if cfg.linear_tree:
                     arrays, row_leaf = self._dispatch(
-                        "_raw_grow", gk, hk, mask_dev, fmask, qk, nk)
+                        "_raw_grow", gk, hk, mask_dev, fmask, qk, nk, rows)
                     new_sk = self._fit_and_store_linear(
                         k, arrays, row_leaf, gk, hk, mask_dev, sk, shrink)
                     if self._shape_k:
@@ -849,7 +925,7 @@ class GBDT:
                 if (self.objective is not None
                         and self.objective.need_renew_tree_output):
                     arrays, row_leaf = self._dispatch(
-                        "_raw_grow", gk, hk, mask_dev, fmask, qk, nk)
+                        "_raw_grow", gk, hk, mask_dev, fmask, qk, nk, rows)
                     arrays = self._renew_and_shrink(arrays, row_leaf, sk,
                                                     shrink)
                     new_sk = _add_leaf_outputs(sk, row_leaf,
@@ -858,11 +934,13 @@ class GBDT:
                     coupled = self._cegb_coupled_dev * (~self._cegb_used_dev)
                     new_sk, arrays, row_leaf = self._dispatch(
                         "_grow_apply", self.bins_dev, sk, gk, hk, mask_dev,
-                        fmask, shrink, coupled, self._cegb_lazy_dev, qk, nk)
+                        fmask, shrink, coupled, self._cegb_lazy_dev, qk, nk,
+                        rows)
                 else:
                     new_sk, arrays, row_leaf = self._dispatch(
                         "_grow_apply", self.bins_dev, sk, gk, hk, mask_dev,
-                        fmask, shrink, quant_key=qk, split_key=nk)
+                        fmask, shrink, quant_key=qk, split_key=nk,
+                        sample_rows=rows)
                 if self._shape_k:
                     self.scores = self.scores.at[:, k].set(new_sk)
                 else:
@@ -1027,6 +1105,7 @@ class GBDT:
         use_quant = self._quant_key is not None
         use_split = self._split_key is not None
         use_goss = strategy.is_goss          # pack-capable => device GOSS
+        goss_unsampled = strategy.goss_unsampled_iters
         use_cegb = self._use_cegb
         health_active = self._health_active
         from ..sampling import bagging_mask_device, feature_mask_device
@@ -1047,10 +1126,21 @@ class GBDT:
                 # bag_key IS the GOSS key (PRNGKey(bagging_seed), folded
                 # by the absolute iteration in-trace — the same stream the
                 # per-round fused iteration uses, so K is scheduling-only).
-                out = core(bins, sc, mask, fmask, shrink, qk, sk,
-                           it=it if use_goss else None,
-                           goss_key=bag_key if use_goss else None,
-                           cegb_used=used)
+                def run(goss_it):
+                    out = core(bins, sc, mask, fmask, shrink, qk, sk,
+                               it=goss_it,
+                               goss_key=(None if goss_it is None
+                                         else bag_key),
+                               cegb_used=used)
+                    # a sampled iteration's last output is its sample
+                    return out if goss_it is None else out[:-1]
+
+                # the iterations GOSS leaves unsampled run the plain
+                # program, the later ones the sampled one: both live in
+                # the scanned body, the iteration number picks
+                out = (jax.lax.cond(it < goss_unsampled,
+                                    lambda: run(None), lambda: run(it))
+                       if use_goss else run(None))
                 hv = None
                 if health_active:
                     *out, hv = out
@@ -1119,6 +1209,12 @@ class GBDT:
             # this pack poisons from the pack's first round (faults.py)
             self._poison_scores()
         shrink = cfg.learning_rate if cfg.boosting != "rf" else 1.0
+        self._last_sample = None
+        if self.sample_strategy.is_goss:
+            self._last_sample = "packed"
+            registry().counter("sample.unsampled_iters").inc(max(0, min(
+                self.iter_ + k, self.sample_strategy.goss_unsampled_iters)
+                - self.iter_))
         base_fmask = (self._fmask_static if self._fmask_static is not None
                       else jnp.asarray(self.feature_sampler.used))
         args = (self.bins_dev, self.scores, np.int32(self.iter_), shrink,
@@ -1405,13 +1501,13 @@ class GBDT:
             return getattr(self, name)(*args, **kw)
 
     def _raw_grow(self, gk, hk, mask_dev, fmask, quant_key=None,
-                  split_key=None):
+                  split_key=None, sample_rows=None):
         return self.grow(
             self.bins_dev, gk, hk, mask_dev, fmask,
             self.meta_dev["num_bins_per_feature"], self.meta_dev["nan_bins"],
             self.meta_dev["is_categorical"], self.meta_dev["monotone"],
             None, None, quant_key, split_key,
-            self._fg_dev, self._fo_dev)
+            self._fg_dev, self._fo_dev, sample_rows)
 
     def _renew_and_shrink(self, arrays: TreeArrays, row_leaf, scores_k,
                           shrink: float) -> TreeArrays:

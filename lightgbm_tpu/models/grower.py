@@ -86,7 +86,7 @@ from ..ops.split import (BestSplit, SplitConfig, _prefix_sum, _resolve_tile,
                          best_split, leaf_gain, leaf_output, smoothed_output,
                          sync_best_split)
 from ..telemetry.registry import registry
-from ..telemetry.spans import kernel_rows, phase
+from ..telemetry.spans import kernel_rows, phase, segment
 from .capabilities import PERM_MIN_ROWS as _MIN_BUCKET
 from .capabilities import plan_growth
 
@@ -123,6 +123,11 @@ class GrowerConfig:
     # sequential-step count — the TPU-shaped analog of the CUDA learner's
     # per-leaf kernel pipeline (cuda_single_gpu_tree_learner.cpp:174).
     leaf_batch: int = 1
+    # The row sampler beside this grower: "none", "goss_device" (the device
+    # selection hands ``grow`` the in-bag row ids, ``sample_rows``),
+    # "goss_host" or "bagging" (a row mask).  The growth plan turns it into
+    # the form a sampled tree is grown in (``plan.sampling``).
+    sampling: str = "none"
     # Quantized training (reference GradientDiscretizer,
     # gradient_discretizer.hpp:128): int8 grad/hess levels, int32 histogram
     # accumulation, per-iteration scales; see ops/quantize.py.
@@ -498,6 +503,25 @@ def _row_leaf_map(leaf_start, leaf_rows, num_leaves, perm, n, sentinel):
         jnp.diff(order, prepend=0), mode="drop")
     pos_leaf = _offset_rows(_prefix_sum(change.reshape(-1, 128)))[:n]
     return jnp.zeros(n, jnp.int32).at[perm[:n]].set(pos_leaf)
+
+
+def _route_rows(row_leaf, go_left, leaf_j, newleaf_j):
+    """One wave of the dense row -> leaf update, over EVERY row
+    (``row_leaf`` is as long as the bit table's rows, ``n + 1`` with the
+    phantom row, so the table is read in place): a row of leaf
+    ``leaf_j[j]`` whose go-left bit ``j`` (``_go_left_bits``' table over
+    all rows, by row id) is clear moves to ``newleaf_j[j]``; every other
+    row stays.  W compares and selects a row in one elementwise pass, no
+    gather — so the rows a sampled tree was not grown on reach their leaf
+    at the price of W dense column reads a wave (0.018 s/iter at 2.27 M
+    rows on a v5e; a per-row walk of the finished tree costs a gather a
+    level, 0.4-0.9 s/iter there: PERF.md, Findings PR 33)."""
+    words = go_left.reshape(-1, row_leaf.shape[0])
+    new = row_leaf
+    for j in range(leaf_j.shape[0]):
+        right = ((words[j >> 5] >> (j & 31)) & 1) == 0
+        new = jnp.where((row_leaf == leaf_j[j]) & right, newleaf_j[j], new)
+    return new
 
 
 def _pack_bits(bits):
@@ -1879,7 +1903,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
 
     # ------------------------------------------------------------------ wave path
     def _grow_wave(bins, vals, scale3, feature_mask, meta, plan, cegb=None,
-                   key=None, axis=None, faxis=None):
+                   key=None, axis=None, faxis=None, rows=None):
         """Wave growth (permutation layout): split the top-W leaves per step.
         THE permutation-layout body: single device, per shard under
         ``shard_map`` when ``axis`` names the mesh data axis, or
@@ -1902,9 +1926,31 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         rate (my chip runs, PR 28; PERF.md section 5) — so no path pads a
         wave beyond the rows it holds.  Sequential depth
         per tree drops from num_leaves-1 steps to
-        ~ceil((num_leaves-1)/W)."""
-        n, gcols = bins.shape
+        ~ceil((num_leaves-1)/W).
+
+        ``rows`` (single device only) = the in-bag row ids of a sampled
+        tree, ``plan.sampling == "subset"``.  The sample's bins and values
+        are copied out ONCE (the reference's ``Dataset::CopySubrow``) and
+        the tree is grown on that copy as on a data set of its own: every
+        pass above is sized by the in-bag count and reads rows that lie
+        side by side (a gather by sparse row id reads 13-20 ns a row on a
+        v5e where one by dense position reads 7; PERF.md, Findings PR 33).
+        Every row's leaf — out-of-bag rows included — comes from
+        ``_route_rows`` wave by wave, off the wave's splits applied to the
+        feature-major copy of ALL rows, instead of from the final
+        ``perm``."""
         f = meta[0].shape[0]
+        route = rows is not None
+        if route:
+            assert axis is None and faxis is None
+            with phase("grow/setup"):
+                # a slot no row filled holds bins.shape[0]: a zero row
+                bins_fm_all = jnp.pad(bins, ((0, 1), (0, 0))).T
+                bins = jnp.take(bins, rows, axis=0, mode="fill",
+                                fill_value=0)
+                vals = jnp.take(vals, rows, axis=0, mode="fill",
+                                fill_value=0)
+        n, gcols = bins.shape
         W = min(cfg.leaf_batch, max(L - 1, 1))
         assert W == 1 or not (n_forced or faxis), (W, n_forced, faxis)
         voting = plan.reduce == "vote"
@@ -2075,7 +2121,9 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                         [payload[:, 0], payload[:, 1]], axis=0))
                 return child[:, 0], child[:, 1], bs
 
-        def body(st: _GrowState) -> _GrowState:
+        def step(st: _GrowState, row_leaf=None):
+            """One wave; ``row_leaf`` (a sampled tree only) is every row's
+            leaf so far, and is returned beside the state."""
             if n_forced:
                 st, use_f, si = _apply_forced(
                     st, scale3, meta,
@@ -2161,6 +2209,12 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                                             dlefts, scats, cmasks)
                 perm, nl_phys = _partition_wave(st.perm, starts, cnts,
                                                 go_left, n)
+                if route:
+                    with segment("oob_route"):
+                        row_leaf = _route_rows(
+                            row_leaf, _go_left_bits(
+                                cfg, bins_fm_all, meta, feats, sbins, dlefts,
+                                scats, cmasks), leaf_j, newleaf_j)
 
             with phase("grow/select"):
                 if axis is None:
@@ -2408,7 +2462,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 # the START of the next step, so a forced split is never
                 # lost (test_forced_splits_survive_intermediate_monotone).
                 return _inter_refresh(st, scale3, meta, feature_mask, cegb,
-                                      groups_mat)
+                                      groups_mat), row_leaf
 
             # ---- best splits for all 2W children in one vmapped search
             # (already computed IN the kernel on the fused path)
@@ -2463,7 +2517,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                         bs.sum_hess_left, mode="drop"),
                     best_cl=st.best_cl.at[idx2].set(
                         bs.count_left, mode="drop"),
-                )
+                ), row_leaf
 
         def cond(st: _GrowState):
             room = st.num_leaves < L
@@ -2472,8 +2526,14 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 more = more | (st.num_leaves - 1 < n_forced)
             return room & more
 
-        state = jax.lax.while_loop(cond, body, state)
-        return _finish(state), _row_leaf_from_perm(state, n, max_bucket)
+        if not route:
+            state = jax.lax.while_loop(cond, lambda st: step(st)[0], state)
+            return _finish(state), _row_leaf_from_perm(state, n, max_bucket)
+        n_all = bins_fm_all.shape[1] - 1
+        state, row_leaf = jax.lax.while_loop(
+            lambda c: cond(c[0]), lambda c: step(*c),
+            (state, jnp.zeros(n_all + 1, jnp.int32)))
+        return _finish(state), row_leaf[:n_all]
 
     # ------------------------------------------------------------------ mask path
     def _grow_mask(bins, vals, scale3, feature_mask, meta, plan, cegb=None,
@@ -2722,6 +2782,9 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                                                      # (extra_trees / bynode)
         feat_group: Optional[jnp.ndarray] = None,    # (F,) i32 (EFB)
         feat_offset: Optional[jnp.ndarray] = None,   # (F,) i32 (EFB)
+        sample_rows: Optional[jnp.ndarray] = None,   # (K,) i32 in-bag row
+                                                     # ids (plan.sampling
+                                                     # == "subset")
     ) -> Tuple[TreeArrays, jnp.ndarray]:
         meta = (num_bins_per_feature, nan_bins, is_categorical, monotone)
         if cfg.bundled:
@@ -2808,8 +2871,12 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             tree, row_leaf = _grow_sharded(bins, vals, scale3, feature_mask,
                                            meta, plan, cegb, split_key)
         elif plan.body == "wave":
+            if sample_rows is not None and plan.sampling != "subset":
+                raise ValueError(f"sample_rows handed to a grower whose "
+                                 f"plan keeps the mask: {plan}")
             tree, row_leaf = _grow_wave(bins, vals, scale3, feature_mask,
-                                        meta, plan, cegb, split_key)
+                                        meta, plan, cegb, split_key,
+                                        rows=sample_rows)
         else:
             if cfg.packed4:
                 # the mask body (tiny row counts / no-gather) indexes

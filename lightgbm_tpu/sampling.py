@@ -5,10 +5,28 @@ Reference: ``SampleStrategy`` factory (``include/LightGBM/sample_strategy.h:23``
 (``bagging.hpp``) and ``GOSSStrategy`` (``goss.hpp``).
 
 TPU re-design: the reference materializes index subsets and copies rows
-(``Dataset::CopySubrow``); here sampling is a **multiplicative row mask** so every
-shape stays static under jit — out-of-bag rows contribute zero gradient/hessian
-and zero count to histograms, which is numerically identical.  GOSS's amplification
-``(1-top_rate)/other_rate`` becomes a per-row weight in the same mask.
+(``Dataset::CopySubrow``).  Here a sample has TWO forms, and the growth plan
+(``models/capabilities.plan_growth``, ``plan.sampling``) says which one a
+composition runs:
+
+- ``subset`` — device GOSS on the single-device wave body (the permutation
+  layout): the selection yields the in-bag ROW IDS at their static length
+  ``top_k + other_k`` beside the weights, and the tree is grown over those
+  rows alone — their bins and values are copied out once a tree (the
+  reference's ``CopySubrow``), so the root histogram, the partition's ladder
+  and every per-leaf gather are sized by the in-bag count; out-of-bag rows
+  reach their leaf for the score update by a dense per-wave update of a
+  full-length row -> leaf vector (``models/grower.py``).
+- ``mask`` — everything else (a device mesh, the mask body under 2 048 rows
+  a shard, the host sampler ``tpu_device_goss=off``, plain and balanced
+  bagging, the streamed trainer): a **multiplicative row mask**, every
+  shape static at ``N`` — out-of-bag rows contribute zero gradient/hessian
+  and zero count to histograms, which is numerically identical and costs a
+  full-row growth.
+
+Either way GOSS's amplification ``(N - top_k) / other_k`` is a per-row
+weight, and the first ``int(1 / learning_rate)`` iterations take every row
+(``goss.hpp``: ``if (iter < static_cast<int>(1.0f / learning_rate)) return``).
 """
 
 from __future__ import annotations
@@ -39,6 +57,15 @@ class SampleStrategy:
         self.is_balanced = balanced and not self.is_goss
         self._cached: Optional[np.ndarray] = None
 
+    @property
+    def goss_unsampled_iters(self) -> int:
+        """Iterations GOSS leaves unsampled: LightGBM's
+        ``int(1 / learning_rate)`` (``goss.hpp``)."""
+        return int(1.0 / self.cfg.learning_rate)
+
+    def goss_samples_at(self, iteration: int) -> bool:
+        return self.is_goss and iteration >= self.goss_unsampled_iters
+
     def needs_resample(self, iteration: int) -> bool:
         if self.is_goss:
             return True
@@ -51,6 +78,8 @@ class SampleStrategy:
              hess: Optional[np.ndarray] = None) -> Optional[np.ndarray]:
         """Return the (N,) f32 mask for this iteration, or None (all rows)."""
         if self.is_goss:
+            if not self.goss_samples_at(iteration):
+                return None
             return self._goss_mask(grad, hess)
         if not self.is_bagging:
             return None
@@ -81,13 +110,13 @@ class SampleStrategy:
 
     def goss_constants(self):
         """(top_k, other_k, amplification) — shared by the host and device
-        GOSS paths (reference goss.hpp:30-60)."""
+        GOSS paths (reference goss.hpp: ``multiply = (cnt - top_k) /
+        other_k``)."""
         cfg = self.cfg
         n = self.num_data
         top_k = max(int(n * cfg.top_rate), 1)
         other_k = int(n * cfg.other_rate)
-        amp = ((1.0 - cfg.top_rate) / cfg.other_rate
-               if cfg.other_rate > 0 else 0.0)
+        amp = (n - top_k) / other_k if other_k > 0 else 0.0
         return top_k, other_k, amp
 
     def _goss_mask(self, grad: np.ndarray, hess: np.ndarray) -> np.ndarray:
@@ -96,7 +125,7 @@ class SampleStrategy:
         cfg = self.cfg
         n = self.num_data
         score = np.abs(grad * hess)
-        top_k, other_k, _amp = self.goss_constants()
+        top_k, other_k, amp = self.goss_constants()
         order = np.argsort(-score, kind="stable")
         mask = np.zeros(n, np.float32)
         mask[order[:top_k]] = 1.0
@@ -104,22 +133,30 @@ class SampleStrategy:
         if len(rest) > 0 and other_k > 0 and cfg.other_rate > 0:
             pick = self.rng.choice(len(rest), size=min(other_k, len(rest)),
                                    replace=False)
-            mask[rest[pick]] = (1.0 - cfg.top_rate) / cfg.other_rate
+            mask[rest[pick]] = amp
         return mask
 
 
-def goss_mask_device(grad_sum, hess_sum, key, top_k: int, other_k: int,
-                     amplify: float):
-    """Device-resident GOSS (reference ``goss.hpp:30-60``) — no host
-    round-trip: exact top-k by |grad*hess|, gumbel-style uniform top-k for
-    the random remainder, amplification folded into the mask."""
+def goss_sample_device(grad_sum, hess_sum, key, top_k: int, other_k: int,
+                       amplify: float, with_rows: bool = True):
+    """Device-resident GOSS (reference ``goss.hpp``) — no host round-trip:
+    exact top-k by ``|grad * hess|``, gumbel-style uniform top-k over a
+    fresh draw for the random remainder.  Returns ``(mask, rows)``: the
+    (N,) weights (1 / ``amplify`` / 0) and, ``with_rows``, the in-bag row
+    ids ascending at their static length ``top_k + other_k`` — the two
+    ``lax.top_k`` hand their indices over with their values, so the ids
+    cost one sort of the sample and no pass over all rows (on a v5e at
+    2.27 M rows the two ``top_k`` take 1.4 ms; compacting a mask instead
+    takes 4.2 ms by a sort and 13.9 by prefix count + scatter: PERF.md,
+    Findings PR 33).  A slot no row fills (fewer than ``other_k`` rows
+    outside the top set) holds ``N``, the growers' phantom zero row."""
     import jax
     import jax.numpy as jnp
 
     n = grad_sum.shape[0]
     score = jnp.abs(grad_sum * hess_sum)
-    _, top_idx = jax.lax.top_k(score, top_k)
-    mask = jnp.zeros(n, jnp.float32).at[top_idx].set(1.0)
+    _, rows = jax.lax.top_k(score, top_k)
+    mask = jnp.zeros(n, jnp.float32).at[rows].set(1.0)
     if other_k > 0:
         u = jax.random.uniform(key, (n,))
         u = jnp.where(mask > 0.0, -1.0, u)       # exclude the top set
@@ -128,7 +165,15 @@ def goss_mask_device(grad_sum, hess_sum, key, top_k: int, other_k: int,
         # other_k)
         tgt = jnp.where(sel_vals >= 0.0, sel_idx, n)
         mask = mask.at[tgt].set(jnp.float32(amplify), mode="drop")
-    return mask
+        rows = jnp.concatenate([rows, tgt])
+    return mask, (jnp.sort(rows).astype(jnp.int32) if with_rows else None)
+
+
+def goss_mask_device(grad_sum, hess_sum, key, top_k: int, other_k: int,
+                     amplify: float):
+    """The mask form of ``goss_sample_device``."""
+    return goss_sample_device(grad_sum, hess_sum, key, top_k, other_k,
+                              amplify, with_rows=False)[0]
 
 
 def _rank_select_device(u, valid, k):

@@ -320,7 +320,8 @@ class StreamTrainer:
         import jax
         g = self.g
         strategy = g.sample_strategy
-        if strategy.is_goss and self._device_goss:
+        if (self._device_goss
+                and strategy.goss_samples_at(g.iter_)):
             from ..sampling import goss_mask_device
             n = g.train_data.num_data
             g_dev, h_dev = g._grad_fn(g.scores)
@@ -433,7 +434,10 @@ class StreamTrainer:
                 if g._quant_key is not None else None)
         skey = (jax.random.fold_in(g._split_key, g.iter_)
                 if g._split_key is not None else None)
+        # goss residency holds the SAMPLED slice: the iterations GOSS
+        # leaves unsampled stream every chunk like any unsampled round
         grow = (self._grow_goss if self.residency == "goss"
+                and g.sample_strategy.goss_samples_at(g.iter_)
                 else self._grow_chunked)
         results = []
         for k in range(g.num_class):

@@ -38,12 +38,13 @@ from .memory import (MEMORY_MODES, MemoryTracker, arm_memory_from_config,
                      set_memory_mode)
 from .prometheus import render_prometheus
 from .registry import (Counter, Gauge, Histogram, MetricsRegistry, registry)
-from .spans import (PHASES, enabled, instrument, kernel_rows, phase,
-                    reset_spans, set_enabled, span, span_totals,
-                    watch_compiles)
+from .spans import (PHASES, SEGMENTS, enabled, instrument, kernel_rows,
+                    phase, reset_spans, segment, set_enabled, span,
+                    span_totals, watch_compiles)
 
 __all__ = [
-    "MEMORY_MODES", "PHASES", "SCHEMA_VERSION", "Counter", "Gauge",
+    "MEMORY_MODES", "PHASES", "SEGMENTS", "SCHEMA_VERSION", "Counter",
+    "Gauge",
     "Histogram",
     "JsonlSink", "MemoryTracker", "MetricsRegistry", "TrainTelemetry",
     "active_sink", "arm_from_config", "arm_memory_from_config",
@@ -51,7 +52,7 @@ __all__ = [
     "host_peak_rss_mb", "instrument", "kernel_rows", "live_buffer_census",
     "memory_analysis_summary", "memory_block", "memory_mode",
     "note_compile", "phase", "registry", "render_prometheus", "reset_spans",
-    "set_enabled", "set_memory_mode", "span", "span_totals",
+    "segment", "set_enabled", "set_memory_mode", "span", "span_totals",
     "telemetry_block", "train_session", "watch_compiles",
 ]
 

@@ -48,6 +48,14 @@ PHASES = (
     "grow/reduce", "grow/update", "grow/finish",
 )
 
+# Named parts INSIDE a phase (``with phase(..), segment(..):``): a scope
+# path segment under the phase's own, so the phase keeps the time and a
+# reader that looks for the segment finds the part.  ``sample``: the row
+# sampler's selection (under ``boost/gradients``); ``oob_route``: the dense
+# per-wave row -> leaf update that gives out-of-bag rows their leaf (under
+# ``grow/partition``).
+SEGMENTS = ("sample", "oob_route")
+
 _local = threading.local()
 
 
@@ -67,6 +75,16 @@ def phase(name: str):
     in ``PHASES``."""
     if name not in PHASES:
         raise ValueError(f"phase {name!r} is not in telemetry.PHASES")
+    import jax
+    return jax.named_scope(name)
+
+
+def segment(name: str):
+    """``with phase("grow/partition"), segment("oob_route"):`` — one more
+    path segment under the enclosing phase.  Refuses a name that is not in
+    ``SEGMENTS``."""
+    if name not in SEGMENTS:
+        raise ValueError(f"segment {name!r} is not in telemetry.SEGMENTS")
     import jax
     return jax.named_scope(name)
 

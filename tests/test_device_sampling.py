@@ -2,7 +2,7 @@
 
 Device GOSS (``tpu_device_goss``): the in-trace mask's top set must match
 the host sampler's bit-for-bit under distinct scores and carry the exact
-``(1-top_rate)/other_rate`` amplification; the random rest-sample is a
+``(N - top_k) / other_k`` amplification; the random rest-sample is a
 different (seed-keyed device) stream than the host ``np.random`` one, so
 end-to-end quality is pinned by AUC parity, not bitwise equality.
 
@@ -58,7 +58,9 @@ class TestDeviceGoss:
                       "verbosity": -1})
         strat = SampleStrategy(cfg, n)
         top_k, other_k, amp = strat.goss_constants()
-        host = strat.mask(0, grad, hess)
+        # GOSS leaves the first int(1 / learning_rate) iterations unsampled
+        assert strat.mask(strat.goss_unsampled_iters - 1, grad, hess) is None
+        host = strat.mask(strat.goss_unsampled_iters, grad, hess)
         dev = np.asarray(goss_mask_device(
             jnp.asarray(grad), jnp.asarray(hess), jax.random.PRNGKey(9),
             top_k, other_k, amp))
@@ -81,8 +83,10 @@ class TestDeviceGoss:
         fused program disabled) share one key stream and must produce
         bitwise-identical trees."""
         X, y = _data()
+        # learning_rate 0.5: GOSS samples from iteration int(1 / 0.5) = 2
         params = {"objective": "binary", "num_leaves": 15, "verbosity": -1,
-                  "data_sample_strategy": "goss", "metric": "none"}
+                  "data_sample_strategy": "goss", "metric": "none",
+                  "learning_rate": 0.5}
         fused = lgb.Booster(params=params, train_set=lgb.Dataset(X, label=y))
         standalone = _unfuse(lgb.Booster(
             params=dict(params, tpu_device_goss="on"),
@@ -92,6 +96,10 @@ class TestDeviceGoss:
             standalone.update()
         assert fused._gbdt.fused_path_active is True
         assert standalone._gbdt.fused_path_active is False
+        assert fused._gbdt.last_sample() is not None
+        for a, b in zip(fused._gbdt.last_sample(),
+                        standalone._gbdt.last_sample()):
+            np.testing.assert_array_equal(a, b)
         for tf, ts in zip(fused._gbdt.models[0], standalone._gbdt.models[0]):
             assert tf.num_leaves == ts.num_leaves
             k = max(tf.num_leaves - 1, 0)

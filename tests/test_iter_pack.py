@@ -60,7 +60,8 @@ def _assert_identical(b1, b4, scores_exact=True):
     {"feature_fraction": 0.6},                             # device col mask
     {"bagging_fraction": 0.8, "bagging_freq": 1,
      "feature_fraction": 0.7},                             # both dynamic
-    {"data_sample_strategy": "goss"},                      # in-trace GOSS
+    {"data_sample_strategy": "goss",
+     "learning_rate": 0.5},           # in-trace GOSS, sampled from round 2
     {"cegb_tradeoff": 0.5, "cegb_penalty_split": 0.02,
      "cegb_penalty_feature_coupled": [2.0] * 8},           # in-trace CEGB
 ], ids=["binary", "bagging", "feature_fraction", "bagging+ff", "goss",

@@ -213,8 +213,9 @@ def test_streamed_bitwise_fp32_multichunk(data, store):
     ({"use_quantized_grad": True}, "quantized"),
     ({"max_bin": 15}, "packed4"),
     ({"tpu_iter_pack": 4}, "iter_pack_k4"),
-    ({"data_sample_strategy": "goss", "use_quantized_grad": True},
-     "goss_quantized"),
+    # learning_rate 0.5: GOSS samples from iteration int(1 / 0.5) = 2 on
+    ({"data_sample_strategy": "goss", "use_quantized_grad": True,
+      "learning_rate": 0.5}, "goss_quantized"),
     ({"use_quantized_grad": True, "max_bin": 15, "tpu_iter_pack": 4},
      "quantized_packed4_pack"),
 ])
@@ -264,13 +265,16 @@ def test_streamed_goss_fp32_ulp(data, tmp_path):
     (see _assert_structure_ulp — the quantized GOSS cell in the matrix
     above is the bitwise pin)."""
     X, y = data
-    params = dict(BASE_PARAMS, num_leaves=7, data_sample_strategy="goss")
+    # learning_rate 0.5: two unsampled rounds (goss.hpp), two sampled
+    params = dict(BASE_PARAMS, num_leaves=7, data_sample_strategy="goss",
+                  learning_rate=0.5)
     store = dataset_to_shards(Dataset(X, label=y, params=params),
                               str(tmp_path / "gf"), rows_per_shard=512,
                               params=params)
     rounds = 4
     ref = _incore(params, X, y, rounds)
     st = train_streamed(_stream_params(extra={"num_leaves": 7,
+                                              "learning_rate": 0.5,
                                               "data_sample_strategy":
                                               "goss"}),
                         store, num_boost_round=rounds)
@@ -283,7 +287,7 @@ def test_streamed_goss_residency_mode(data, tmp_path):
     in-core GOSS training bitwise on the (non-stochastic) quantized wire
     and to 1 ULP on fp32."""
     X, y = data
-    params = dict(BASE_PARAMS, num_leaves=7,
+    params = dict(BASE_PARAMS, num_leaves=7, learning_rate=0.5,
                   data_sample_strategy="goss",
                   use_quantized_grad=True, stochastic_rounding=False)
     store = dataset_to_shards(Dataset(X, label=y, params=params),
@@ -291,7 +295,7 @@ def test_streamed_goss_residency_mode(data, tmp_path):
                               params=params)
     rounds = 4
     ref = _incore(params, X, y, rounds)
-    sp = _stream_params(extra={"num_leaves": 7,
+    sp = _stream_params(extra={"num_leaves": 7, "learning_rate": 0.5,
                                "data_sample_strategy": "goss",
                                "use_quantized_grad": True,
                                "stochastic_rounding": False,

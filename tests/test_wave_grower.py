@@ -65,7 +65,7 @@ def test_wave_respects_budget_and_quality_small_tree():
 def test_wave_with_bagging_goss_quantized():
     X, y = _data(n=5000)
     for extra in ({"bagging_fraction": 0.7, "bagging_freq": 1},
-                  {"data_sample_strategy": "goss"},
+                  {"data_sample_strategy": "goss", "learning_rate": 0.5},
                   {"use_quantized_grad": True}):
         p = dict(BASE, tpu_leaf_batch=4, **extra)
         bst = lgb.train(p, lgb.Dataset(X, label=y), 8)

@@ -74,14 +74,19 @@ _DESCRIPTIONS = {
         "boosting rounds fused into one scanned XLA dispatch "
         "(docs/ITER_PACK.md); 0 = auto-pack when results cannot change"),
     "tpu_device_goss": (
-        "GOSS sampling residency: auto|on|off — auto/on derive the mask "
+        "GOSS sampling residency: auto|on|off — auto/on select the sample "
         "in-trace from the device gradients (exact lax.top_k top set with "
-        "the host sampler's tie-break, key-folded rest-sample with the "
-        "exact (1-top_rate)/other_rate amplification), keeping a GOSS "
-        "round ONE compiled dispatch and pack-capable; the rest-sample "
-        "RNG stream differs from the host np.random one (statistically "
-        "equivalent, AUC-parity pinned); off = reference host sampler "
-        "(np argsort + np.random), pulling gradients each round"),
+        "the host sampler's tie-break, key-folded rest-sample with "
+        "LightGBM's (N - top_k) / other_k amplification), keeping a GOSS "
+        "round ONE compiled dispatch and pack-capable; on the "
+        "single-device wave body the selection hands the grower the "
+        "in-bag row ids and the tree is grown over those rows alone "
+        "(plan.sampling = subset), elsewhere over a mask of all rows; the "
+        "rest-sample RNG stream differs from the host np.random one "
+        "(statistically equivalent, AUC-parity pinned); off = reference "
+        "host sampler (np argsort + np.random), pulling gradients each "
+        "round, always a mask. Either way the first int(1 / "
+        "learning_rate) iterations take every row (goss.hpp)"),
     "tpu_native_predict_max_rows": (
         "predict batches up to this many rows take the native C++ host "
         "traversal; larger batches go through the compiled serve plan "

@@ -25,7 +25,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # dynamically attribute-resolved at call time, so wrapping the attribute
 # intercepts every launch): the fused iteration, the grow+apply program,
 # the bare grower, and the objective-gradient program.
-_DISPATCH_ATTRS = ("_fused_iter", "_grow_apply", "grow", "_grad_fn")
+_DISPATCH_ATTRS = ("_fused_iter", "_grow_apply", "grow", "_grad_fn",
+                   "_goss_sample")
 
 
 def _count_dispatches_and_syncs(bst, iters):
@@ -38,10 +39,14 @@ def _count_dispatches_and_syncs(bst, iters):
     import jax
 
     import lightgbm_tpu.models.gbdt as gbdt_mod
-    import lightgbm_tpu.sampling as sampling_mod
 
-    bst.update()                                    # compile outside census
     gbdt = bst._gbdt
+    # compile outside the census: one round, and where GOSS leaves its
+    # first int(1 / learning_rate) rounds unsampled, the first sampled one
+    strategy = gbdt.sample_strategy
+    for _ in range(1 + (strategy.goss_unsampled_iters if strategy.is_goss
+                        else 0)):
+        bst.update()
     counts = {"dispatch": 0, "sync": 0}
     wrapped = []
 
@@ -64,7 +69,6 @@ def _count_dispatches_and_syncs(bst, iters):
     for name in ("_add_leaf_outputs", "_scale_tree_arrays",
                  "_mark_features_used"):
         wrap(gbdt_mod, name)
-    wrap(sampling_mod, "goss_mask_device")
     wrap(linear_ops_mod, "fit_linear_leaves_device")
     orig_get = jax.device_get
 
@@ -85,8 +89,10 @@ def _count_dispatches_and_syncs(bst, iters):
 
 _CENSUS_PATHS = (
     ("fused", {}),
-    ("goss", {"data_sample_strategy": "goss"}),
-    ("goss_host", {"data_sample_strategy": "goss",
+    # learning_rate 1: GOSS leaves only the first int(1 / learning_rate)
+    # = 1 iteration unsampled, so a few census iterations reach the sampler
+    ("goss", {"data_sample_strategy": "goss", "learning_rate": 1.0}),
+    ("goss_host", {"data_sample_strategy": "goss", "learning_rate": 1.0,
                    "tpu_device_goss": "off"}),
     ("cegb", {"cegb_penalty_split": 0.1,
               "cegb_penalty_feature_coupled": [1.0] * 8}),

@@ -13,7 +13,7 @@ is ``chip_smoke.py``'s job, on the chip.
 prints one ``[OK]``/``[FAIL]`` line per kernel (with the compiler's message)
 and exits non-zero when any failed, or 3 when libtpu offers no topology.
 
-    python tools/tpu_aot.py --grower higgs|msltr|epsilon [--phase grow/hist]
+    python tools/tpu_aot.py --grower higgs|msltr|epsilon|msltr-goss [--phase grow/hist]
 
 compiles the whole GROWER at a benchmark cell's shape (1.5 M x 28 on the
 fused wave, 2.27 M x 137 and 400 K x 2000 on the unfused; 255 leaves,
@@ -134,9 +134,11 @@ def kernel_cases(sharding):
     return cases
 
 
-GROWER_SHAPES = {"higgs": (1_500_000, 28, "fused"),
-                 "msltr": (2_270_000, 137, "unfused"),
-                 "epsilon": (400_000, 2000, "unfused")}
+# rows, features, wave kernel, in-bag rows of a sampled tree (0: every row)
+GROWER_SHAPES = {"higgs": (1_500_000, 28, "fused", 0),
+                 "msltr": (2_270_000, 137, "unfused", 0),
+                 "epsilon": (400_000, 2000, "unfused", 0),
+                 "msltr-goss": (2_270_000, 137, "unfused", 454_000)}
 
 
 def grower_case(cell: str, sharding):
@@ -152,22 +154,26 @@ def grower_case(cell: str, sharding):
     from lightgbm_tpu.ops.split import SplitConfig
 
     pc.interpret_mode = lambda: False
-    n, f, wave = GROWER_SHAPES[cell]
+    n, f, wave, in_bag = GROWER_SHAPES[cell]
     scfg = SplitConfig(min_data_in_leaf=0, min_sum_hessian_in_leaf=100.0,
                        has_nan=False, has_categorical=False,
                        use_sorted_categorical=False, has_monotone=False)
     grow = G.make_grower(G.GrowerConfig(
         num_leaves=255, num_bins=255, split=scfg, leaf_batch=16,
-        histogram_impl="pallas", wave_kernel=wave))
+        histogram_impl="pallas", wave_kernel=wave,
+        sampling="goss_device" if in_bag else "none"))
     assert grow.plan.fused == (wave == "fused"), str(grow.plan)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     i32, f32 = jnp.int32, jnp.float32
-    return grow.raw, (sds((n, f), jnp.uint8), sds((n,), f32), sds((n,), f32),
-                      sds((n,), f32), sds((f,), jnp.bool_), sds((f,), i32),
-                      sds((f,), i32), sds((f,), jnp.bool_), sds((f,), i32))
+    args = (sds((n, f), jnp.uint8), sds((n,), f32), sds((n,), f32),
+            sds((n,), f32), sds((f,), jnp.bool_), sds((f,), i32),
+            sds((f,), i32), sds((f,), jnp.bool_), sds((f,), i32))
+    if in_bag:      # a sampled tree: the in-bag row ids, the last argument
+        args += (None,) * 6 + (sds((in_bag,), i32),)
+    return grow.raw, args
 
 
 def phase_census(hlo_text: str, phase: str) -> dict:
