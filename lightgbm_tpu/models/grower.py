@@ -216,12 +216,13 @@ class GrowerConfig:
     # Fused wave kernel (ops/pallas_wave.py): ONE pallas_call per wave
     # builds the smaller-sibling histograms, derives the larger siblings
     # by parent subtraction and runs the split scan without the (W, G, B,
-    # 3) tensors leaving VMEM — vs one histogram dispatch per leaf plus
-    # two more HBM passes (subtract + scan) unfused.  "auto" fuses only
+    # 3) tensors leaving VMEM — vs one ragged histogram launch per wave
+    # plus two more HBM passes (subtract + scan) unfused.  "auto" fuses only
     # where the capability checks pass AND the flat pallas kernel is the
     # live histogram impl (TPU backends); "fused" forces the kernel
     # (interpret-mode on CPU — how tier-1 exercises the kernel body);
-    # "unfused" keeps the per-leaf path.  The growth plan's ``fused``.
+    # "unfused" keeps build, subtract and scan apart.  The growth plan's
+    # ``fused``.
     wave_kernel: str = "auto"
     # Training-health sentinel signals (resilience/health.py): True wires
     # the quantized int16-wire overflow guard's escalation into a
@@ -355,6 +356,20 @@ def _split_buckets(n: int) -> list:
 _WAVE_LADDER_STEPS = 2
 
 
+# Rows per granule of the UNFUSED ragged wave's packing (the kernel's row
+# block where that is larger).  The packing's granule is not the kernel's
+# block: one slice of ``perm`` costs 1.5-1.9 us whatever it holds, every
+# slot pads its last granule in the gather, and the kernel skips the
+# padding blocks inside a granule.  On a v5e (PERF.md, Findings PR 34: one
+# wave of 16 slots, gather + launch, at 128 / 256 / 512 / 1024 / 2048 rows
+# a granule): 2.27 M x 137, a wave of 693 K rows 38.5 / 33.7 / 32.0 / 34.6
+# / 34.5 ms and one of 23 K rows 3.6 (256) / 3.9 / 4.0 / 4.3; 400 K x 2000,
+# 100 K rows 45.9 (128) / 45.4 (512) / 49.2 (2048), 7 K rows 9.1 / 9.3 /
+# 10.2.  512 is the best or within 6 % of it on each; the partition pass's
+# block (2 048 at 2.27 M rows) is 7-12 % behind on its own shape.
+_WAVE_GRANULE = 512
+
+
 def _wave_row_ladder(lo: int, hi: int, blk: int) -> list:
     """Static TOTAL-row sizes of the ragged fused wave, whole row blocks
     each, ascending: ``hi`` (the most a wave can hold) and
@@ -370,6 +385,30 @@ def _wave_row_ladder(lo: int, hi: int, blk: int) -> list:
         if not sizes or t < sizes[-1]:
             sizes.append(t)
         k += 1
+
+
+def _ragged_wave_totals(most: int, w: int, gran: int) -> list:
+    """Static TOTAL-row sizes of the unfused ragged wave
+    (``_grow_wave._ragged_wave``): the ladder from ``w`` granules (every
+    slot holds one) to ``most`` rows with each of the ``w`` slots rounded up
+    to a whole granule.  Two shapes the chip asked for (PERF.md, Findings
+    PR 34):
+
+    - under a sixteenth of the top only every other step is kept.  An
+      instance is a kernel and some 1 000 instructions to trace, compile
+      and load in every process (15 instances at 2.27 M rows read 3.8 s of
+      set-up over the per-leaf buckets' 12), and the waves down there are
+      a few in a hundred of the rows a tree hands over;
+    - every step is moved up to an ODD multiple of the granule.  XLA's row
+      gather into ``(T, 137)`` bytes reads 11.5 ms at every ``T = odd x
+      512`` tried around 697 K rows and 15.7-16.4 ms at every even multiple
+      (a multiple of 1 024), 8.2 against 9.3 ms at 105 K x 2 000 — gather +
+      launch 9 % and 2 % apart (the per-leaf buckets were powers of two)."""
+    hi = (most // gran + w) * gran
+    steps = _wave_row_ladder(w * gran, hi, gran)
+    low = [t for t in steps if 16 * t < hi]
+    steps = low[-2::-2][::-1] + steps[len(low):]
+    return sorted({t + gran * (1 - (t // gran) % 2) for t in steps})
 
 
 def _ladder_step(sizes, x):
@@ -1916,15 +1955,22 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         Per wave: partition the chosen leaves' contiguous segments in one
         ragged pass (``_partition_wave``), histogram
         each SMALLER sibling's contiguous range, get the larger siblings by
-        subtraction, and search all 2W children's splits.  Unfused that is
-        W per-leaf ``histogram_flat`` calls at each leaf's own bucket, an
-        XLA subtract and one vmapped scan; fused (``_fused_wave``) it is
-        ONE ragged kernel launch over the W segments packed back to back.
-        Either way the cost is the rows handed over — the kernels take
-        6 ns a row at 28 columns and 25 at 137 on a v5e, 0.18-0.21 ns a
-        row-column, instruction-bound and nowhere near the HBM stream's
-        rate (my chip runs, PR 28; PERF.md section 5) — so no path pads a
-        wave beyond the rows it holds.  Sequential depth
+        subtraction, and search all 2W children's splits.  Either way
+        the W segments are packed back to back and handed to ONE ragged
+        kernel launch, padded only to the next step of a total-row ladder:
+        fused (``_fused_wave``) the launch also subtracts and scans;
+        unfused (``_ragged_wave``) it is ``histogram_ragged`` — once per
+        column chunk where a histogram is wider than one launch — and an
+        XLA subtract and one vmapped scan follow.  The cost is the rows
+        handed over — the kernels take 6 ns a row at 28 columns and 25 at
+        137 on a v5e, 0.18-0.21 ns a row-column, instruction-bound and
+        nowhere near the HBM stream's rate (my chip runs, PR 28; PERF.md
+        section 5) — so no Pallas path pads a wave beyond the rows it
+        holds.  (The per-leaf call at a power-of-two bucket,
+        ``_hist_branch_for``, stays for the root's rows, the pool's
+        recompute-on-miss, forced splits, and per slot for the ``onehot``
+        / ``segment`` implementations, which have no ragged form.)
+        Sequential depth
         per tree drops from num_leaves-1 steps to
         ~ceil((num_leaves-1)/W).
 
@@ -2121,6 +2167,84 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                         [payload[:, 0], payload[:, 1]], axis=0))
                 return child[:, 0], child[:, 1], bs
 
+        # ---- the unfused wave's histograms (ops/pallas_histogram.py):
+        # the same packing as the fused wave's, without subtract and scan
+        elif plan.hist_impl == "pallas":
+            from ..ops.pallas_common import interpret_mode
+            from ..ops.pallas_histogram import (histogram_ragged,
+                                                kernel_layout,
+                                                ragged_block_map)
+            from ..ops.pallas_wave import wave_block_map, wave_dtype_for
+            hist_dtype = wave_dtype_for(cfg)
+            blk, hist_ftile = kernel_layout(hist_cols, HB, hist_dtype,
+                                            cfg.rows_block, cfg.packed4)[:2]
+            # the segments are cut in granules (a multiple of the kernel's
+            # block) and the kernel skips the padding blocks INSIDE a
+            # granule as it skips the ladder's: it pays for the rows a
+            # slot holds rounded to ``blk``, the gather for them rounded
+            # to ``gran`` and to the ladder step
+            gran = max(_WAVE_GRANULE, blk)
+            # smaller siblings of disjoint leaves hold at most half the
+            # rows; on a data shard the GLOBAL smaller side may hold all of
+            # them; each of the W slots rounds up to whole granules
+            most = n if axis is not None else n // 2
+            wave_totals = _ragged_wave_totals(most, W, gran)
+            wave_totals_arr = jnp.asarray(wave_totals, jnp.int32)
+
+            def _ragged_wave(perm, small_start, small_cnt):
+                """The W smaller siblings' RAW histograms ``(W, G, HB, 3)``
+                from ONE gather and ONE kernel launch (a column chunk):
+                their contiguous perm segments packed back to back in whole
+                granules (``wave_block_map``; rows past a slot's count hit
+                the phantom zero row), padded only to the next step of the
+                TOTAL-row ladder, and a block -> slot map that tells the
+                kernel whose histogram each row block belongs to and which
+                blocks hold no row at all."""
+                with phase("grow/select"):
+                    _, goff, ng_total = wave_block_map(small_cnt, gran)
+                    ti = _bucket_of(ng_total * gran, wave_totals_arr)
+
+                def branch_for(T):
+                    @phase("grow/hist")
+                    def br(_):
+                        # granule -> (slot, place in it, its slot's start
+                        # and count): W compares a granule and ONE lookup,
+                        # which is all the index work a branch compiles
+                        g = jnp.arange(T // gran, dtype=jnp.int32)
+                        gslot = jnp.sum(g[:, None] >= goff[None, :],
+                                        axis=1, dtype=jnp.int32) - 1
+                        at = jnp.stack([goff, small_start, small_cnt],
+                                       axis=1)[gslot]
+                        gk, gcnt = g - at[:, 0], at[:, 2]
+                        row0 = gk * gran
+                        seg = jax.vmap(
+                            lambda s0: jax.lax.dynamic_slice(
+                                perm, (s0,), (gran,)))(at[:, 1] + row0)
+                        valid = (row0[:, None]
+                                 + jnp.arange(gran, dtype=jnp.int32)[None, :]
+                                 < gcnt[:, None])
+                        seg = jnp.where(valid, seg, n).reshape(T)
+                        blocks = ragged_block_map(gslot, gk, gcnt, blk, gran)
+                        gbins, gvals = bins_pad[seg], vals_pad[seg]
+                        with kernel_rows(T, hist_ftile,
+                                         -(-hist_cols // hist_ftile)):
+                            return histogram_ragged(
+                                gbins, gvals, blocks, slots=W, num_bins=HB,
+                                rows_block=cfg.rows_block, dtype=hist_dtype,
+                                packed4=cfg.packed4, features=f,
+                                interpret=interpret_mode())
+                    return br
+
+                return jax.lax.switch(
+                    ti, [branch_for(T) for T in wave_totals], 0)
+
+        if use_fused or plan.hist_impl == "pallas":
+            # what the traced shape selects, for the registry (set once a
+            # compile): rows per packing granule, instances of the ladder
+            registry().gauge("hist.wave_granule").set(
+                blk if use_fused else gran)
+            registry().gauge("hist.wave_ladder_steps").set(len(wave_totals))
+
         def step(st: _GrowState, row_leaf=None):
             """One wave; ``row_leaf`` (a sampled tree only) is every row's
             leaf so far, and is returned beside the state."""
@@ -2255,17 +2379,21 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                     jnp.stack([cl, cr], 1), jnp.stack([out_l, out_r], 1),
                     active)
             else:
-                def hist_one(j, hs):
-                    h = jax.lax.switch(
-                        _bucket_of(small_cnt[j]), hist_branches, perm,
-                        small_start[j], small_cnt[j])
-                    return hs.at[j].set(h)
+                if plan.hist_impl == "pallas":
+                    hist_small = _ragged_wave(perm, small_start, small_cnt)
+                else:
+                    # onehot / segment have no ragged form: a slot at a
+                    # time, at its own bucket
+                    def hist_one(j, hs):
+                        h = jax.lax.switch(
+                            _bucket_of(small_cnt[j]), hist_branches, perm,
+                            small_start[j], small_cnt[j])
+                        return hs.at[j].set(h)
 
-                with phase("grow/hist"):
-                    hist_small = jax.lax.fori_loop(
-                        0, W, hist_one,
-                        jnp.zeros((W, f if cfg.packed4 else gcols, HB, 3),
-                                  raw_dtype))                 # (W, G, B, 3)
+                    with phase("grow/hist"):
+                        hist_small = jax.lax.fori_loop(
+                            0, W, hist_one,
+                            jnp.zeros((W, hist_cols, HB, 3), raw_dtype))
                 if axis is not None and not voting:
                     # ONE cross-shard reduce per wave — integer tensors
                     # under quantized training (bin.h:48-81; int16 on the

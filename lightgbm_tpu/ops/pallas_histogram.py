@@ -30,8 +30,12 @@ Why this shape wins on the MXU:
   select + one weight push per register of one-hot.  Its time is the rows
   it is handed, so the streamed volume stays proportional to the rows
   actually histogrammed: the reference's smaller-sibling trick
-  (``serial_tree_learner.cpp:369``), one call per smaller sibling at its
-  own bucket, or the fused wave's one ragged launch.
+  (``serial_tree_learner.cpp:369``), and per wave ONE ragged launch over
+  the W smaller siblings packed back to back (:func:`histogram_ragged`
+  here, per column chunk; ``pallas_wave.fused_wave_call`` where subtract
+  and scan fuse in), which skips the padding blocks it is handed.  The
+  per-leaf :func:`histogram_flat` is the root's call (and the pool's
+  recompute-on-miss), padded to its power-of-two bucket.
 - int8 variant: s8 vals x s8 one-hot -> s32 accumulation — the reference's
   quantized-training histograms (``Int32HistogramSumReducer``, ``bin.h:48``)
   on the MXU's double-rate int8 path.
@@ -154,18 +158,14 @@ def _prep(bins, vals, rows_block, ftile):
     return bins, valsT, ntot // rows_block, (f + fpad) // ftile
 
 
-def _flat_kernel(bins_ref, valsT_ref, out_ref, *, num_bins, ftile, dtype,
-                 packed4=False):
-    """``num_bins`` is the lane-padded bin count (multiple of 128): each
-    column's slab of the flat histogram starts on a lane-tile boundary.
-    Real bin ids never reach the phantom bins, so their histogram lanes
-    are exact zeros and the caller slices them off."""
-    rb = pl.program_id(0)  # row-block index
-
-    @pl.when(rb == 0)
-    def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
+def _accumulate(bins_ref, valsT_ref, out_ref, *, num_bins, ftile, dtype,
+                packed4=False):
+    """Add one row block's sums to the ``(C_PAD, ftile * num_bins)``
+    accumulator block ``out_ref``.  ``num_bins`` is the lane-padded bin
+    count (multiple of 128): each column's slab of the flat histogram
+    starts on a lane-tile boundary.  Real bin ids never reach the phantom
+    bins, so their histogram lanes are exact zeros and the caller slices
+    them off."""
     bins_blk = bins_ref[:].astype(jnp.int32)            # (blk, ct)
     valsT = valsT_ref[:]                                # (C_PAD, blk)
 
@@ -184,6 +184,57 @@ def _flat_kernel(bins_ref, valsT_ref, out_ref, *, num_bins, ftile, dtype,
         out_ref[:, half:] += contract((bins_blk >> 4) & 15)
     else:
         out_ref[:, :] += contract(bins_blk)
+
+
+def _flat_kernel(bins_ref, valsT_ref, out_ref, **layout):
+    """One leaf: zero at the first row block, accumulate every block."""
+    rb = pl.program_id(0)  # row-block index
+
+    @pl.when(rb == 0)
+    def _init():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    _accumulate(bins_ref, valsT_ref, out_ref, **layout)
+
+
+def _ragged_kernel(slot_ref, real_ref, src_ref, bins_ref, valsT_ref, out_ref,
+                   **layout):
+    """Row block ``b`` of a packed wave (``pallas_wave._wave_kernel`` minus
+    its subtract and scan): ``out_ref`` is the accumulator block of slot
+    ``slot_ref[b]``, zeroed at the slot's first block — a slot's blocks are
+    consecutive, so a neighbour compare finds it — and added to on every
+    REAL block.  A padding block (inside a slot's last granule or past the
+    wave's last one) does nothing; ``src_ref`` is the index maps' alone."""
+    del src_ref
+    b = pl.program_id(0)
+    first = (b == 0) | (slot_ref[jnp.maximum(b - 1, 0)] != slot_ref[b])
+
+    @pl.when(first)
+    def _init():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    @pl.when(real_ref[b] > 0)
+    def _real():
+        _accumulate(bins_ref, valsT_ref, out_ref, **layout)
+
+
+def _from_flat(out, *, nchunks, ftile, cols_tile, b_pad, num_bins, f,
+               packed4):
+    """``(C_PAD, nchunks * ftile * b_pad)`` chunk results side by side ->
+    the ``(F, num_bins, 3)`` histogram: channels, lane padding and phantom
+    columns sliced off, packed4's nibble planes un-permuted."""
+    out = out.reshape(C_PAD, nchunks * ftile, b_pad)[:3, :, :num_bins]
+    if packed4:
+        # Each chunk emits its low-nibble features then its high-nibble
+        # features; un-permute back to the interleaved pack_bins4 order
+        # (feature 2j in packed column j's low nibble, 2j+1 high).
+        order = np.concatenate(
+            [np.concatenate([2 * cols, 2 * cols + 1])
+             for cols in np.split(np.arange(nchunks * cols_tile), nchunks)])
+        out = jnp.take(out, jnp.asarray(np.argsort(order)[:f]), axis=1)
+    else:
+        out = out[:, :f]     # drop phantom feature blocks
+    return jnp.transpose(out, (1, 2, 0))
 
 
 @functools.partial(
@@ -227,18 +278,99 @@ def histogram_flat(
                                         (c + 1) * cols_tile, axis=1), valsT)
               for c in range(nchunks)]
     out = chunks[0] if nchunks == 1 else jnp.concatenate(chunks, axis=1)
-    out = out.reshape(C_PAD, nchunks * ftile, b_pad)[:3, :, :num_bins]
-    if packed4:
-        # Each chunk emits its low-nibble features then its high-nibble
-        # features; un-permute back to the interleaved pack_bins4 order
-        # (feature 2j in packed column j's low nibble, 2j+1 high).
-        order = np.concatenate(
-            [np.concatenate([2 * cols, 2 * cols + 1])
-             for cols in np.split(np.arange(nchunks * cols_tile), nchunks)])
-        out = jnp.take(out, jnp.asarray(np.argsort(order)[:f]), axis=1)
-    else:
-        out = out[:, :f]     # drop phantom feature blocks
-    return jnp.transpose(out, (1, 2, 0))
+    return _from_flat(out, nchunks=nchunks, ftile=ftile, cols_tile=cols_tile,
+                      b_pad=b_pad, num_bins=num_bins, f=f, packed4=packed4)
+
+
+def ragged_block_map(gslot, gk, gcnt, blk: int, gran: int):
+    """The packed wave's row blocks, from its GRANULES.  The W segments lie
+    back to back in whole granules of ``gran`` rows (a multiple of the
+    kernel's row block ``blk``; ``pallas_wave.wave_block_map`` at
+    ``gran``): granule ``g`` is slot ``gslot[g]``'s ``gk[g]``-th and that
+    slot holds ``gcnt[g]`` rows; the granules past the wave's last one name
+    the last slot at a granule past its rows.  Returns per row block ``b``
+    of the ``len(gslot) * gran / blk``:
+
+    - ``slot[b]``: whose accumulator block the kernel holds at ``b``;
+    - ``real[b]``: 1 iff the block's first row lies under its slot's
+      count — the blocks the kernel contracts.  The rest of a slot's last
+      granule and every granule past the wave's last are 0;
+    - ``src[b]``: the block the input index maps name at ``b`` — ``b``
+      itself where real, else its slot's last real block (its first block
+      where it has none), so a skipped block moves no data."""
+    r = gran // blk
+    rep = lambda a: jnp.repeat(a.astype(jnp.int32), r)      # granule -> blocks
+    k = (gk.astype(jnp.int32)[:, None] * r
+         + jnp.arange(r, dtype=jnp.int32)).reshape(-1)
+    nreal = rep((gcnt + (blk - 1)) // blk)
+    b = jnp.arange(k.shape[0], dtype=jnp.int32)
+    src = b - k + jnp.minimum(k, jnp.maximum(nreal - 1, 0))
+    return rep(gslot), (k < nreal).astype(jnp.int32), src
+
+
+@functools.partial(
+    jax.jit, static_argnames=("slots", "num_bins", "rows_block", "dtype",
+                              "interpret", "packed4", "features"))
+def histogram_ragged(
+    bins: jnp.ndarray,   # (T, F) the wave's rows, packed by slot — or packed4
+    vals: jnp.ndarray,   # (T, 3) their channel values (zero on padding rows)
+    blocks,              # ragged_block_map's (slot, real, src), (T / blk,) each
+    *,
+    slots: int,          # W
+    num_bins: int,
+    rows_block: int = 0,
+    dtype: str = "f32",
+    interpret: bool = False,
+    packed4: bool = False,
+    features: int = 0,
+) -> jnp.ndarray:        # (W, F, num_bins, 3) f32 (int32 for int8)
+    """The W histograms of one packed wave in ONE launch per column chunk:
+    every slot accumulates its segment from its first row in blocks of the
+    layout's row block — what :func:`histogram_flat` does on that segment
+    alone, so the sums are bitwise its sums wherever the per-leaf call
+    resolves the same block (every bucket of 2 048 rows and more, at every
+    width the row-block rule does not give more than 1 024 rows) — and the
+    launch skips the blocks that hold no row of any slot."""
+    t, fcols = bins.shape
+    f = features if packed4 else fcols
+    acc_dtype = _DTYPES[dtype][0]
+    blk, ftile, cols_tile, b_pad = kernel_layout(
+        f, num_bins, dtype, rows_block, packed4)
+    if t % blk or any(a.shape != (t // blk,) for a in blocks):
+        raise ValueError(
+            f"ragged histogram needs whole row blocks and one map entry per "
+            f"block: got {t} rows, {[a.shape for a in blocks]} entries, row "
+            f"block {blk}")
+    bins, valsT, nblocks, nchunks = _prep(bins, vals, blk, cols_tile)
+    fb = ftile * b_pad
+    call = pl.pallas_call(
+        functools.partial(_ragged_kernel, num_bins=b_pad, ftile=ftile,
+                          dtype=dtype, packed4=packed4),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(nblocks,),
+            in_specs=[
+                pl.BlockSpec((blk, cols_tile),
+                             lambda b, slot, real, src: (src[b], 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((C_PAD, blk),
+                             lambda b, slot, real, src: (0, src[b]),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_specs=pl.BlockSpec((None, C_PAD, fb),
+                                   lambda b, slot, real, src: (slot[b], 0, 0),
+                                   memory_space=pltpu.VMEM)),
+        out_shape=jax.ShapeDtypeStruct((slots, C_PAD, fb), acc_dtype),
+        compiler_params=compiler_params("arbitrary"),
+        interpret=interpret,
+    )
+    chunks = [call(*blocks, jax.lax.slice_in_dim(
+        bins, c * cols_tile, (c + 1) * cols_tile, axis=1), valsT)
+        for c in range(nchunks)]
+    out = chunks[0] if nchunks == 1 else jnp.concatenate(chunks, axis=2)
+    return jax.vmap(functools.partial(
+        _from_flat, nchunks=nchunks, ftile=ftile, cols_tile=cols_tile,
+        b_pad=b_pad, num_bins=num_bins, f=f, packed4=packed4))(out)
 
 
 def histogram_pallas(

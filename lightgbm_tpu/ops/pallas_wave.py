@@ -15,7 +15,8 @@ This kernel runs the whole sequence while the (C_PAD, F*B) accumulators
 are VMEM-resident:
 
 - grid ``(row_blocks,)`` over a RAGGED wave — one ``pallas_call`` per
-  WAVE (vs one histogram dispatch per leaf unfused): the W smaller
+  WAVE (as the unfused wave's ``histogram_ragged``, which is this kernel
+  without (b) and (c)): the W smaller
   siblings' rows lie back to back in whole row blocks
   (:func:`wave_block_map`), a scalar-prefetched block -> slot map steers
   each block's parent / stats / output blocks, and the launch is handed

@@ -276,7 +276,8 @@ def test_plan_matches_what_runs(rows, params):
     program: the phase scopes and the kernel launches of the traced
     iteration.  ``grow/wave_gather`` is there iff the plan says fused; the
     mask body hands every histogram launch all the rows, the wave body
-    hands some a leaf's bucket (or the packed wave)."""
+    hands all but the root's a packed wave (``fused_wave_call``, or
+    unfused ``histogram_ragged``)."""
     import jax
     from test_phase_scopes import _walk
 
@@ -295,7 +296,7 @@ def test_plan_matches_what_runs(rows, params):
     handed = set()
     for eqn, scope in _walk(jaxpr):
         gathers += "grow/wave_gather" in scope
-        if eqn.params.get("name") == "histogram_flat":
+        if eqn.params.get("name") in ("histogram_flat", "histogram_ragged"):
             launches += 1
             handed.add(eqn.invars[0].aval.shape[0])
     plan = g.plan

@@ -308,14 +308,19 @@ def wave_pair():
             jnp.full(FW, BW, jnp.int32), jnp.full(FW, BW, jnp.int32),
             jnp.zeros(FW, bool), jnp.zeros(FW, jnp.int32)]
 
-    def compile_txt(mode):
+    def compile_txt(mode, hist_impl="auto"):
         gcfg = G.GrowerConfig(num_leaves=LW, num_bins=BW, split=scfg,
-                              leaf_batch=WW, wave_kernel=mode)
+                              leaf_batch=WW, wave_kernel=mode,
+                              histogram_impl=hist_impl)
         grow = G.make_grower(gcfg)
         assert grow.plan.fused == (mode == "fused")
+        assert hist_impl in ("auto", grow.plan.hist_impl)
         return grow.lower(*args).compile().as_text()
 
-    return {"fused": compile_txt("fused"), "unfused": compile_txt("unfused")}
+    # "ragged": the unfused wave beside the Pallas kernel (interpreted),
+    # the program of the benchmark's unfused cells
+    return {"fused": compile_txt("fused"), "unfused": compile_txt("unfused"),
+            "ragged": compile_txt("unfused", "pallas")}
 
 
 def test_fused_wave_no_hbm_scan_roundtrip(wave_pair):
@@ -355,6 +360,66 @@ def test_fused_wave_gathers_no_more_than_a_wave_holds(wave_pair):
     assert cap in rows, sorted(rows)      # the ladder's top step is there
     assert max(rows) <= max(cap, NW + 1), sorted(rows)
     assert not re.search(rf"u8\[{WW},\d+,{FW}\]", wave_pair["fused"])
+
+
+def _scoped(txt, scope):
+    return [ln for ln in txt.splitlines() if scope in ln]
+
+
+def test_unfused_pallas_wave_is_one_ragged_launch_per_wave(wave_pair):
+    """ISSUE-34 structural pin: beside the Pallas kernel the unfused wave
+    histograms its W smaller siblings in ONE gather and ONE launch — a
+    ``switch`` over the steps of one total-row ladder in granules of
+    ``_WAVE_GRANULE`` rows — and no ``fori_loop`` of W per-leaf launches
+    is left: nothing under ``grow/hist`` is shaped by a power-of-two
+    bucket, and no per-slot result is written into the ``(W, F, B, 3)``
+    batch (the old form: per slot a ``switch`` over buckets of 2 048 and
+    4 096 rows here, a gather at the bucket, a launch over all of it and
+    ``hs.at[j].set(h)``).  The implementations without a ragged form keep
+    that loop."""
+    from lightgbm_tpu.ops.pallas_histogram import kernel_layout
+
+    blk = kernel_layout(FW, BW, "f32", 16384)[0]
+    gran = max(G._WAVE_GRANULE, blk)
+    ladder = G._ragged_wave_totals(NW // 2, WW, gran)
+    buckets = set(G._split_buckets(NW))
+    assert not buckets & set(ladder)            # the pin can tell them apart
+    batch = f"f32[{WW},{FW},{BW},3]"
+
+    def rows_handed(lines):
+        return {int(r) for ln in lines for r in re.findall(r"/rows(\d+)", ln)}
+
+    def slot_writes(lines):
+        return [ln for ln in lines
+                if "dynamic-update-slice(" in ln and f"= {batch}" in ln]
+
+    hist = _scoped(wave_pair["ragged"], "grow/hist")
+    assert hist and rows_handed(hist) == set(ladder), rows_handed(hist)
+    assert not slot_writes(hist)
+    gathered = {int(dims.split(",")[0]) for ln in hist
+                for dt, dims in _parse_shapes(ln)
+                if dt == "u8" and dims.endswith(f",{FW}")}
+    # (the interpreted kernel also reads its own row block, u8[blk, F])
+    assert set(ladder) <= gathered and not gathered & buckets, sorted(gathered)
+    # the loop that stays for onehot / segment is what the pin would catch
+    assert slot_writes(_scoped(wave_pair["unfused"], "grow/hist"))
+
+
+def test_fused_wave_program_holds_nothing_of_the_unfused_wave(wave_pair):
+    """The fused program never reaches the changed branch: no operation of
+    it sits under ``grow/hist``, and the rows its launches are handed are
+    still the steps of the ladder in KERNEL blocks (the same text as
+    before ISSUE 34; ``tools/tpu_aot.py --grower higgs`` prints the
+    compiled TPU module's digest to compare two checkouts by)."""
+    from lightgbm_tpu.ops.pallas_wave import wave_layout
+
+    fused = wave_pair["fused"]
+    assert not _scoped(fused, "grow/hist")
+    blk = wave_layout(FW, BW, "f32")["rows_block"]
+    ladder = G._wave_row_ladder(WW * blk, (NW // (2 * blk) + WW) * blk, blk)
+    handed = {int(r) for ln in _scoped(fused, "grow/wave_gather")
+              for r in re.findall(r"/rows(\d+)", ln)}
+    assert handed == set(ladder), sorted(handed)
 
 
 @pytest.mark.parametrize("mode", ["fused", "unfused"])

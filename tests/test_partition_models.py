@@ -14,6 +14,17 @@ row id).  Recorded with::
     PYTHONPATH=<checkout of the parent> python tests/test_partition_models.py
 
 (8 virtual CPU devices, as ``conftest.py`` forces for the tests).
+
+The ``ragged_*`` compositions were recorded the same way from the commit
+BEFORE the unfused wave's one ragged histogram launch (PR 33,
+``ad9f38f``), whose per-leaf ``histogram_flat`` loop they ran: the Pallas
+kernel (interpreted here) on the unfused wave — a wave of sixteen and of
+one, quantised int8 sums, packed4 nibbles, the histogram pool (parents
+recomputed on a miss by the per-leaf call that stays), EFB's bundle
+columns, the data mesh (a shard's smaller side may hold all its rows) and
+one sampled tree grown on its in-bag rows.  Every slot accumulates its
+segment in the blocks the per-leaf call used, so the float32 sums — and
+the models — are the parent's bit for bit.
 """
 
 import json
@@ -36,6 +47,19 @@ CASES = {
     "data_mesh": (20000, {"tree_learner": "data", "tpu_leaf_batch": 4}),
     "feature_mesh": (6000, {"tree_learner": "feature"}),
 }
+RAGGED = {"tpu_histogram_impl": "pallas", "tpu_wave_kernel": "unfused",
+          "tpu_leaf_batch": 4}
+CASES.update({name: (n, dict(RAGGED, **extra)) for name, (n, extra) in {
+    "ragged_wave16": (6000, {"tpu_leaf_batch": 16, "num_leaves": 31}),
+    "ragged_wave1": (6000, {"tpu_leaf_batch": 1}),
+    "ragged_quantised": (6000, {"use_quantized_grad": True}),
+    "ragged_packed4": (6000, {"max_bin": 15}),
+    "ragged_pool": (6000, {"histogram_pool_size": 0.2}),
+    "ragged_efb": (6000, {"enable_bundle": True}),
+    "ragged_data_mesh": (20000, {"tree_learner": "data"}),
+    "ragged_goss": (6000, {"data_sample_strategy": "goss", "top_rate": 0.2,
+                           "other_rate": 0.1, "learning_rate": 0.5}),
+}.items()})
 ROUNDS = 3
 
 
@@ -57,14 +81,18 @@ def _model(name):
     import lightgbm_tpu as lgb
 
     n, extra = CASES[name]
-    X, y = _data(n, sparse=name == "efb")
+    X, y = _data(n, sparse="efb" in name)
     bst = lgb.train(dict(BASE, **extra), lgb.Dataset(X, label=y), ROUNDS)
     g = bst._gbdt
     assert g.plan.body == "wave", str(g.plan)   # the pass under test ran
-    assert g.grower_cfg.bundled is (name == "efb")
-    assert g.plan.packed4 is (name == "packed4_quantised")
-    assert g.plan.layout == {"data_mesh": "data",
-                             "feature_mesh": "feature"}.get(name, "single")
+    assert g.grower_cfg.bundled is ("efb" in name)
+    assert g.plan.packed4 is ("packed4" in name)
+    assert g.plan.layout == ("data" if "data_mesh" in name else "feature"
+                             if "feature_mesh" in name else "single")
+    if name.startswith("ragged"):               # the branch under test ran
+        assert g.plan.hist_impl == "pallas" and not g.plan.fused, str(g.plan)
+        assert g.plan.pool is (name == "ragged_pool")
+        assert (g.plan.sampling == "subset") is (name == "ragged_goss")
     return bst.model_to_string()
 
 

@@ -31,7 +31,8 @@ PATHS = {
     "fused": ("boost/gradients", "boost/score_update", "grow/setup",
               "grow/select", "grow/partition", "grow/wave_gather",
               "grow/wave_unpack", "grow/scan", "grow/update", "grow/finish"),
-    # msltr.train's program: per-leaf histogram_flat, XLA subtract + scan
+    # msltr.train's program: one ragged histogram launch a wave, XLA
+    # subtract + scan
     "unfused": ("boost/gradients", "boost/score_update", "grow/setup",
                 "grow/select", "grow/partition", "grow/hist",
                 "grow/subtract", "grow/scan", "grow/update", "grow/finish"),
@@ -142,6 +143,7 @@ def test_kernel_launch_sites_end_in_the_rows_they_are_handed(path):
     for eqn, scope in _walk(jax.make_jaxpr(fn)(*args).jaxpr):
         kernel = eqn.params.get("name")
         if kernel not in ("histogram_flat",      # bins (R, F)
+                          "histogram_ragged",    # packed wave rows (T, F)
                           "fused_wave_call"):    # packed wave rows (T, F)
             continue
         rows, cols = eqn.invars[0].aval.shape    # F fits one launch here
@@ -149,9 +151,10 @@ def test_kernel_launch_sites_end_in_the_rows_they_are_handed(path):
         assert scope.split("/")[-2:] == [f"cols{cols}", f"rows{rows}"], \
             (kernel, scope)
         assert any(p in scope for p in PHASES), scope
-    assert "histogram_flat" in launches          # the root pass
+    assert launches.count("histogram_flat") == 1    # the root pass
     assert ("fused_wave_call" in launches) is (path == "fused")
-    assert len(launches) > 2                     # one launch per bucket
+    assert ("histogram_ragged" in launches) is (path == "unfused")
+    assert len(launches) > 2                     # one launch per ladder step
 
 
 @pytest.mark.parametrize("features,expect", [(28, 1), (137, 1), (700, 3)])
@@ -211,6 +214,66 @@ def test_fused_wave_gather_is_handed_the_rows_the_wave_has():
     assert handed == G._wave_row_ladder(w * blk, cap, blk)
     assert handed[-1] == cap and all(t % blk == 0 for t in handed)
     assert largest == cap, (largest, cap)
+
+
+def test_unfused_wave_is_one_ragged_launch_handed_the_rows_the_wave_has():
+    """The mirror of the fused pin on ``msltr.train``'s program: every
+    kernel launch under ``grow/hist`` is the ragged call — the root's
+    per-leaf ``histogram_flat`` sits under ``grow/setup`` — its path ends
+    ``cols<C>/rows<T>`` with ``T`` the steps of ONE total-row ladder in
+    odd multiples of granules of ``_WAVE_GRANULE`` rows (the kernel's block where that is
+    larger), and nothing under
+    ``grow/hist`` is larger than the most a wave can hold: half the rows
+    and each of the W slots rounded up to a whole granule.  (The per-leaf
+    form gathered every slot at its power-of-two bucket, W x 2 048 rows at
+    the least and up to N for ONE slot.)"""
+    import lightgbm_tpu.models.grower as G
+    from lightgbm_tpu.ops.pallas_histogram import kernel_layout
+
+    fn, args = _program("unfused")
+    w = PARAMS["tpu_leaf_batch"]
+    blk = kernel_layout(F, 256, "f32", 16384)[0]
+    gran = max(G._WAVE_GRANULE, blk)
+    ladder = G._ragged_wave_totals(N // 2, w, gran)
+    cap = ladder[-1]
+    assert (N // (2 * gran) + w) * gran <= cap < w * G._MIN_BUCKET
+    handed, largest = [], 0
+    for eqn, scope in _walk(jax.make_jaxpr(fn)(*args).jaxpr):
+        if "grow/hist" not in scope:
+            continue
+        kernel = eqn.params.get("name")
+        assert kernel != "histogram_flat", scope
+        if kernel == "histogram_ragged":
+            rows, cols = eqn.invars[0].aval.shape
+            assert scope.split("/")[-2:] == [f"cols{cols}", f"rows{rows}"]
+            assert eqn.outvars[0].aval.shape[:2] == (w, F)
+            handed.append(rows)
+            continue        # inside it: (W, C_PAD, F * b_pad) has no rows
+        largest = max([largest] + [d for v in eqn.outvars
+                                   for d in getattr(v.aval, "shape", ())])
+    assert handed == ladder and all(t % (2 * gran) == gran for t in handed)
+    assert largest == cap, (largest, cap)
+
+
+@pytest.mark.parametrize("path", ["fused", "unfused"])
+def test_wave_gauges_say_the_granule_and_the_ladder(path):
+    """``hist.wave_granule`` / ``hist.wave_ladder_steps``, set while the
+    grower is traced: the fused wave packs in kernel blocks, the unfused
+    in ``_WAVE_GRANULE`` rows or its kernel's block, the larger."""
+    import lightgbm_tpu.models.grower as G
+    from lightgbm_tpu.ops.pallas_histogram import kernel_layout
+    from lightgbm_tpu.telemetry.registry import registry
+
+    w = PARAMS["tpu_leaf_batch"]
+    gran = kernel_layout(F, 256, "f32", 16384)[0]
+    ladder = G._wave_row_ladder(w * gran, (N // (2 * gran) + w) * gran, gran)
+    if path == "unfused":
+        gran = max(G._WAVE_GRANULE, gran)
+        ladder = G._ragged_wave_totals(N // 2, w, gran)
+    fn, args = _program(path)
+    jax.make_jaxpr(fn)(*args)
+    assert registry().gauge("hist.wave_granule").value == gran
+    assert registry().gauge("hist.wave_ladder_steps").value == len(ladder)
 
 
 @pytest.mark.parametrize("path", ["fused", "unfused", "sharded"])

@@ -18,9 +18,13 @@ and exits non-zero when any failed, or 3 when libtpu offers no topology.
 compiles the whole GROWER at a benchmark cell's shape (1.5 M x 28 on the
 fused wave, 2.27 M x 137 and 400 K x 2000 on the unfused; 255 leaves,
 ``leaf_batch=16``)
-and prints the compile's seconds, the temporaries it plans and the
-operations it holds under one phase scope — a count and a plan, never a
-speed.
+and prints the compile's seconds, the temporaries it plans, the module's
+instruction count, a digest of its text without metadata and a digest of
+the jaxpr it was lowered from (all equal on two checkouts: the same
+program, kernels included) and the operations it holds
+under one phase scope with the distinct ``rows<R>`` their paths carry (the
+instances of a bucket set or of a total-row ladder) — a count and a plan,
+never a speed.
 """
 
 from __future__ import annotations
@@ -68,7 +72,9 @@ def kernel_cases(sharding):
     import jax.numpy as jnp
 
     from lightgbm_tpu.ops.pallas_common import C_PAD
-    from lightgbm_tpu.ops.pallas_histogram import histogram_flat
+    from lightgbm_tpu.ops.pallas_histogram import (histogram_flat,
+                                                   histogram_ragged,
+                                                   kernel_layout)
     from lightgbm_tpu.ops.pallas_traverse import fused_class_sums
     from lightgbm_tpu.ops.pallas_wave import (STAT_LANES, fused_wave_call,
                                               wave_layout)
@@ -94,6 +100,22 @@ def kernel_cases(sharding):
         functools.partial(histogram_flat, num_bins=15, dtype="f32",
                           packed4=True, features=f),
         (sds((n, f // 2), jnp.uint8), sds((n, 3), jnp.float32))))
+    # the unfused wave's ONE ragged launch a wave (a column chunk): MS-LTR's
+    # one launch of 137, Epsilon's eight of 250, and the two other operand
+    # forms at the Higgs width
+    for name, cols, nb, dtype, vd, p4 in (
+            (f"f32 B={b} F={wide}", wide, b, "f32", jnp.float32, False),
+            (f"f32 B={b} F=2000", 2000, b, "f32", jnp.float32, False),
+            (f"int8 B={b}", f, b, "int8", jnp.int8, False),
+            ("f32 packed4 B=15", f // 2, 15, "f32", jnp.float32, True)):
+        blk = kernel_layout(f if p4 else cols, nb, dtype, 0, p4)[0]
+        cases.append((
+            f"histogram_ragged {name}",
+            functools.partial(histogram_ragged, slots=w, num_bins=nb,
+                              dtype=dtype, packed4=p4,
+                              features=f if p4 else 0),
+            (sds((n, cols), jnp.uint8), sds((n, 3), vd),
+             (sds((n // blk,), jnp.int32),) * 3)))
     scfg = SplitConfig(min_data_in_leaf=0, min_sum_hessian_in_leaf=100.0,
                        has_nan=True, has_categorical=False,
                        use_sorted_categorical=False, has_monotone=False)
@@ -204,6 +226,30 @@ def phase_census(hlo_text: str, phase: str) -> dict:
             "rows": sorted(rows)}
 
 
+def module_digest(hlo_text: str, jaxpr_text: str) -> dict:
+    """What two checkouts are compared by, to show that a cell a PR does
+    not touch runs the parent's program: ``instructions`` and ``digest`` of
+    the compiled module's text without its metadata (source lines, scope
+    paths, stack frames) and without the Mosaic kernels' serialized bodies
+    (MLIR bytecode that carries its call sites' line numbers) — opcode,
+    shape, layout and operands of every instruction — and ``traced``, a
+    digest of the jaxpr the module was lowered from, which holds every
+    kernel's body equation by equation and no source location."""
+    import hashlib
+    import re
+
+    def sha(text):
+        return hashlib.sha1(text.encode()).hexdigest()[:16]
+
+    head, _, comps = hlo_text.partition("\n\nFileNames")
+    comps = comps[comps.find("\n\n\n"):]      # past the stack-frame tables
+    body = re.sub(r",? ?metadata=\{[^}]*\}", "", head + comps)
+    body = re.sub(r'"body":"[^"]*"', '"body":""', body)
+    n = len(re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = ", body, re.M))
+    return {"instructions": n, "digest": sha(body),
+            "traced": sha(re.sub(r"0x[0-9a-f]+", "0x", jaxpr_text))}
+
+
 def main() -> int:
     import argparse
     import time
@@ -221,10 +267,14 @@ def main() -> int:
         fn, args = grower_case(opts.grower, sharding)
         t0 = time.time()
         compiled = compile_for_tpu(fn, *args)
-        census = phase_census(compiled.as_text(), opts.phase)
+        text = compiled.as_text()
+        census = phase_census(text, opts.phase)
+        import jax
+        digest = module_digest(text, str(jax.make_jaxpr(fn)(*args)))
         print(f"[OK] grower {opts.grower}: compiled in "
               f"{time.time() - t0:.1f} s, temporaries "
-              f"{compiled.memory_analysis().temp_size_in_bytes} bytes; "
+              f"{compiled.memory_analysis().temp_size_in_bytes} bytes, "
+              f"module {digest}; "
               f"under {opts.phase}: {census}", flush=True)
         return 0
     failed = 0
