@@ -20,6 +20,7 @@ from .dataset import TrainData
 from .models.gbdt import GBDT
 from .models.dart import DART
 from .models.rf import RandomForest
+from .telemetry import span
 
 
 class Sequence:
@@ -216,7 +217,11 @@ class Dataset:
         # scipy sparse stays sparse all the way into binning (binned
         # column-wise from CSC, binning._bin_sparse_matrix) — a Bosch-class
         # 1.2M x 968 CSR must never materialize as ~9 GB of dense f64.
-        self.data = data.tocsr() if _is_scipy_sparse(data) else _as_2d(data)
+        # ``data/init``: the conversion of the caller's matrix (a dense
+        # input becomes a float64 copy: 6.4 GB and seconds at 400 K x 2000)
+        with span("data/init"):
+            self.data = (data.tocsr() if _is_scipy_sparse(data)
+                         else _as_2d(data))
         self.label = None if label is None else np.asarray(label)
         self.reference = reference
         self.weight = None if weight is None else np.asarray(weight, np.float64)
@@ -315,7 +320,6 @@ class Dataset:
             # rule): construction runs before the GBDT constructor or
             # engine session ever sees the config, so without this the
             # run's own training set would always bin under mode "off".
-            from .telemetry import span
             from .telemetry.memory import set_memory_mode
             if "tpu_telemetry_memory" in cfg.raw_params \
                     or "telemetry_memory" in cfg.raw_params:
@@ -506,7 +510,10 @@ class Booster:
             cls = RandomForest
         else:
             cls = GBDT
-        self._gbdt = cls(self.cfg, td, valid_td, base_model=base_model)
+        # ``train/booster_init``: objective init (lambdarank's query
+        # tables), the initial score, the device copies, the growth plan
+        with span("train/booster_init"):
+            self._gbdt = cls(self.cfg, td, valid_td, base_model=base_model)
         self.train_set = train_set
 
     # ------------------------------------------------------------------- train

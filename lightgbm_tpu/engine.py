@@ -402,39 +402,31 @@ def train(
         valid_sets=[nm for nm, _ in valid_pairs])
     tel.maybe_start_profile()
 
-    def _emit_iter(done_it: int, dispatch_s: float, host_s: float,
-                   pack_size: int, ckpt_s: Optional[float]) -> None:
-        """One ``train.iter`` event per COMMITTED round: wall time split
-        into dispatch wait (time inside the device-facing call — amortized
-        per round on the pack path) vs host bookkeeping (commit, eval,
-        callbacks, checkpoint), plus the health verdict so far."""
-        host_s = max(host_s, 0.0)
-        tel.emit("train.iter", iteration=done_it,
-                 wall_s=round(dispatch_s + host_s, 6),
-                 dispatch_wait_s=round(dispatch_s, 6),
-                 host_s=round(host_s, 6), pack_size=pack_size,
-                 checkpoint_s=(None if ckpt_s is None
-                               else round(ckpt_s, 6)),
-                 health=(None if sentinel is None else sentinel.verdict()))
+    def _emit_iter(done_it: int, pack_size: int,
+                   ckpt_s: Optional[float]) -> None:
+        """One ``train.iter`` event per COMMITTED round — a view of the
+        iteration's record (telemetry/iters.py: the wall time to the next
+        iteration, the part of it before the program was enqueued, CPU,
+        switches, faults, compiles; a pack's amortised per round), written
+        when the next iteration closes that record — plus the checkpoint
+        write and the health verdict so far."""
+        tel.emit_iter(done_it, pack_size,
+                      checkpoint_s=(None if ckpt_s is None
+                                    else round(ckpt_s, 6)),
+                      health=(None if sentinel is None
+                              else sentinel.verdict()))
         tel.maybe_stop_profile(done_it - start_it)
 
     try:
         while it < num_boost_round:
             if use_pack:
-                t_pack0 = time.perf_counter()
                 rounds, finished = booster._gbdt.train_pack(
                     min(pack_k, num_boost_round - it))
-                # amortized device share of each committed round's event
-                # (the pack is ONE dispatch — per-round attribution below
-                # it is not observable from the host)
-                disp_share = ((time.perf_counter() - t_pack0)
-                              / max(len(rounds), 1))
                 committed = 0
                 stopped = False
                 rollback_due = False
                 try:
                     for j, rnd in enumerate(rounds):
-                        t_round0 = time.perf_counter()
                         # Commit one round, then replay its callbacks/eval:
                         # valid scores update per committed tree, so
                         # callbacks observe the SAME per-iteration metric
@@ -447,9 +439,7 @@ def train(
                         faults.maybe_kill(it + j + 1)
                         rollback_due = _health_check(it + j + 1)
                         stopped = (not rollback_due) and _fire_after(it + j)
-                        _emit_iter(it + j + 1, disp_share,
-                                   time.perf_counter() - t_round0,
-                                   len(rounds), None)
+                        _emit_iter(it + j + 1, len(rounds), None)
                         if rollback_due or stopped:
                             break
                 finally:
@@ -475,21 +465,16 @@ def train(
                     break
                 _maybe_checkpoint(it)
             else:
-                t_round0 = time.perf_counter()
                 for cb in cbs_before:
                     cb(CallbackEnv(booster, params, it, 0,
                                    num_boost_round, None))
-                t_disp0 = time.perf_counter()
                 finished = booster.update(fobj=fobj)
-                disp_s = time.perf_counter() - t_disp0
                 faults.maybe_kill(it + 1)
                 if snapshot_freq > 0 and (it + 1) % snapshot_freq == 0:
                     booster.save_model(
                         f"{snapshot_base}.snapshot_iter_{it + 1}")
                 if _health_check(it + 1):
-                    _emit_iter(it + 1, disp_s,
-                               time.perf_counter() - t_round0 - disp_s,
-                               1, None)
+                    _emit_iter(it + 1, 1, None)
                     it = _do_rollback()
                     continue
                 stopped = _fire_after(it)
@@ -497,14 +482,13 @@ def train(
                 ckpt_s = None
                 if not (stopped or finished):
                     ckpt_s = _maybe_checkpoint(it)
-                _emit_iter(it, disp_s,
-                           time.perf_counter() - t_round0 - disp_s,
-                           1, ckpt_s)
+                _emit_iter(it, 1, ckpt_s)
                 if stopped or finished:
                     break
     finally:
         if sentinel is not None:
             booster._health_report = sentinel.report()
+        tel.flush_iters()
         tel.emit("train.end", iterations=int(booster._gbdt.iter_),
                  elapsed_s=round(time.perf_counter() - t_train0, 6),
                  best_iteration=int(booster.best_iteration),

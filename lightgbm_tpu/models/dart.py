@@ -64,7 +64,7 @@ class DART(GBDT):
             self._add_valid(i, k, self._tree_pred_idx(k, idx, vbins) * delta)
         self._scale_stored_tree(k, idx, factor)
 
-    def train_one_iter(self, grad=None, hess=None) -> bool:
+    def _train_one_iter(self, grad=None, hess=None) -> bool:
         cfg = self.cfg
         n_trees = len(self.dev_models[0])
         drop_idx: list = []
@@ -86,7 +86,7 @@ class DART(GBDT):
                 pred = self._tree_pred_idx(k, idx, self.score_bins_dev)
                 drop_preds[(k, idx)] = pred
                 self._add_scores(k, -pred)
-        stop = super().train_one_iter(grad, hess)
+        stop = super()._train_one_iter(grad, hess)
         # Normalize (reference DART::Normalize): dropped trees come back scaled
         # by k/(k+1); the new tree is scaled by 1/(k+1).
         kd = len(drop_idx)

@@ -25,7 +25,8 @@ import numpy as np
 from ..config import Config
 from ..dataset import TrainData
 from ..metrics import Metric
-from ..telemetry import phase, registry, segment, span, watch_compiles
+from ..telemetry import (dispatched, iter_record, note_program, phase,
+                         registry, segment, span, watch_compiles)
 from ..objectives import ObjectiveFunction, create_objective
 from ..sampling import FeatureSampler, SampleStrategy
 from ..ops.split import SplitConfig
@@ -154,6 +155,10 @@ class GBDT:
     # Auto pack-size ceiling: bounds the (K, ...)-stacked TreeArrays a
     # single scan emits (explicit tpu_iter_pack may exceed it).
     _PACK_AUTO_CAP = 256
+    # The running iteration's record (telemetry/iters.py; None with
+    # telemetry off) and what marks its programs' names under GOSS.
+    _iter_rec = None
+    _iter_tag = ""
 
     def __init__(self, cfg: Config, train: TrainData,
                  valids: Sequence[Tuple[str, TrainData]] = (),
@@ -806,7 +811,8 @@ class GBDT:
         self._host_cache[k].append(None)
         if not self.valid_bins:
             return
-        with span("train/valid_scores", track_memory=True):
+        with span("train/valid_scores", track_memory=True,
+                  iter=self.iter_ + 1):
             for i, vbins in enumerate(self.valid_bins):
                 pred = predict_tree_bins_device(
                     _tree_dict(arrays), vbins, self.meta_dev["nan_bins"])
@@ -839,7 +845,13 @@ class GBDT:
     def train_one_iter(self, grad: Optional[np.ndarray] = None,
                        hess: Optional[np.ndarray] = None) -> bool:
         """One boosting iteration (reference ``GBDT::TrainOneIter``).  Returns
-        True when no tree could be grown (training should stop)."""
+        True when no tree could be grown (training should stop).  The host's
+        side of it is one iteration record and one ``train/iter`` span
+        (telemetry/iters.py); DART and RF override ``_train_one_iter``."""
+        with iter_record(self.iter_ + 1) as self._iter_rec:
+            return self._train_one_iter(grad, hess)
+
+    def _train_one_iter(self, grad, hess) -> bool:
         cfg = self.cfg
         if grad is None and self.objective is None:
             raise ValueError(
@@ -857,6 +869,7 @@ class GBDT:
         if self.sample_strategy.is_goss and not sampled:
             registry().counter("sample.unsampled_iters").inc()
         goss_in_fused = used_fused and sampled
+        self._iter_tag = "[sampled]" if sampled else ""
         if goss_in_fused:
             # The GOSS mask is derived IN-TRACE from the fused iteration's
             # own gradients — no standalone mask dispatch, no host pull.
@@ -959,6 +972,7 @@ class GBDT:
                     g_dev, h_dev,
                     tuple(a.leaf_value for _k, a, _rl in results),
                     self.scores)
+        dispatched(self._iter_rec)     # the counters, after the enqueue
         for k, arrays, row_leaf in results:
             self._store_tree(k, arrays, row_leaf)
         self.iter_ += 1
@@ -1193,6 +1207,10 @@ class GBDT:
         (and everything after) are trimmed — the exact stop that the
         deferred per-round check in train_one_iter approximates one
         iteration late."""
+        with iter_record(self.iter_ + 1, k) as self._iter_rec:
+            return self._train_pack(k)
+
+    def _train_pack(self, k: int):
         # a previous pack's trailing vector that nothing consumed (e.g. a
         # callback early-stop at the last committed round) must not be
         # misattributed to this pack's rounds
@@ -1221,10 +1239,12 @@ class GBDT:
                 self._full_mask, base_fmask, self._goss_key, self._ff_key,
                 self._quant_key, self._split_key,
                 self._cegb_used_dev if self._use_cegb else None)
-        with span("train/pack_dispatch", track_memory=True), \
-                _kernel_compile_errors():
+        with span("train/pack_dispatch", track_memory=True,
+                  iter=self.iter_ + 1), _kernel_compile_errors():
             scores2, stacked, nls, used_stack, health_stack = \
                 self._pack_fn(k)(*args)
+        note_program(self._iter_rec, f"pack_k{k}")
+        dispatched(self._iter_rec)
         self.scores = scores2
         with span("train/pack_sync"):
             if health_stack is not None:
@@ -1496,9 +1516,12 @@ class GBDT:
         boundary only).  A kernel compile failure is re-raised with the
         explicit opt-outs named (:func:`_kernel_compile_errors`) — never
         retried on another implementation."""
-        with span("train/" + name.lstrip("_"), track_memory=True), \
-                _kernel_compile_errors():
-            return getattr(self, name)(*args, **kw)
+        program = name.lstrip("_")
+        with span("train/" + program, track_memory=True,
+                  iter=self.iter_ + 1), _kernel_compile_errors():
+            out = getattr(self, name)(*args, **kw)
+        note_program(self._iter_rec, program + self._iter_tag)
+        return out
 
     def _raw_grow(self, gk, hk, mask_dev, fmask, quant_key=None,
                   split_key=None, sample_rows=None):

@@ -38,7 +38,7 @@ class RandomForest(GBDT):
         self._sum_valid = [jnp.zeros_like(v) for v in self.valid_scores]
         self._init_valid = [v for v in self.valid_scores]
 
-    def train_one_iter(self, grad=None, hess=None) -> bool:
+    def _train_one_iter(self, grad=None, hess=None) -> bool:
         if grad is None:
             g_dev, h_dev = self._grad_fn(self._init_train_scores)
         else:

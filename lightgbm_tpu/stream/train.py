@@ -37,6 +37,7 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
+from ..telemetry import iter_record, note_program
 from ..utils.log import Log
 from .residency import ResidencyManager, pack_bins4_host
 from .store import ShardedDataset
@@ -420,6 +421,12 @@ class StreamTrainer:
         grew) — the reference ``TrainOneIter`` contract, checked per
         round (the in-core fused path may defer this check by one
         iteration; streamed never defers)."""
+        with iter_record(self.g.iter_ + 1) as rec:
+            finished = self._train_round()
+            note_program(rec, "stream_round")   # its last fetch is back
+            return finished
+
+    def _train_round(self) -> bool:
         import jax
         jnp = self._jnp
         g = self.g
@@ -717,9 +724,7 @@ def train_streamed(
              stream=trainer.stats())
     try:
         while it < num_boost_round:
-            t_r0 = time.perf_counter()
             finished = trainer.train_round()
-            disp_s = time.perf_counter() - t_r0
             faults.maybe_kill(it + 1)
             stopped = _fire_after(it)
             it += 1
@@ -734,18 +739,14 @@ def train_streamed(
                 last_ckpt = it
                 tel.emit("train.checkpoint", iteration=it, dir=ckpt_dir,
                          seconds=round(ckpt_s, 6))
-            tel.emit("train.iter", iteration=it,
-                     wall_s=round(time.perf_counter() - t_r0, 6),
-                     dispatch_wait_s=round(disp_s, 6),
-                     host_s=round(time.perf_counter() - t_r0 - disp_s, 6),
-                     pack_size=1,
-                     checkpoint_s=(None if ckpt_s is None
-                                   else round(ckpt_s, 6)),
-                     health=None)
+            tel.emit_iter(it, 1, health=None,
+                          checkpoint_s=(None if ckpt_s is None
+                                        else round(ckpt_s, 6)))
             if stopped or finished:
                 break
     finally:
         booster._stream_stats = trainer.stats()
+        tel.flush_iters()
         tel.emit("train.end", iterations=int(booster._gbdt.iter_),
                  elapsed_s=round(time.perf_counter() - t0, 6),
                  best_iteration=int(booster.best_iteration),

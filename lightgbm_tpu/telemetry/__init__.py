@@ -27,12 +27,15 @@ Knobs: ``tpu_telemetry=on|off`` (off is bitwise-inert),
 
 from __future__ import annotations
 
+import time
 from typing import Dict
 
 from .events import (SCHEMA_VERSION, JsonlSink, active_sink, close_log,
                      configure_log, emit)
+from .iters import (dispatched, iter_record, iter_records,
+                    last_iter_record, note_program)
 from .memory import (MEMORY_MODES, MemoryTracker, arm_memory_from_config,
-                     device_memory_stats, host_peak_rss_mb,
+                     device_memory_stats, host_peak_rss_mb, listen_to_jit,
                      live_buffer_census, memory_analysis_summary,
                      memory_block, memory_mode, note_compile,
                      set_memory_mode)
@@ -48,13 +51,17 @@ __all__ = [
     "Histogram",
     "JsonlSink", "MemoryTracker", "MetricsRegistry", "TrainTelemetry",
     "active_sink", "arm_from_config", "arm_memory_from_config",
-    "close_log", "configure_log", "device_memory_stats", "emit", "enabled",
-    "host_peak_rss_mb", "instrument", "kernel_rows", "live_buffer_census",
-    "memory_analysis_summary", "memory_block", "memory_mode",
-    "note_compile", "phase", "registry", "render_prometheus", "reset_spans",
-    "segment", "set_enabled", "set_memory_mode", "span", "span_totals",
-    "telemetry_block", "train_session", "watch_compiles",
+    "close_log", "configure_log", "device_memory_stats", "dispatched",
+    "emit", "enabled", "host_peak_rss_mb", "instrument", "iter_record",
+    "iter_records", "kernel_rows", "last_iter_record", "listen_to_jit",
+    "live_buffer_census", "memory_analysis_summary", "memory_block",
+    "memory_mode", "note_compile", "note_program", "phase", "registry",
+    "render_prometheus", "reset_spans", "segment", "set_enabled",
+    "set_memory_mode", "span", "span_totals", "telemetry_block",
+    "train_session", "watch_compiles",
 ]
+
+listen_to_jit()     # every compile of the process, from its first (jit.*)
 
 
 def arm_from_config(cfg) -> bool:
@@ -64,6 +71,35 @@ def arm_from_config(cfg) -> bool:
     on = getattr(cfg, "tpu_telemetry", "on") != "off"
     set_enabled(on)
     return on
+
+
+def iter_event(rec, iteration: int, pack_size: int = 1, **fields) -> Dict:
+    """The fields of a ``train.iter`` event from an iteration's record:
+    ``wall_s`` is the record's period (update() to the next update(); for a
+    run's last, open record: to now), ``dispatch_wait_s`` the part of it
+    before the program was enqueued, ``host_s`` the rest; ``period_s`` and
+    ``cpu_s`` are ``None`` while the record is open.  A pack's record is
+    one dispatch of ``pack_size`` rounds: its seconds are amortised per
+    round, its counts stay the pack's."""
+    n = max(int(pack_size), 1)
+
+    def secs(ns):
+        return None if ns is None else round(ns / 1e9 / n, 6)
+
+    rec = rec or {}
+    enter, period = rec.get("enter_ns"), rec.get("period_ns")
+    if enter is not None:
+        wall = secs(time.time_ns() - enter if period is None else period)
+        disp = secs((rec["dispatched_ns"] or enter) - enter)
+        host = round(max(wall - disp, 0.0), 6)
+    else:
+        wall = disp = host = None
+    return dict(
+        iteration=iteration, wall_s=wall, dispatch_wait_s=disp, host_s=host,
+        pack_size=pack_size, **fields, period_s=secs(period),
+        cpu_s=secs(rec.get("cpu_ns")),
+        involuntary_switches=rec.get("involuntary_switches"),
+        major_faults=rec.get("major_faults"), compiles=rec.get("compiles"))
 
 
 def telemetry_block() -> Dict:
@@ -106,11 +142,32 @@ class TrainTelemetry:
         self._span_base = {n: d["seconds"]
                           for n, d in span_totals().items()}
         self._profiling = False
+        self._held = []       # train.iter events whose record is still open
 
     # ------------------------------------------------------------ events
     def emit(self, kind: str, **fields) -> None:
         if self.enabled:
             emit(kind, **fields)
+
+    def emit_iter(self, iteration: int, pack_size: int = 1,
+                  **fields) -> None:
+        """One ``train.iter`` event of a committed round: a view of the
+        iteration's record (:func:`iter_event`), so it is HELD until the
+        next iteration has closed that record; :meth:`flush_iters` writes
+        what a run's end leaves open."""
+        if not self.enabled:
+            return
+        self.flush_iters(closed_only=True)
+        self._held.append((last_iter_record(), dict(
+            iteration=iteration, pack_size=pack_size, **fields)))
+
+    def flush_iters(self, closed_only: bool = False) -> None:
+        held, self._held = self._held, []
+        for rec, fields in held:
+            if closed_only and rec is not None and rec["period_ns"] is None:
+                self._held.append((rec, fields))
+            else:
+                self.emit("train.iter", **iter_event(rec, **fields))
 
     def span_delta(self) -> Dict[str, float]:
         """Per-span seconds accumulated since this session started."""

@@ -39,6 +39,7 @@ on at all.
 from __future__ import annotations
 
 import sys
+import threading
 from typing import Any, Dict, Optional
 
 try:
@@ -344,6 +345,9 @@ def note_compile(label: str, seconds: float, compiled=None) -> None:
     reg = registry()
     reg.counter("compile.count").inc()
     reg.histogram("compile.seconds").observe(seconds)
+    # the same seconds per program, to lay beside jax's own account of it
+    # (jit.*{fun_name=...}): this one is the wall time of the whole call
+    reg.histogram("compile.seconds", labels={"label": label}).observe(seconds)
     fields: Dict[str, Any] = {"label": label, "seconds": round(seconds, 6)}
     if compiled is not None:
         summary = memory_analysis_summary(compiled)
@@ -351,6 +355,106 @@ def note_compile(label: str, seconds: float, compiled=None) -> None:
             fields["memory_analysis"] = summary
     from . import events
     events.emit("compile.end", **fields)
+
+
+# ------------------------------------------- every compile, by jax's account
+# ``note_compile`` sees the programs behind ``instrument`` / ``watch_compiles``
+# from OUTSIDE the call.  jax itself reports every trace, lowering and backend
+# compile of the process through ``jax.monitoring``; one listener turns those
+# into ``jit.*`` instruments, each also under ``{fun_name="..."}``:
+#   jit.trace_seconds, jit.lower_seconds, jit.backend_seconds (histograms),
+#   jit.cache_load_seconds (the backend step of a program the persistent cache
+#   held: retrieval + deserialisation, kept OUT of backend_seconds so the four
+#   add up), jit.compiles (programs made ready), jit.cache_hits /
+#   jit.cache_misses (of the requests that used the persistent cache).
+# A trace inside a trace (a jitted helper traced while the outer program is)
+# is part of the outer one's seconds and is not observed again.
+_JIT_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace_seconds",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower_seconds",
+    "/jax/core/compile/backend_compile_duration": "jit.backend_seconds",
+}
+_CACHE_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_jit_local = threading.local()     # trace depth, cache request / hit pending
+_jit_totals = [0, 0.0]             # compiles, seconds: the iteration records'
+_jit_listening = False
+
+
+def jit_totals():
+    """``(compiles, seconds)`` of the process so far by jax's account — what
+    an iteration record's ``compiles`` / ``compile_s`` are differences of."""
+    return _jit_totals[0], _jit_totals[1]
+
+
+def _fun(fun_name) -> str:
+    name = str(fun_name or "?")
+    return name[4:-1] if name.startswith("jit(") and name.endswith(")") \
+        else name
+
+
+def _on_jit_start(event: str, _value, **_kw) -> None:
+    if event == "/jax/core/compile/jaxpr_trace_duration":
+        _jit_local.depth = getattr(_jit_local, "depth", 0) + 1
+
+
+def _on_jit_event(event: str, **_kw) -> None:
+    if event == _CACHE_REQUEST:
+        _jit_local.cache = "miss"
+    elif event == _CACHE_HIT:
+        _jit_local.cache = "hit"
+
+
+def _on_jit_duration(event: str, seconds: float, fun_name=None,
+                     **_kw) -> None:
+    name = _JIT_EVENTS.get(event)
+    if name is None:
+        return
+    if name == "jit.trace_seconds":
+        depth = _jit_local.depth = max(
+            getattr(_jit_local, "depth", 1) - 1, 0)
+        if depth:
+            return                 # part of the enclosing trace's seconds
+    from . import spans
+    if not spans.enabled():
+        _jit_local.cache = None
+        return
+    reg, labels = registry(), {"fun_name": _fun(fun_name)}
+    counts = []
+    if name == "jit.backend_seconds":
+        cache, _jit_local.cache = getattr(_jit_local, "cache", None), None
+        counts.append("jit.compiles")
+        _jit_totals[0] += 1
+        if cache == "hit":
+            name = "jit.cache_load_seconds"
+        if cache:
+            counts.append("jit.cache_hits" if cache == "hit"
+                          else "jit.cache_misses")
+    _jit_totals[1] += seconds
+    for lb in (None, labels):
+        reg.histogram(name, labels=lb).observe(seconds)
+        for c in counts:
+            reg.counter(c, labels=lb).inc()
+
+
+def listen_to_jit() -> bool:
+    """Register the one ``jax.monitoring`` listener of the process
+    (idempotent; called when the telemetry package is imported)."""
+    global _jit_listening
+    if _jit_listening:
+        return True
+    try:
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(_on_jit_duration)
+        monitoring.register_event_listener(_on_jit_event)
+    except Exception:  # noqa: BLE001 — a jax without it: no jit.* series
+        return False
+    _jit_listening = True
+    try:       # without it nested traces are observed once more each
+        monitoring.register_scalar_listener(_on_jit_start)
+    except Exception:  # noqa: BLE001
+        pass
+    return True
 
 
 # ----------------------------------------------------------- bench block

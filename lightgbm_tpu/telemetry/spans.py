@@ -1,7 +1,7 @@
 """Trace spans: ONE context manager that opens a ``jax.profiler.
 TraceAnnotation`` region (so the span shows up in device profiler traces)
-AND aggregates host wall time into the hierarchical timer + the process
-registry (docs/OBSERVABILITY.md).
+AND aggregates host wall time into the hierarchical timer that
+``span_totals()`` reads (docs/OBSERVABILITY.md).
 
 Span names are ``area/phase`` (``train/iter_dispatch``, ``grower/grow``,
 ``serve/predict``); nested spans join with ``/`` through a thread-local
@@ -27,7 +27,6 @@ import time
 from typing import Dict
 
 from ..utils.timer import Timer
-from .registry import registry
 
 # Process-wide arm switch (tpu_telemetry).  Set per-run by the engine /
 # GBDT constructor from the config; raw Booster.update loops (bench rungs)
@@ -113,24 +112,30 @@ def _stack():
 
 class span:
     """``with span("train/grow"): ...`` — host timer + profiler
-    annotation + registry histogram, one context manager.  Re-entrant and
+    annotation, one context manager.  Re-entrant and
     thread-safe (per-thread name stacks; the timer is lock-guarded).
 
     ``track_memory=True`` additionally records the span's device-memory
     delta + watermark (telemetry/memory.py) when
     ``tpu_telemetry_memory`` is armed — a no-op (one mode check) when it
-    is ``off``, host-side observation either way."""
+    is ``off``, host-side observation either way.
+
+    Keyword arguments (``iter=7``) go to the ``TraceAnnotation`` as the
+    event's stats: the ``train/*`` spans of one boosting iteration carry
+    the iteration's number, as its ``train/iter`` span and its record do
+    (telemetry/iters.py)."""
 
     __slots__ = ("name", "_path", "_t0", "_trace", "_track_memory",
-                 "_mem_token")
+                 "_mem_token", "_stats")
 
-    def __init__(self, name: str, track_memory: bool = False):
+    def __init__(self, name: str, track_memory: bool = False, **stats):
         self.name = name
         self._path = None
         self._t0 = 0.0
         self._trace = None
         self._track_memory = track_memory
         self._mem_token = None
+        self._stats = stats
 
     def __enter__(self):
         if not _enabled:
@@ -140,7 +145,8 @@ class span:
         stack.append(self._path)
         try:
             import jax.profiler
-            self._trace = jax.profiler.TraceAnnotation(self._path)
+            self._trace = jax.profiler.TraceAnnotation(self._path,
+                                                       **self._stats)
             self._trace.__enter__()
         except Exception:  # noqa: BLE001 — profiler is garnish on the timer
             self._trace = None
@@ -170,7 +176,6 @@ class span:
                 pass           # break training or mask the real exception
             self._mem_token = None
         _span_timer.add(self._path, dt)
-        registry().histogram(f"span.{self._path}").observe(dt)
         self._path = None
         return False
 
@@ -239,4 +244,7 @@ def span_totals() -> Dict[str, Dict[str, float]]:
 
 
 def reset_spans() -> None:
+    """Drop the span totals and, with them, the iteration records."""
     _span_timer.reset()
+    from . import iters
+    iters.reset_iter_records()

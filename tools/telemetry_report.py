@@ -45,6 +45,7 @@ import collections
 import json
 import math
 import os
+import statistics
 import sys
 from typing import Dict, List, Tuple
 
@@ -97,19 +98,45 @@ def load_events(path: str) -> Tuple[List[dict], List[str]]:
     return events, problems
 
 
+# The program's stall rule (lightgbm_tpu/telemetry/iters.py; this tool
+# imports nothing of the program, tests/test_iter_records.py holds the
+# two to the same numbers).
+STALL_RATIO, STALL_MIN_S, STALL_HISTORY, STALL_MIN_HISTORY = 3.0, 0.1, 16, 4
+
+
 def _f(v, digits=4):
     return "-" if v is None else f"{float(v):.{digits}f}"
 
 
+def _n(v):
+    return "-" if v is None else int(v)
+
+
 def iteration_rows(events: List[dict]) -> List[tuple]:
-    rows = []
+    """One row per ``train.iter`` event.  ``stall`` marks the rows the
+    program's stall rule would have flagged (the constants above: the
+    period against the median of the 16 periods before it); a log does
+    not say which programs a round dispatched, so all rounds are one
+    history here."""
+    rows, hist = [], collections.deque(maxlen=STALL_HISTORY)
     for e in events:
         if e["kind"] != "train.iter":
             continue
+        period = e.get("period_s")
+        mark = "-"
+        if period is not None:
+            if len(hist) >= STALL_MIN_HISTORY:
+                median = statistics.median(hist)
+                if (period > STALL_RATIO * median
+                        and period - median >= STALL_MIN_S):
+                    mark = "STALL"
+            hist.append(period)
         rows.append((e.get("iteration", "?"), _f(e.get("wall_s")),
                      _f(e.get("dispatch_wait_s")), _f(e.get("host_s")),
                      e.get("pack_size", 1), _f(e.get("checkpoint_s")),
-                     e.get("health") or "-"))
+                     e.get("health") or "-", _f(period), _f(e.get("cpu_s")),
+                     _n(e.get("involuntary_switches")),
+                     _n(e.get("compiles")), mark))
     return rows
 
 
@@ -299,7 +326,8 @@ def report(path: str, memory: bool = False, serve: bool = False) -> int:
                  if s.get("pack_degrade_reason") else "") + ")")
     _table("iterations",
            ("iter", "wall_s", "dispatch_s", "host_s", "pack", "ckpt_s",
-            "health"), iteration_rows(events))
+            "health", "period_s", "cpu_s", "invol_sw", "compiles",
+            "stall"), iteration_rows(events))
     _table("phases (span totals, seconds)", ("span", "seconds"),
            phase_rows(events))
     _table("event counts", ("kind", "count"),
