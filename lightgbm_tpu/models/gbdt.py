@@ -373,6 +373,9 @@ class GBDT:
         # (mask, in-bag row ids or None) of the last iteration's sample;
         # None = it took every row (last_sample())
         self._last_sample = None
+        # per class (gq, hq, g_scale, h_scale) the last fused iteration
+        # grew its trees on under quantized training (last_levels())
+        self._last_levels = None
         if self.sample_strategy.is_goss:
             top_k, other_k, amp = self.sample_strategy.goss_constants()
             registry().gauge("sample.top_k").set(top_k)
@@ -578,15 +581,17 @@ class GBDT:
 
         def grow_apply(bins, scores_k, grad_k, hess_k, mask, fmask, shrink,
                        cegb_coupled=None, cegb_lazy=None, quant_key=None,
-                       split_key=None, sample_rows=None):
+                       split_key=None, sample_rows=None, levels_out=False):
             # bins rides as an ARGUMENT (not a closure): multi-process jit
             # rejects closing over arrays spanning non-addressable devices
-            arrays, row_leaf = grow(
+            grown = grow(
                 bins, grad_k, hess_k, mask, fmask,
                 meta["num_bins_per_feature"], meta["nan_bins"],
                 meta["is_categorical"], meta["monotone"],
                 cegb_coupled, cegb_lazy, quant_key, split_key,
-                self._fg_dev, self._fo_dev, sample_rows)
+                self._fg_dev, self._fo_dev, sample_rows,
+                levels_out=levels_out)
+            arrays, row_leaf = grown[:2]
             with phase("boost/score_update"):
                 grew = arrays.num_leaves > 1
                 lv = jnp.where(grew, arrays.leaf_value * shrink, 0.0)
@@ -604,7 +609,8 @@ class GBDT:
                 arrays = arrays._replace(
                     leaf_value=lv,
                     internal_value=arrays.internal_value * shrink)
-                return scores_k + lv[row_leaf], arrays, row_leaf
+                return (scores_k + lv[row_leaf], arrays, row_leaf) \
+                    + tuple(grown[2:])
 
         self._grow_apply = jax.jit(grow_apply)
 
@@ -644,6 +650,9 @@ class GBDT:
         cegb_lazy = self._cegb_lazy_dev if use_cegb else None
         cegb_coupled_raw = self._cegb_coupled_dev if use_cegb else None
         health_active = self._health_active
+        # quantized training hands each class's (gq, hq, g_scale, h_scale)
+        # out of the fused program, LAST (what last_levels() reads)
+        levels_out = self._quant_key is not None
         if (obj is not None and not obj.need_renew_tree_output
                 and not obj.stochastic_gradients):
             def fused(bins, scores, mask, fmask, shrink, quant_key=None,
@@ -663,7 +672,7 @@ class GBDT:
                 if use_cegb:
                     coupled = cegb_coupled_raw * (~cegb_used)
                     lazy = cegb_lazy
-                outs = []
+                outs, levels = [], []
                 if shape_k:
                     new_scores = scores
                     for k in range(num_class):
@@ -671,17 +680,20 @@ class GBDT:
                               else jax.random.fold_in(quant_key, k))
                         sk = (None if split_key is None
                               else jax.random.fold_in(split_key, k))
-                        ns_k, arrays, row_leaf = grow_apply(
+                        ns_k, arrays, row_leaf, *lv = grow_apply(
                             bins, new_scores[:, k], grad[:, k], hess[:, k],
                             mask, fmask, shrink, coupled, lazy,
-                            quant_key=qk, split_key=sk, sample_rows=rows)
+                            quant_key=qk, split_key=sk, sample_rows=rows,
+                            levels_out=levels_out)
                         new_scores = new_scores.at[:, k].set(ns_k)
                         outs.append((arrays, row_leaf))
+                        levels += lv
                 else:
-                    new_scores, arrays, row_leaf = grow_apply(
+                    new_scores, arrays, row_leaf, *levels = grow_apply(
                         bins, scores, grad, hess, mask, fmask, shrink,
                         coupled, lazy, quant_key=quant_key,
-                        split_key=split_key, sample_rows=rows)
+                        split_key=split_key, sample_rows=rows,
+                        levels_out=levels_out)
                     outs = [(arrays, row_leaf)]
                 hv = None
                 if health_active:
@@ -706,7 +718,9 @@ class GBDT:
                 if health_active:
                     ret.append(hv)
                 if sample is not None:
-                    ret.append(sample)     # LAST: what last_sample() reads
+                    ret.append(sample)     # what last_sample() reads
+                if levels_out:
+                    ret.append(tuple(levels))   # LAST: last_levels()
                 return tuple(ret)
             self._fused_core = fused      # scanned by the pack path
             # watch_compiles (telemetry/spans.py): launches already run
@@ -796,6 +810,27 @@ class GBDT:
         rows = (np.flatnonzero(mask > 0) if rows is None
                 else rows[rows < mask.shape[0]])
         return rows.astype(np.int64), mask[rows]
+
+    def last_levels(self):
+        """What the last ``train_one_iter`` grew its trees on under
+        quantized training (``use_quantized_grad``): ``None`` when that is
+        off, else ``(gq, hq, g_scale, h_scale)`` as numpy arrays — each
+        row's int8 gradient and hessian levels and the two float32 scales
+        the int32 histograms are rescaled by (a list of them, one per
+        class, for ``num_class > 1``).  Reading it is a host read: what a
+        comparison that recomputes a quantized tree is handed
+        (``benchmark/compare_quantized.py``), never called by update()."""
+        if self._quant_key is None:
+            return None
+        if self._last_levels in ("packed", "unfused"):
+            raise RuntimeError(
+                f"the {self._last_levels} iterations keep no levels; run the "
+                "one to be read with update() on the fused path")
+        if self._last_levels is None:
+            raise RuntimeError("no iteration has run yet")
+        got = [tuple(np.asarray(a) for a in lv)
+               for lv in jax.device_get(self._last_levels)]
+        return got if self._shape_k else got[0]
 
     def _tree_fmask(self) -> jnp.ndarray:
         """This iteration's feature mask — the ONE derivation shared by
@@ -896,6 +931,8 @@ class GBDT:
                 "_fused_iter", self.bins_dev, self.scores, mask_dev,
                 fmask, shrink, qkey, skey, it_arg, gkey, used0)
             self._last_sample = None
+            if qkey is not None:
+                *out, self._last_levels = out
             if goss_in_fused:
                 *out, self._last_sample = out
             if self._health_active:
@@ -906,6 +943,8 @@ class GBDT:
                 self.scores, outs = out
             results = [(k, a, rl) for k, (a, rl) in enumerate(outs)]
         else:
+            if qkey is not None:
+                self._last_levels = "unfused"
             if goss_grads is not None:
                 g_dev, h_dev = goss_grads
             elif grad is None:
@@ -1146,6 +1185,8 @@ class GBDT:
                                goss_key=(None if goss_it is None
                                          else bag_key),
                                cegb_used=used)
+                    if use_quant:       # the levels: no pack keeps them
+                        out = out[:-1]
                     # a sampled iteration's last output is its sample
                     return out if goss_it is None else out[:-1]
 
@@ -1228,6 +1269,7 @@ class GBDT:
             self._poison_scores()
         shrink = cfg.learning_rate if cfg.boosting != "rf" else 1.0
         self._last_sample = None
+        self._last_levels = "packed" if self._quant_key is not None else None
         if self.sample_strategy.is_goss:
             self._last_sample = "packed"
             registry().counter("sample.unsampled_iters").inc(max(0, min(

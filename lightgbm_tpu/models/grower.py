@@ -2913,6 +2913,8 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
         sample_rows: Optional[jnp.ndarray] = None,   # (K,) i32 in-bag row
                                                      # ids (plan.sampling
                                                      # == "subset")
+        levels_out: bool = False,   # Python: under quantized training also
+                                    # return (gq, hq, g_scale, h_scale)
     ) -> Tuple[TreeArrays, jnp.ndarray]:
         meta = (num_bins_per_feature, nan_bins, is_categorical, monotone)
         if cfg.bundled:
@@ -2927,6 +2929,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             lazy = (cegb_lazy if cegb_lazy is not None
                     else jnp.zeros(f, jnp.float32))
             cegb = (coupled, lazy)
+        levels = None
         with phase("grow/setup"):
             g = grad * sample_mask
             h = hess * sample_mask
@@ -2939,14 +2942,17 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                                             gradient_scales)
                 if quant_key is None:
                     quant_key = jax.random.PRNGKey(0)
-                g_scale, h_scale = gradient_scales(
-                    g, h, cfg.num_grad_quant_bins)
-                gq, hq = discretize_gradients(g, h, g_scale, h_scale,
-                                              quant_key,
-                                              cfg.stochastic_rounding)
-                vals = jnp.stack([gq, hq, in_bag.astype(jnp.int8)], axis=-1)
-                scale3 = jnp.stack(
-                    [g_scale, h_scale, jnp.asarray(1.0, jnp.float32)])
+                with segment("quantize"):
+                    g_scale, h_scale = gradient_scales(
+                        g, h, cfg.num_grad_quant_bins)
+                    gq, hq = discretize_gradients(g, h, g_scale, h_scale,
+                                                  quant_key,
+                                                  cfg.stochastic_rounding)
+                    vals = jnp.stack([gq, hq, in_bag.astype(jnp.int8)],
+                                     axis=-1)
+                    scale3 = jnp.stack(
+                        [g_scale, h_scale, jnp.asarray(1.0, jnp.float32)])
+                levels = (gq, hq, g_scale, h_scale)
             else:
                 vals = jnp.stack([g, h, in_bag.astype(jnp.float32)], axis=-1)
                 scale3 = None
@@ -2990,6 +2996,13 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                                   cfg.rows_block, cfg.packed4)[1]
         registry().gauge("hist.col_chunks").set(-(-hcols // ftile))
         registry().gauge("hist.cols_tile").set(ftile)
+        # the bits of the values the histograms contract: int8 levels
+        # (int32 sums) under quantized training, float32 otherwise
+        registry().gauge("hist.dtype").set(8 if cfg.quantized else 32)
+        if cfg.quantized:
+            registry().gauge("quant.bins").set(cfg.num_grad_quant_bins)
+            registry().gauge("quant.stochastic").set(
+                int(cfg.stochastic_rounding))
         registry().gauge("scan.tile").set(
             _resolve_tile(cfg.split.scan_tile, nfeat))
         if plan.layout == "feature":
@@ -3030,6 +3043,8 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 tree = tree._replace(
                     leaf_value=jnp.where(active, renewed, 0.0),
                     leaf_weight=jnp.where(active, h_leaf, 0.0))
+        if levels_out and levels is not None:
+            return tree, row_leaf, levels
         return tree, row_leaf
 
     # ------------------------------------------------- streaming grow kit
@@ -3069,14 +3084,16 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                                             gradient_scales)
                 if quant_key is None:
                     quant_key = jax.random.PRNGKey(0)
-                g_scale, h_scale = gradient_scales(
-                    g, h, cfg.num_grad_quant_bins)
-                gq, hq = discretize_gradients(g, h, g_scale, h_scale,
-                                              quant_key,
-                                              cfg.stochastic_rounding)
-                vals = jnp.stack([gq, hq, in_bag.astype(jnp.int8)], axis=-1)
-                scale3 = jnp.stack(
-                    [g_scale, h_scale, jnp.asarray(1.0, jnp.float32)])
+                with segment("quantize"):
+                    g_scale, h_scale = gradient_scales(
+                        g, h, cfg.num_grad_quant_bins)
+                    gq, hq = discretize_gradients(g, h, g_scale, h_scale,
+                                                  quant_key,
+                                                  cfg.stochastic_rounding)
+                    vals = jnp.stack([gq, hq, in_bag.astype(jnp.int8)],
+                                     axis=-1)
+                    scale3 = jnp.stack(
+                        [g_scale, h_scale, jnp.asarray(1.0, jnp.float32)])
                 return jax.lax.optimization_barrier(vals), scale3
             vals = jnp.stack([g, h, in_bag.astype(jnp.float32)], axis=-1)
             # same barrier as _grow_impl: histogram inputs materialize,

@@ -52,8 +52,9 @@ PHASES = (
 # reader that looks for the segment finds the part.  ``sample``: the row
 # sampler's selection (under ``boost/gradients``); ``oob_route``: the dense
 # per-wave row -> leaf update that gives out-of-bag rows their leaf (under
-# ``grow/partition``).
-SEGMENTS = ("sample", "oob_route")
+# ``grow/partition``); ``quantize``: quantized training's discretization of
+# the gradients to int8 levels and its two scales (under ``grow/setup``).
+SEGMENTS = ("sample", "oob_route", "quantize")
 
 _local = threading.local()
 

@@ -13,11 +13,11 @@ is ``chip_smoke.py``'s job, on the chip.
 prints one ``[OK]``/``[FAIL]`` line per kernel (with the compiler's message)
 and exits non-zero when any failed, or 3 when libtpu offers no topology.
 
-    python tools/tpu_aot.py --grower higgs|msltr|epsilon|msltr-goss [--phase grow/hist]
+    python tools/tpu_aot.py --grower higgs|msltr|epsilon|msltr-goss|epsilon-q [--phase grow/hist]
 
 compiles the whole GROWER at a benchmark cell's shape (1.5 M x 28 on the
-fused wave, 2.27 M x 137 and 400 K x 2000 on the unfused; 255 leaves,
-``leaf_batch=16``)
+fused wave, 2.27 M x 137 and 400 K x 2000 on the unfused, the last also
+quantized; 255 leaves, ``leaf_batch=16``)
 and prints the compile's seconds, the temporaries it plans, the module's
 instruction count, a digest of its text without metadata and a digest of
 the jaxpr it was lowered from (all equal on two checkouts: the same
@@ -101,11 +101,12 @@ def kernel_cases(sharding):
                           packed4=True, features=f),
         (sds((n, f // 2), jnp.uint8), sds((n, 3), jnp.float32))))
     # the unfused wave's ONE ragged launch a wave (a column chunk): MS-LTR's
-    # one launch of 137, Epsilon's eight of 250, and the two other operand
-    # forms at the Higgs width
+    # one launch of 137, Epsilon's eight of 250 in f32 and in int8
+    # (epsilon-q), and the two other operand forms at the Higgs width
     for name, cols, nb, dtype, vd, p4 in (
             (f"f32 B={b} F={wide}", wide, b, "f32", jnp.float32, False),
             (f"f32 B={b} F=2000", 2000, b, "f32", jnp.float32, False),
+            (f"int8 B={b} F=2000", 2000, b, "int8", jnp.int8, False),
             (f"int8 B={b}", f, b, "int8", jnp.int8, False),
             ("f32 packed4 B=15", f // 2, 15, "f32", jnp.float32, True)):
         blk = kernel_layout(f if p4 else cols, nb, dtype, 0, p4)[0]
@@ -156,11 +157,13 @@ def kernel_cases(sharding):
     return cases
 
 
-# rows, features, wave kernel, in-bag rows of a sampled tree (0: every row)
-GROWER_SHAPES = {"higgs": (1_500_000, 28, "fused", 0),
-                 "msltr": (2_270_000, 137, "unfused", 0),
-                 "epsilon": (400_000, 2000, "unfused", 0),
-                 "msltr-goss": (2_270_000, 137, "unfused", 454_000)}
+# rows, features, wave kernel, in-bag rows of a sampled tree (0: every row),
+# quantized training (int8 levels, int32 histograms)
+GROWER_SHAPES = {"higgs": (1_500_000, 28, "fused", 0, False),
+                 "msltr": (2_270_000, 137, "unfused", 0, False),
+                 "epsilon": (400_000, 2000, "unfused", 0, False),
+                 "msltr-goss": (2_270_000, 137, "unfused", 454_000, False),
+                 "epsilon-q": (400_000, 2000, "unfused", 0, True)}
 
 
 def grower_case(cell: str, sharding):
@@ -176,14 +179,14 @@ def grower_case(cell: str, sharding):
     from lightgbm_tpu.ops.split import SplitConfig
 
     pc.interpret_mode = lambda: False
-    n, f, wave, in_bag = GROWER_SHAPES[cell]
+    n, f, wave, in_bag, quantized = GROWER_SHAPES[cell]
     scfg = SplitConfig(min_data_in_leaf=0, min_sum_hessian_in_leaf=100.0,
                        has_nan=False, has_categorical=False,
                        use_sorted_categorical=False, has_monotone=False)
     grow = G.make_grower(G.GrowerConfig(
         num_leaves=255, num_bins=255, split=scfg, leaf_batch=16,
         histogram_impl="pallas", wave_kernel=wave,
-        sampling="goss_device" if in_bag else "none"))
+        sampling="goss_device" if in_bag else "none", quantized=quantized))
     assert grow.plan.fused == (wave == "fused"), str(grow.plan)
 
     def sds(shape, dtype):
@@ -195,6 +198,8 @@ def grower_case(cell: str, sharding):
             sds((f,), i32), sds((f,), jnp.bool_), sds((f,), i32))
     if in_bag:      # a sampled tree: the in-bag row ids, the last argument
         args += (None,) * 6 + (sds((in_bag,), i32),)
+    if quantized:   # the iteration's rounding key
+        args += (None, None, sds((2,), jnp.uint32))
     return grow.raw, args
 
 
