@@ -563,6 +563,48 @@ def _route_rows(row_leaf, go_left, leaf_j, newleaf_j):
     return new
 
 
+# The operand size from which ``_take_rows`` slices (see ``_sliced``).
+_SLICE_MIN_BYTES = 1 << 29
+
+
+def _sliced(a, w: int) -> bool:
+    """Whether ``_take_rows`` reads ``w`` rows of ``a`` as slices.  XLA's
+    TPU gather of whole rows out of a LARGE operand slices every column
+    chunk of it out first and then loops over the rows — a pass over all
+    of it, read and written: 4.5 ms a wave on a v5e for the (255, 2000,
+    255, 3) float32 histogram carry, 2.1 ms for the ``(2000, N + 1)``
+    feature-major bins at 400 000 rows — where ``w`` slices move the rows
+    alone.  Under half a GiB (every operand of the 28- and 137-column
+    cells, 22-311 MB) that pass is short or the gather native, and the
+    slices cost more than they save: 3.7 % of the iteration with
+    ``higgs``'s 22 MB carry sliced (PERF.md, Findings).  And a slice of a
+    2-D operand reads the whole tile its row lies in (8 rows of 32-bit
+    words: 32 rows of bytes), so ``w`` of them must read fewer bytes than
+    the pass."""
+    tile = 32 // a.dtype.itemsize if a.ndim == 2 else 1
+    return (a.size * a.dtype.itemsize >= _SLICE_MIN_BYTES
+            and w * (min(tile, a.shape[0]) + 1) < 2 * a.shape[0])
+
+
+def _take_rows(a, idx):
+    """``a[idx]`` for a short static-length index vector: one
+    ``dynamic_slice`` a row, stacked, where that moves fewer bytes than a
+    gather (``_sliced``).  Out-of-range indices clamp, as the gather's
+    do."""
+    if not _sliced(a, idx.shape[0]):
+        return a[idx]
+    return jnp.stack([jax.lax.dynamic_index_in_dim(a, idx[j], keepdims=False)
+                      for j in range(idx.shape[0])])
+
+
+def _take_rows_bytes(a, w: int) -> int:
+    """The bytes ``_take_rows`` moves as slices for ``w`` rows of ``a``:
+    the rows, or 0 where it gathers."""
+    if not _sliced(a, w):
+        return 0
+    return w * (a.size // a.shape[0]) * a.dtype.itemsize
+
+
 def _pack_bits(bits):
     """``(W, M)`` bools -> ``(ceil(W / 32) * M,)`` int32: row ``j`` is bit
     ``j % 32`` of the words ``[(j // 32) * M : (j // 32 + 1) * M]``."""
@@ -597,7 +639,8 @@ def _go_left_bits(cfg, bins_fm, meta, feats, sbins, dlefts, scats, cmasks):
     categorical split looks its bin up in the mask's 32-bit words."""
     col = lambda a: a[:, None]
     gcols = meta[4][feats] if cfg.bundled else feats
-    raw = bins_fm[gcols // 2 if cfg.packed4 else gcols].astype(jnp.int32)
+    raw = _take_rows(bins_fm, gcols // 2 if cfg.packed4 else gcols
+                     ).astype(jnp.int32)
     if cfg.packed4:
         raw = jnp.where(col(gcols) % 2 == 0, raw & 15, (raw >> 4) & 15)
     bins = _decode_col(cfg, raw, col(feats), meta)              # (W, N + 1)
@@ -2244,6 +2287,16 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
             registry().gauge("hist.wave_granule").set(
                 blk if use_fused else gran)
             registry().gauge("hist.wave_ladder_steps").set(len(wave_totals))
+        # the bytes the wave's row reads move as slices (_take_rows), set
+        # once a compile: the W parents' carry rows (the pool reads its
+        # own), the W split columns of the feature-major bins, and of all
+        # rows' where a sampled tree routes its out-of-bag rows; 0 where
+        # every read is a gather
+        registry().gauge("grow.wave_read_bytes").set(sum(
+            _take_rows_bytes(a, W) for a in
+            ([] if pool_on else [state.leaf_hist])
+            + ([] if faxis is not None else [bins_fm])
+            + ([bins_fm_all] if route else [])))
 
         def step(st: _GrowState, row_leaf=None):
             """One wave; ``row_leaf`` (a sampled tree only) is every row's
@@ -2364,7 +2417,7 @@ def make_grower(cfg: GrowerConfig, mesh=None, data_axis: str = "data"):
                 out_r = smoothed_output(gr, hr, cr, pout, cfg.split)
 
                 if not pool_on:
-                    parent_hist = st.leaf_hist[top_l]
+                    parent_hist = _take_rows(st.leaf_hist, top_l)
 
             fused_bs = None
             if use_fused:
